@@ -17,7 +17,7 @@ upload, A/B hooks) the reference's users ship in their operator zips.
 Runs anywhere: python examples/custom_operator.py
 """
 
-import _bootstrap  # noqa: F401 — platform pin + repo path
+import _bootstrap  # noqa: F401 — repo path
 
 import json
 import os

@@ -15,11 +15,11 @@ one checkpointed.
 
 Runs on the 8-device virtual mesh:
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/elastic_rescale.py
 """
 
-import _bootstrap  # noqa: F401 — platform pin + repo path
+import _bootstrap  # noqa: F401 — repo path
 
 import tempfile
 

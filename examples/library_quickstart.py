@@ -5,7 +5,7 @@ whole client population (the reference's per-phone subprocess loop,
 Runs anywhere jax runs; on a multi-device host the clients shard over dp.
 """
 
-import _bootstrap  # noqa: F401 — platform pin + repo path
+import _bootstrap  # noqa: F401 — repo path
 
 
 import jax

@@ -12,11 +12,11 @@ check.
 
 Runs on any 8-device mesh; for a quick local run:
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/long_context_training.py
 """
 
-import _bootstrap  # noqa: F401 — platform pin + repo path
+import _bootstrap  # noqa: F401 — repo path
 
 import jax
 import numpy as np

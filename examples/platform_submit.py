@@ -2,7 +2,7 @@
 reference-schema task JSON over gRPC, and poll it to completion (the
 reference's submitTask → schedule → run → getTaskStatus loop)."""
 
-import _bootstrap  # noqa: F401 — platform pin + repo path
+import _bootstrap  # noqa: F401 — repo path
 
 import json
 import time

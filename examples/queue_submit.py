@@ -2,7 +2,7 @@
 reference's Redis-list submit path) and let the scheduler daemon drain it
 through the normal validated submit."""
 
-import _bootstrap  # noqa: F401 — platform pin + repo path
+import _bootstrap  # noqa: F401 — repo path
 
 import json
 import os

@@ -14,11 +14,11 @@ optimizer step lands on the same params as a dense single-device step.
 
 Runs on any 8-device mesh; for a quick local run:
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/tensor_pipeline_parallel.py
 """
 
-import _bootstrap  # noqa: F401 — platform pin + repo path
+import _bootstrap  # noqa: F401 — repo path
 
 import jax
 import numpy as np

@@ -23,8 +23,7 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--platform", default=None,
         help="force the JAX platform (e.g. 'cpu' for control-plane-only "
-        "hosts; some environments pin a hardware plugin via sitecustomize "
-        "that plain env vars cannot override)",
+        "hosts); same effect as the JAX_PLATFORMS environment variable",
     )
     args = ap.parse_args(argv)
 

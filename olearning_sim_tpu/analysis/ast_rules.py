@@ -89,10 +89,6 @@ WAIVERS: Dict[str, Dict[str, str]] = {
             "rollback/close during connection recycling: cleanup of an "
             "already-failed connection; the original error is re-raised "
             "after the second attempt",
-        "olearning_sim_tpu/engine/compile_cache.py":
-            "platform probe and telemetry bridge must never break "
-            "compiles; the degraded answer (env value / uncounted event) "
-            "is the designed fallback",
         "olearning_sim_tpu/supervisor/supervisor.py":
             "a deviceflow hiccup during finalization must not block it "
             "forever; the scan retries on a later pass",

@@ -7,11 +7,6 @@ a cross-process mesh.
 
 from __future__ import annotations
 
-from olearning_sim_tpu.utils.compat import ensure_jax_compat
-
-# This module calls jax.shard_map; adapt legacy runtimes before first use.
-ensure_jax_compat()
-
 
 def smoke_psum() -> int:
     """All-reduce across the whole world: proves cross-process collectives

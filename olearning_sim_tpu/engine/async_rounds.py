@@ -593,8 +593,11 @@ def build_async_round_step(core, num_windows: int, schedule: str,
                     scores_acc = jnp.where(mask_w, scores_w, scores_acc)
                 return scores_acc, (tuple(center) if robust_agg else ())
 
+            # The scores follow the gathered masks, which all_gather types
+            # device-varying; the carry has to start out typed the same.
             scores_all, win_aggs = jax.lax.scan(
-                win_scan, jnp.zeros((c_local * dpn,), jnp.float32),
+                win_scan,
+                _to_varying(jnp.zeros((c_local * dpn,), jnp.float32), "dp"),
                 jnp.arange(W, dtype=jnp.int32),
             )
             if defense_score:

@@ -2,59 +2,52 @@
 
 The engine compiles a *grid* of round-program variants — (deadline, attack,
 defense-structure) x algorithm x model family — and every process start
-(bench sweeps, supervisor relaunches after a crash, the chips-scaling
-family) used to pay full XLA compilation for each variant again (resnet18:
-377 s per BENCH_suite.json). :func:`enable_compile_cache` points jax's
-persistent compilation cache at a durable directory (default:
-``artifacts/xla_compile_cache`` at the repo root) so a second process
-compiling an already-cached variant deserializes the executable instead.
+(a chip-tool call, a supervisor relaunch after a crash) would otherwise pay
+full XLA compilation for each variant again. :func:`enable_compile_cache`
+turns on jax's persistent compilation cache so a second process compiling
+an already-cached variant deserializes the executable instead.
+
+Where the cache lives — one rule, one name:
+
+- ``JAX_COMPILATION_CACHE_DIR`` set: jax already has the directory from
+  its own environment handling; this module sets **no** directory in code
+  (only the two thresholds and the telemetry listener). Whoever launches
+  the process (the chip tool, a deployment) places the cache.
+- unset: the fixed ``artifacts/xla_compile_cache`` inside the checkout
+  (git-ignored). Never a temporary, pid- or time-derived path: the path is
+  part of the cache key, so a directory that moves never hits.
 
 Cache keying is jax's own: a hash of the optimized HLO module, compile
 options, device topology, and jax/XLA versions — so a changed model shape,
 mesh, defense structure, or library upgrade misses cleanly and never
-collides. Invalidation is therefore automatic; the directory can be
-deleted at any time at the cost of re-compiling (see
-docs/performance.md#compile-cache).
+collides. The directory can be deleted at any time at the cost of
+re-compiling (docs/performance.md#compile-cache).
 
 Observability: jax emits ``/jax/compilation_cache/cache_hits`` (persistent
 entry deserialized) and ``/jax/compilation_cache/cache_misses`` (entry
 compiled and written) monitoring events; a process-wide listener mirrors
 them into the cataloged ``ols_engine_compile_cache_hits_total`` /
-``ols_engine_compile_cache_misses_total`` counters so bench records and
-scraped telemetry show whether a run amortized its compiles.
+``ols_engine_compile_cache_misses_total`` counters.
 
-Environment knobs: ``OLS_COMPILE_CACHE=0`` disables the whole feature
-(processes keep jax's default no-persistent-cache behavior);
-``OLS_COMPILE_CACHE=1`` forces it on; ``OLS_COMPILE_CACHE_DIR`` overrides
-the directory (and implies force-on).
-
-Platform gate: with no explicit opt-in, the cache enables only on
-accelerator platforms. Processes that resolve to the CPU backend — pinned
-(``JAX_PLATFORMS=cpu``: the test mesh, degraded bench fallbacks) or
-simply running on a CPU-only host — keep it off: jaxlib 0.4.x CPU
-executable deserialization is unstable under the engine's
-many-executables workload (observed: tier-1 segfaults with the cache on),
-and CPU compiles are not where the variant grid's 377 s resnet cost
-lives. Passing an explicit ``directory`` (the cache benches/tests do)
-overrides the gate.
+``OLS_COMPILE_CACHE=0`` disables the whole feature.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 from typing import Optional
 
 _lock = threading.Lock()
 _state = {"dir": None, "listener": False}
 
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
 
 def default_cache_dir() -> str:
-    """``artifacts/xla_compile_cache`` next to the package (the repo's
-    bench-artifact convention), overridable via ``OLS_COMPILE_CACHE_DIR``."""
-    env = os.environ.get("OLS_COMPILE_CACHE_DIR")
-    if env:
-        return env
+    """``artifacts/xla_compile_cache`` inside the checkout — the directory
+    used when ``JAX_COMPILATION_CACHE_DIR`` is not set."""
     repo_root = os.path.dirname(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )
@@ -67,77 +60,37 @@ def enabled_dir() -> Optional[str]:
     return _state["dir"]
 
 
-def _platform_hint() -> str:
-    """The pinned platform name — read from env / jax config WITHOUT
-    initializing a backend (bench parents must not touch a possibly-wedged
-    accelerator here). Empty string = nothing pinned."""
-    plat = (os.environ.get("OLS_FORCE_PLATFORM")
-            or os.environ.get("JAX_PLATFORMS") or "")
-    try:
-        import jax
-
-        plat = getattr(jax.config, "jax_platforms", None) or plat
-    except Exception:  # lint: allow-silent — env answer is good enough
-        pass
-    return str(plat).split(",")[0].strip()
-
-
-def _cpu_pinned() -> bool:
-    """Whether this process resolves to the CPU backend. A pinned platform
-    (sitecustomize, conftest, degraded-bench fallback) answers without any
-    backend init; with NO pin at all — a plain host where init is safe and
-    imminent anyway (task bridge / supervisor build meshes next) — the
-    real backend is consulted, so a CPU-only deployment is gated exactly
-    like a pinned one."""
-    hint = _platform_hint()
-    if hint:
-        return hint == "cpu"
-    try:
-        import jax
-
-        return jax.default_backend() == "cpu"
-    except Exception:  # noqa: BLE001 — unknown backend: stay gated off
-        return True
-
-
-def enable_compile_cache(directory: Optional[str] = None) -> Optional[str]:
-    """Enable jax's persistent compilation cache under ``directory`` and
-    install the hit/miss telemetry listener. Idempotent; safe to call from
-    every entry point (task bridge, bench, supervisor relaunch) — the
-    first caller wins the directory. Returns the active directory, or None
-    when disabled (``OLS_COMPILE_CACHE=0``), gated off (CPU-pinned process
-    with no explicit opt-in — see module docstring), or the runtime lacks
-    the config knobs."""
+def enable_compile_cache() -> Optional[str]:
+    """Enable jax's persistent compilation cache (module docstring: where)
+    and install the hit/miss telemetry listener. Idempotent; safe to call
+    from every entry point (task bridge, bench, supervisor relaunch).
+    Returns the active directory, or None when disabled
+    (``OLS_COMPILE_CACHE=0``) or the directory cannot be created (reported
+    on stderr; the process then compiles without a persistent cache)."""
     if os.environ.get("OLS_COMPILE_CACHE") == "0":
-        return None
-    forced = (directory is not None
-              or os.environ.get("OLS_COMPILE_CACHE") == "1"
-              or bool(os.environ.get("OLS_COMPILE_CACHE_DIR")))
-    if not forced and _cpu_pinned():
         return None
     import jax
 
     with _lock:
         if _state["dir"] is not None:
-            _install_listener()
             return _state["dir"]
-        directory = directory or default_cache_dir()
-        try:
-            os.makedirs(directory, exist_ok=True)
+        directory = os.environ.get(CACHE_DIR_ENV)
+        if not directory:
+            directory = default_cache_dir()
+            try:
+                os.makedirs(directory, exist_ok=True)
+            except OSError as e:
+                print(f"compile cache disabled: cannot create {directory}: "
+                      f"{e}", file=sys.stderr)
+                return None
             jax.config.update("jax_compilation_cache_dir", directory)
-            # Cache EVERY executable: the variant grid's small programs
-            # (mlp families, CPU-mesh tests) compile under jax's default
-            # 1 s floor yet still dominate multi-process sweeps in
-            # aggregate.
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        except Exception:  # noqa: BLE001 — cache is an optimization only
-            # Unknown config names (exotic jax build) or an unwritable
-            # directory must never take down the engine.
-            return None
-        _state["dir"] = directory
+        # Cache EVERY executable: the variant grid's small programs compile
+        # under jax's default 1 s floor yet still dominate a cold process
+        # start in aggregate.
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         _install_listener()
+        _state["dir"] = directory
     return directory
 
 
@@ -149,25 +102,17 @@ def _install_listener() -> None:
     would silently bind to whichever entry point enabled the cache first."""
     if _state["listener"]:
         return
-    try:
-        from jax import monitoring
-    except ImportError:
-        return
+    from jax import monitoring
+
     from olearning_sim_tpu.telemetry import instrument
 
     def _on_event(event: str, **kwargs) -> None:
-        try:
-            if event == "/jax/compilation_cache/cache_hits":
-                instrument("ols_engine_compile_cache_hits_total").inc()
-            elif event == "/jax/compilation_cache/cache_misses":
-                instrument("ols_engine_compile_cache_misses_total").inc()
-        except Exception:  # lint: allow-silent — telemetry must never
-            pass           # break compiles
+        if event == "/jax/compilation_cache/cache_hits":
+            instrument("ols_engine_compile_cache_hits_total").inc()
+        elif event == "/jax/compilation_cache/cache_misses":
+            instrument("ols_engine_compile_cache_misses_total").inc()
 
-    try:
-        monitoring.register_event_listener(_on_event)
-    except Exception:  # noqa: BLE001 — monitoring API drift
-        return
+    monitoring.register_event_listener(_on_event)
     _state["listener"] = True
 
 
@@ -180,14 +125,7 @@ def cache_stats() -> dict:
     def _value(counter):
         return float(sum(child.value for _k, child in counter.children()))
 
-    try:
-        return {
-            "hits": _value(
-                instrument("ols_engine_compile_cache_hits_total")
-            ),
-            "misses": _value(
-                instrument("ols_engine_compile_cache_misses_total")
-            ),
-        }
-    except Exception:  # noqa: BLE001 — accounting helper only
-        return {"hits": 0.0, "misses": 0.0}
+    return {
+        "hits": _value(instrument("ols_engine_compile_cache_hits_total")),
+        "misses": _value(instrument("ols_engine_compile_cache_misses_total")),
+    }

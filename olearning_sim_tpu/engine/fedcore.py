@@ -45,11 +45,6 @@ from olearning_sim_tpu.engine.algorithms import Algorithm
 from olearning_sim_tpu.engine.client_data import ClientDataset
 from olearning_sim_tpu.parallel.mesh import MeshPlan, global_put, pad_to_multiple
 
-from olearning_sim_tpu.utils.compat import ensure_jax_compat
-
-# This module calls jax.shard_map; adapt legacy runtimes before first use.
-ensure_jax_compat()
-
 
 class ServerState(struct.PyTreeNode):
     """Global FL state carried across rounds (the checkpointable unit —
@@ -289,16 +284,7 @@ def _to_varying(tree, axis: str):
     Needed for scan carries that start replicated (e.g. global params) but
     accumulate shard-local data inside ``shard_map``.
     """
-    try:
-        return jax.lax.pcast(tree, (axis,), to="varying")
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return jax.lax.pvary(tree, axis)
-    except (AttributeError, TypeError):
-        # Pre-VMA jax: no varying typing exists (and the compat shard_map
-        # shim runs with replication checking off), so identity is correct.
-        return tree
+    return jax.lax.pcast(tree, (axis,), to="varying")
 
 
 def _tree_where(pred, a, b):

@@ -64,10 +64,6 @@ import numpy as np
 import optax
 from jax.sharding import PartitionSpec as P
 
-from olearning_sim_tpu.utils.compat import ensure_jax_compat
-
-ensure_jax_compat()
-
 
 def validate_pp_build(model, plan, config, algorithm, microbatches):
     """Build-time checks for a pipelined fedcore — fail before any trace.

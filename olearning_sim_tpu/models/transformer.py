@@ -22,11 +22,6 @@ import numpy as np
 
 from olearning_sim_tpu.models.registry import ModelSpec, register_model
 
-from olearning_sim_tpu.utils.compat import ensure_jax_compat
-
-# This module calls jax.shard_map; adapt legacy runtimes before first use.
-ensure_jax_compat()
-
 
 class TransformerBlock(nn.Module):
     width: int
@@ -65,7 +60,8 @@ class TransformerBlock(nn.Module):
             # Fused Pallas kernel: no HBM score tensor. Slower than XLA's
             # fused dense path on current chips (see ops/flash_attention.py);
             # exists as the ring per-step primitive and for variants XLA
-            # can't fuse.
+            # can't fuse. Not for FedCore client models: the round program
+            # leaves mp to the auto partitioner, which Mosaic refuses.
             from olearning_sim_tpu.ops import flash_attention
 
             B, L, W = x.shape

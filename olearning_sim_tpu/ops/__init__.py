@@ -8,9 +8,10 @@ optional ring-attention per-step primitive via
 :func:`flash_attention_stats`, and a fusion point for variants XLA's
 fused path can't reach). A ``weighted_sum`` FedAvg-reduction kernel existed
 through round 1 but measured at parity with XLA's ``tensordot`` and was
-retired — the engine's aggregation is plain XLA (``fedcore.py``). Every
-kernel has an ``interpret`` mode so numerics are CI-testable on the CPU
-mesh.
+retired — the engine's aggregation is plain XLA (``fedcore.py``). On the
+TPU backend the kernels lower to Mosaic; on the CPU backend (the test path)
+the Pallas interpreter runs the same kernel bodies so numerics are
+CI-testable.
 """
 
 from olearning_sim_tpu.ops.flash_attention import (

@@ -28,11 +28,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from olearning_sim_tpu.parallel.mesh import MeshPlan, global_put
 
-from olearning_sim_tpu.utils.compat import ensure_jax_compat
-
-# This module calls jax.shard_map; adapt legacy runtimes before first use.
-ensure_jax_compat()
-
 
 def _validate_sp_inputs(model, tokens, plan: MeshPlan, caller: str) -> None:
     if plan.sp <= 1:
@@ -87,13 +82,15 @@ def _compiled_forward(model, mesh):
             # logits are replicated over sp after the model's pooling psum.
             return model.apply({"params": params}, tokens_chunk)
 
+        # Manual over EVERY mesh axis (the plan's size-1 mp included): the
+        # ring's Pallas step primitive lowers to a Mosaic kernel, which
+        # refuses to sit under any axis left to the auto partitioner.
         _FWD_CACHE[key] = jax.jit(
             jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(P(), P("dp", "sp")),
                 out_specs=P("dp"),
-                axis_names=frozenset({"dp", "sp"}),
             )
         )
     return _FWD_CACHE[key]
@@ -182,8 +179,7 @@ def _compiled_train(model, mesh, optimizer):
             mesh=mesh,
             in_specs=(P(), P(), P("dp", "sp"), P("dp")),
             out_specs=(P(), P(), P()),
-            axis_names=frozenset({"dp", "sp"}),
-            check_vma=False,
+            check_vma=False,  # manual over every mesh axis, as in forward
         ),
         donate_argnums=(0, 1),
     )

@@ -39,11 +39,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from olearning_sim_tpu.parallel.mesh import MeshPlan, global_put
 
-from olearning_sim_tpu.utils.compat import ensure_jax_compat
-
-# This module calls jax.shard_map; adapt legacy runtimes before first use.
-ensure_jax_compat()
-
 
 _BLOCK_RE = re.compile(r"^TransformerBlock_(\d+)$")
 

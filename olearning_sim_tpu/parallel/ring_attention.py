@@ -29,11 +29,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from olearning_sim_tpu.utils.compat import ensure_jax_compat
-
-# This module calls jax.shard_map; adapt legacy runtimes before first use.
-ensure_jax_compat()
-
 
 NEG_INF = -1e30
 

@@ -26,11 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from olearning_sim_tpu.utils.compat import ensure_jax_compat
-
-# This module calls jax.shard_map; adapt legacy runtimes before first use.
-ensure_jax_compat()
-
 
 _CHECKED: set = set()
 

@@ -37,11 +37,6 @@ from typing import Any, Tuple
 import jax
 from jax.sharding import PartitionSpec as P
 
-from olearning_sim_tpu.utils.compat import ensure_jax_compat
-
-# This module calls jax.shard_map; adapt legacy runtimes before first use.
-ensure_jax_compat()
-
 
 _BLOCK_MARKERS = ("TransformerBlock", "EncoderBlock", "Block")
 _ATTN_MARKER = "MultiHeadDotProductAttention"

@@ -30,14 +30,16 @@ from olearning_sim_tpu.analysis import (  # noqa: E402
 )
 from olearning_sim_tpu.analysis.grid import Variant  # noqa: E402
 
-# Every program structure + both shard modes + both dp, in 4 compiles
-# (maximal = deadline+attack+defense in one program). The full 20-variant
-# grid is check_all's job; tier-1 keeps the compile bill bounded.
+# Every program structure + both shard modes + both dp, in 5 compiles
+# (maximal = deadline+attack+defense in one program; async_defense is the
+# one async program whose anomaly scoring no other tier-1 test traces).
+# The full grid is check_all's job; tier-1 keeps the compile bill bounded.
 SUBSET = [
     Variant("plain", False, 1),
     Variant("deadline", False, 2),
     Variant("defense", False, 2),
     Variant("maximal", True, 2),
+    Variant("async_defense", False, 2),
 ]
 
 

@@ -41,9 +41,7 @@ def test_lint_catches_the_gathered_formulation():
 
     from olearning_sim_tpu.engine import hlo_stats
     from olearning_sim_tpu.parallel.mesh import make_mesh_plan
-    from olearning_sim_tpu.utils.compat import ensure_jax_compat
 
-    ensure_jax_compat()
     dp = 2
     plan = make_mesh_plan(devices=jax.devices()[:dp], dp=dp, mp=1)
     clients, params = 16, 64
@@ -54,8 +52,10 @@ def test_lint_catches_the_gathered_formulation():
         d_all = jax.lax.all_gather(deltas, "dp", tiled=True)
         return jnp.median(d_all, axis=0)
 
+    # all_gather's result is typed device-varying (every device holds its
+    # own copy), so the per-device medians leave through P("dp").
     fn = jax.jit(jax.shard_map(
-        gathered, mesh=plan.mesh, in_specs=(P("dp"),), out_specs=P(),
+        gathered, mesh=plan.mesh, in_specs=(P("dp"),), out_specs=P("dp"),
         axis_names=frozenset({"dp"}),
     ))
     x = np.zeros((clients, params), np.float32)

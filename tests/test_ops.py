@@ -1,4 +1,5 @@
-"""Pallas kernels (interpret mode) + ring attention vs dense references."""
+"""Pallas kernels (run by the interpreter: the CPU backend's test path) +
+ring attention vs dense references."""
 
 import os as _os
 
@@ -33,7 +34,7 @@ def rand_qkv(key, B=2, H=2, L=32, D=16, dtype=jnp.float32):
 # ------------------------------------------------------------------ flash
 def test_flash_matches_dense():
     q, k, v = rand_qkv(jax.random.key(0))
-    out = flash_attention(q, k, v, interpret=True)
+    out = flash_attention(q, k, v)
     ref = dense_reference(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -41,7 +42,7 @@ def test_flash_matches_dense():
 def test_flash_padding_mask():
     q, k, v = rand_qkv(jax.random.key(1), B=2, L=24)
     mask = jnp.arange(24)[None, :] < jnp.array([[24], [7]])
-    out = flash_attention(q, k, v, kv_mask=mask, interpret=True)
+    out = flash_attention(q, k, v, kv_mask=mask)
     ref = dense_reference(q, k, v, kv_mask=mask)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -49,14 +50,14 @@ def test_flash_padding_mask():
 def test_flash_unaligned_shapes():
     # L and D far from the 128-lane / block alignments.
     q, k, v = rand_qkv(jax.random.key(2), B=1, H=3, L=13, D=9)
-    out = flash_attention(q, k, v, interpret=True)
+    out = flash_attention(q, k, v)
     ref = dense_reference(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
 def test_flash_bf16_inputs():
     q, k, v = rand_qkv(jax.random.key(3), dtype=jnp.bfloat16)
-    out = flash_attention(q, k, v, interpret=True)
+    out = flash_attention(q, k, v)
     assert out.dtype == jnp.bfloat16
     ref = dense_reference(q, k, v)
     np.testing.assert_allclose(
@@ -67,7 +68,7 @@ def test_flash_bf16_inputs():
 def test_flash_fully_masked_rows_zero():
     q, k, v = rand_qkv(jax.random.key(4), B=1, L=8)
     mask = jnp.zeros((1, 8), bool)
-    out = flash_attention(q, k, v, kv_mask=mask, interpret=True)
+    out = flash_attention(q, k, v, kv_mask=mask)
     np.testing.assert_allclose(np.asarray(out), 0.0, atol=1e-6)
 
 
@@ -200,15 +201,13 @@ def test_flash_stats_match_dense_and_compose():
     from olearning_sim_tpu.ops import flash_attention_stats
 
     q, k, v = rand_qkv(jax.random.key(8), B=2, H=2, L=32, D=16)
-    o, m, l = flash_attention_stats(q, k, v, interpret=True)
+    o, m, l = flash_attention_stats(q, k, v)
     ref = dense_reference(q, k, v)
     np.testing.assert_allclose(np.asarray(o), np.asarray(ref), atol=2e-5)
 
     # manual two-block merge: acc_blk = o_blk * l_blk
-    o1, m1, l1 = flash_attention_stats(q, k[:, :, :16], v[:, :, :16],
-                                       interpret=True)
-    o2, m2, l2 = flash_attention_stats(q, k[:, :, 16:], v[:, :, 16:],
-                                       interpret=True)
+    o1, m1, l1 = flash_attention_stats(q, k[:, :, :16], v[:, :, :16])
+    o2, m2, l2 = flash_attention_stats(q, k[:, :, 16:], v[:, :, 16:])
     m1, l1 = m1[..., None], l1[..., None]
     m2, l2 = m2[..., None], l2[..., None]
     m12 = jnp.maximum(m1, m2)
@@ -225,7 +224,7 @@ def test_flash_stats_fully_masked_rows():
 
     q, k, v = rand_qkv(jax.random.key(9), B=1, L=8)
     mask = jnp.zeros((1, 8), bool)
-    o, m, l = flash_attention_stats(q, k, v, kv_mask=mask, interpret=True)
+    o, m, l = flash_attention_stats(q, k, v, kv_mask=mask)
     np.testing.assert_allclose(np.asarray(o), 0.0, atol=1e-6)
     np.testing.assert_allclose(np.asarray(l), 0.0, atol=1e-6)
 
@@ -267,8 +266,7 @@ def test_flash_stats_grads_match_reference():
         jnp.float32)
 
     def loss_flash(q, k, v):
-        o, m, l = flash_attention_stats(q, k, v, kv_mask=mask,
-                                        interpret=True)
+        o, m, l = flash_attention_stats(q, k, v, kv_mask=mask)
         # Consume all three outputs the way the ring merge does.
         return (jnp.sum(o.astype(jnp.float32) * l[..., None])
                 + jnp.sum(jnp.tanh(m)))
