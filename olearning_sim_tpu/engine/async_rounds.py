@@ -476,15 +476,17 @@ def build_async_round_step(core, num_windows: int, schedule: str,
         def block_step(carry, inp):
             buf, buf_w, sum_loss, sum_w, count, n_clip = _unpack(carry)
             bx, by, bns, bst, buid, bw, bwin, bscore, batk = inp
-            deltas, losses = jax.vmap(
-                core._local_train,
-                in_axes=(None, 0, 0, 0, 0, 0, None, None),
-            )(params, bx, by, bns, bst, buid, base_key, round_idx)
-            if with_attack:
-                deltas = _attack_deltas(deltas, batk)
-            # Finiteness gate — the same shared helper as the synchronous
-            # engine: a diverged client contributes nothing.
-            ok = _finite_client_mask(losses, deltas)
+            with jax.named_scope("client_train"):
+                deltas, losses = jax.vmap(
+                    core._local_train,
+                    in_axes=(None, 0, 0, 0, 0, 0, None, None),
+                )(params, bx, by, bns, bst, buid, base_key, round_idx)
+            with jax.named_scope("delta_transform"):
+                if with_attack:
+                    deltas = _attack_deltas(deltas, batk)
+                # Finiteness gate — the same shared helper as the
+                # synchronous engine: a diverged client contributes nothing.
+                ok = _finite_client_mask(losses, deltas)
 
             def gate(d):
                 return jnp.where(
@@ -492,41 +494,47 @@ def build_async_round_step(core, num_windows: int, schedule: str,
                 )
 
             bw_eff = jnp.where(ok, bw, 0.0)
-            d32 = jax.tree.map(lambda d: gate(d.astype(jnp.float32)), deltas)
-            defense_ys = None
-            if defense is not None:
-                # Per-client L2 clip, the synchronous formulation (shared).
-                d32, too_big = _clip_client_deltas(d32, clip_norm)
-                n_clip = n_clip + jnp.logical_and(
-                    bw_eff > 0, too_big
-                ).sum().astype(jnp.float32)
-            if with_score:
-                # Apodotiko contribution scores reweight clients inside
-                # their buffer (the polynomial staleness discount applies
-                # per window at commit time).
+            with jax.named_scope("delta_transform"):
                 d32 = jax.tree.map(
-                    lambda d: d * bscore.reshape(
-                        (-1,) + (1,) * (d.ndim - 1)
-                    ),
-                    d32,
+                    lambda d: gate(d.astype(jnp.float32)), deltas
                 )
-            if defense_gather:
-                defense_ys = (d32, bw_eff)
-            else:
-                # Buffered accumulation: each client's weighted delta
-                # lands in its commit window's slot (segment_sum over the
-                # window-assignment data — zero-weight rows are inert).
-                buf = jax.tree.map(
-                    lambda b, d: b + jax.ops.segment_sum(
-                        bw_eff.reshape((-1,) + (1,) * (d.ndim - 1)) * d,
-                        bwin, num_segments=W,
-                    ),
-                    buf, d32,
+                defense_ys = None
+                if defense is not None:
+                    # Per-client L2 clip, the synchronous formulation (shared).
+                    d32, too_big = _clip_client_deltas(d32, clip_norm)
+                    n_clip = n_clip + jnp.logical_and(
+                        bw_eff > 0, too_big
+                    ).sum().astype(jnp.float32)
+                if with_score:
+                    # Apodotiko contribution scores reweight clients inside
+                    # their buffer (the polynomial staleness discount applies
+                    # per window at commit time).
+                    d32 = jax.tree.map(
+                        lambda d: d * bscore.reshape(
+                            (-1,) + (1,) * (d.ndim - 1)
+                        ),
+                        d32,
+                    )
+            with jax.named_scope("aggregate"):
+                if defense_gather:
+                    defense_ys = (d32, bw_eff)
+                else:
+                    # Buffered accumulation: each client's weighted delta
+                    # lands in its commit window's slot (segment_sum over the
+                    # window-assignment data — zero-weight rows are inert).
+                    buf = jax.tree.map(
+                        lambda b, d: b + jax.ops.segment_sum(
+                            bw_eff.reshape((-1,) + (1,) * (d.ndim - 1)) * d,
+                            bwin, num_segments=W,
+                        ),
+                        buf, d32,
+                    )
+                buf_w = buf_w + jax.ops.segment_sum(
+                    bw_eff, bwin, num_segments=W
                 )
-            buf_w = buf_w + jax.ops.segment_sum(bw_eff, bwin, num_segments=W)
-            sum_loss = sum_loss + jnp.where(ok, bw * losses, 0.0).sum()
-            sum_w = sum_w + bw_eff.sum()
-            count = count + (bw_eff > 0).sum().astype(jnp.float32)
+                sum_loss = sum_loss + jnp.where(ok, bw * losses, 0.0).sum()
+                sum_w = sum_w + bw_eff.sum()
+                count = count + (bw_eff > 0).sum().astype(jnp.float32)
             return (_pack(buf, buf_w, sum_loss, sum_w, count, n_clip),
                     (losses, defense_ys))
 
@@ -538,170 +546,172 @@ def build_async_round_step(core, num_windows: int, schedule: str,
             n_clip = jnp.float32(0.0)
         client_loss = block_losses.reshape((c_local,))
 
-        buf_w = jax.lax.psum(buf_w, "dp")
-        sum_loss = jax.lax.psum(sum_loss, "dp")
-        sum_w = jax.lax.psum(sum_w, "dp")
-        count = jax.lax.psum(count, "dp")
-        if defense is not None:
-            n_clip = jax.lax.psum(n_clip, "dp")
+        with jax.named_scope("aggregate"):
+            buf_w = jax.lax.psum(buf_w, "dp")
+            sum_loss = jax.lax.psum(sum_loss, "dp")
+            sum_w = jax.lax.psum(sum_w, "dp")
+            count = jax.lax.psum(count, "dp")
+            if defense is not None:
+                n_clip = jax.lax.psum(n_clip, "dp")
 
-        anomaly_score = jnp.float32(0.0)
-        # Per-window PRE-NORMALIZED aggregates feeding the commit scan:
-        # ``delta_stack`` replicated [W, *param] leaves, or
-        # ``delta_shard_stack`` [W, D_pad/dp] leaves under the sharded
-        # server update. Robust aggregates are already normalized
-        # statistics; the weighted-mean path divides by the window's
-        # aggregation weight here.
-        delta_stack = delta_shard_stack = None
-        if defense_gather:
-            from olearning_sim_tpu.engine import defense as defense_mod
+            anomaly_score = jnp.float32(0.0)
+            # Per-window PRE-NORMALIZED aggregates feeding the commit scan:
+            # ``delta_stack`` replicated [W, *param] leaves, or
+            # ``delta_shard_stack`` [W, D_pad/dp] leaves under the sharded
+            # server update. Robust aggregates are already normalized
+            # statistics; the weighted-mean path divides by the window's
+            # aggregation weight here.
+            delta_stack = delta_shard_stack = None
+            if defense_gather:
+                from olearning_sim_tpu.engine import defense as defense_mod
 
-            d_pc, w_pc = defense_out
-            w_flat = w_pc.reshape((c_local,))
-            w_all = jax.lax.all_gather(w_flat, "dp", tiled=True)
-            win_all = jax.lax.all_gather(
-                wclamp.reshape((c_local,)), "dp", tiled=True
-            )
-            shards = jax.tree.map(
-                lambda a: defense_mod.shard_client_deltas(
-                    a.reshape((c_local,) + a.shape[2:]), "dp", dpn
-                ),
-                d_pc,
-            )
-            shard_leaves = jax.tree.leaves(shards)
-            treedef = jax.tree.structure(shards)
+                d_pc, w_pc = defense_out
+                w_flat = w_pc.reshape((c_local,))
+                w_all = jax.lax.all_gather(w_flat, "dp", tiled=True)
+                win_all = jax.lax.all_gather(
+                    wclamp.reshape((c_local,)), "dp", tiled=True
+                )
+                shards = jax.tree.map(
+                    lambda a: defense_mod.shard_client_deltas(
+                        a.reshape((c_local,) + a.shape[2:]), "dp", dpn
+                    ),
+                    d_pc,
+                )
+                shard_leaves = jax.tree.leaves(shards)
+                treedef = jax.tree.structure(shards)
 
-            def win_scan(scores_acc, w):
-                mask_w = (win_all == w) & (w_all > 0)
-                center = [
-                    defense_mod.robust_leaf_aggregate(
-                        s, mask_w,
-                        aggregator if robust_agg else "median",
-                        trim_fraction,
-                    )
-                    for s in shard_leaves
-                ]
+                def win_scan(scores_acc, w):
+                    mask_w = (win_all == w) & (w_all > 0)
+                    center = [
+                        defense_mod.robust_leaf_aggregate(
+                            s, mask_w,
+                            aggregator if robust_agg else "median",
+                            trim_fraction,
+                        )
+                        for s in shard_leaves
+                    ]
+                    if defense_score:
+                        partial = functools.reduce(
+                            jnp.add,
+                            [defense_mod.partial_distance_sq(s, c)
+                             for s, c in zip(shard_leaves, center)],
+                        )
+                        scores_w = jnp.where(
+                            mask_w, jnp.sqrt(jax.lax.psum(partial, "dp")), 0.0
+                        )
+                        scores_acc = jnp.where(mask_w, scores_w, scores_acc)
+                    return scores_acc, (tuple(center) if robust_agg else ())
+
+                # The scores follow the gathered masks, which all_gather types
+                # device-varying; the carry has to start out typed the same.
+                scores_all, win_aggs = jax.lax.scan(
+                    win_scan,
+                    _to_varying(jnp.zeros((c_local * dpn,), jnp.float32), "dp"),
+                    jnp.arange(W, dtype=jnp.int32),
+                )
                 if defense_score:
-                    partial = functools.reduce(
-                        jnp.add,
-                        [defense_mod.partial_distance_sq(s, c)
-                         for s, c in zip(shard_leaves, center)],
+                    anomaly_score = jax.lax.dynamic_slice(
+                        scores_all, (jax.lax.axis_index("dp") * c_local,),
+                        (c_local,),
                     )
-                    scores_w = jnp.where(
-                        mask_w, jnp.sqrt(jax.lax.psum(partial, "dp")), 0.0
+                if robust_agg:
+                    delta_shard_stack = jax.tree.unflatten(
+                        treedef, list(win_aggs)
                     )
-                    scores_acc = jnp.where(mask_w, scores_w, scores_acc)
-                return scores_acc, (tuple(center) if robust_agg else ())
+                    if not shard_update:
+                        delta_stack = jax.tree.map(
+                            lambda s, p: jax.vmap(
+                                lambda sh: defense_mod.place_coordinate_shard(
+                                    sh, "dp", dpn, p.shape
+                                )
+                            )(s),
+                            delta_shard_stack, params,
+                        )
+                        delta_shard_stack = None
+                else:
+                    # Score-only defense keeps the weighted-mean aggregate:
+                    # rebuild the (device-local) window buffer from the
+                    # gathered clipped deltas so scoring composes with the
+                    # streaming aggregation below (which does the psum).
+                    buf = jax.tree.map(
+                        lambda a, p: jax.ops.segment_sum(
+                            w_flat[:, None] * a.reshape((c_local, -1)),
+                            wclamp, num_segments=W,
+                        ).reshape((W,) + p.shape),
+                        d_pc, params,
+                    )
 
-            # The scores follow the gathered masks, which all_gather types
-            # device-varying; the carry has to start out typed the same.
-            scores_all, win_aggs = jax.lax.scan(
-                win_scan,
-                _to_varying(jnp.zeros((c_local * dpn,), jnp.float32), "dp"),
-                jnp.arange(W, dtype=jnp.int32),
-            )
-            if defense_score:
-                anomaly_score = jax.lax.dynamic_slice(
-                    scores_all, (jax.lax.axis_index("dp") * c_local,),
-                    (c_local,),
-                )
-            if robust_agg:
-                delta_shard_stack = jax.tree.unflatten(
-                    treedef, list(win_aggs)
-                )
-                if not shard_update:
+            if delta_stack is None and delta_shard_stack is None:
+                # Weighted-mean path: normalize each window by its weight.
+                def normalize(b):
+                    shape = (W,) + (1,) * (b.ndim - 1)
+                    return b / jnp.maximum(buf_w, 1e-8).reshape(shape)
+
+                if shard_update:
+                    # psum_scatter both reduces the device-local partial sums
+                    # over dp AND scatters the coordinates in one collective.
+                    delta_shard_stack = jax.tree.map(
+                        lambda b: jax.lax.psum_scatter(
+                            jax.vmap(lambda l: _flat_pad_leaf(l, dpn))(b),
+                            "dp", scatter_dimension=1, tiled=True,
+                        ) / jnp.maximum(buf_w, 1e-8)[:, None],
+                        buf,
+                    )
+                else:
                     delta_stack = jax.tree.map(
-                        lambda s, p: jax.vmap(
-                            lambda sh: defense_mod.place_coordinate_shard(
-                                sh, "dp", dpn, p.shape
-                            )
-                        )(s),
-                        delta_shard_stack, params,
+                        lambda b: normalize(jax.lax.psum(b, "dp")), buf
                     )
-                    delta_shard_stack = None
-            else:
-                # Score-only defense keeps the weighted-mean aggregate:
-                # rebuild the (device-local) window buffer from the
-                # gathered clipped deltas so scoring composes with the
-                # streaming aggregation below (which does the psum).
-                buf = jax.tree.map(
-                    lambda a, p: jax.ops.segment_sum(
-                        w_flat[:, None] * a.reshape((c_local, -1)),
-                        wclamp, num_segments=W,
-                    ).reshape((W,) + p.shape),
-                    d_pc, params,
-                )
 
-        if delta_stack is None and delta_shard_stack is None:
-            # Weighted-mean path: normalize each window by its weight.
-            def normalize(b):
-                shape = (W,) + (1,) * (b.ndim - 1)
-                return b / jnp.maximum(buf_w, 1e-8).reshape(shape)
+        with jax.named_scope("server_update"):
+            # -------------------------------------------------- commit scan
+            # Sequential staleness-discounted server commits, one per window,
+            # in arrival order. Empty (or fully stale) windows are bitwise
+            # no-ops via tree_where.
+            def commit(carry, inp):
+                p, op = carry
+                d_w, w_w, sw = inp
+                gate = (w_w > 0) & (sw > 0)
+                pseudo = jax.tree.map(
+                    lambda d, q: (-(sw * d)).astype(q.dtype), d_w, p
+                )
+                updates, new_op = alg.server_optimizer.update(pseudo, op, p)
+                new_p = optax.apply_updates(p, updates)
+                p, op = _tree_where(gate, (new_p, new_op), (p, op))
+                return (p, op), gate.astype(jnp.float32)
 
             if shard_update:
-                # psum_scatter both reduces the device-local partial sums
-                # over dp AND scatters the coordinates in one collective.
-                delta_shard_stack = jax.tree.map(
-                    lambda b: jax.lax.psum_scatter(
-                        jax.vmap(lambda l: _flat_pad_leaf(l, dpn))(b),
-                        "dp", scatter_dimension=1, tiled=True,
-                    ) / jnp.maximum(buf_w, 1e-8)[:, None],
-                    buf,
+                from olearning_sim_tpu.engine import defense as defense_mod
+
+                def my_shard(p):
+                    flat = _flat_pad_leaf(p, dpn)
+                    s = flat.shape[0] // dpn
+                    return jax.lax.dynamic_slice(
+                        flat, (jax.lax.axis_index("dp") * s,), (s,)
+                    )
+
+                shard_params0 = jax.tree.map(my_shard, params)
+                opt_in = jax.tree.map(
+                    lambda l, sharded: l if sharded else _to_varying(l, "dp"),
+                    opt_state, core._opt_sharded,
+                )
+                (shard_params, new_opt_state), gates = jax.lax.scan(
+                    commit, (shard_params0, opt_in),
+                    (delta_shard_stack, buf_w, sw_w),
+                )
+                new_opt_state = jax.tree.map(
+                    lambda l, sharded: l if sharded else jax.lax.pmax(l, "dp"),
+                    new_opt_state, core._opt_sharded,
+                )
+                new_params = jax.tree.map(
+                    lambda s, p: defense_mod.place_coordinate_shard(
+                        s, "dp", dpn, p.shape
+                    ),
+                    shard_params, params,
                 )
             else:
-                delta_stack = jax.tree.map(
-                    lambda b: normalize(jax.lax.psum(b, "dp")), buf
+                (new_params, new_opt_state), gates = jax.lax.scan(
+                    commit, (params, opt_state), (delta_stack, buf_w, sw_w),
                 )
-
-        # -------------------------------------------------- commit scan
-        # Sequential staleness-discounted server commits, one per window,
-        # in arrival order. Empty (or fully stale) windows are bitwise
-        # no-ops via tree_where.
-        def commit(carry, inp):
-            p, op = carry
-            d_w, w_w, sw = inp
-            gate = (w_w > 0) & (sw > 0)
-            pseudo = jax.tree.map(
-                lambda d, q: (-(sw * d)).astype(q.dtype), d_w, p
-            )
-            updates, new_op = alg.server_optimizer.update(pseudo, op, p)
-            new_p = optax.apply_updates(p, updates)
-            p, op = _tree_where(gate, (new_p, new_op), (p, op))
-            return (p, op), gate.astype(jnp.float32)
-
-        if shard_update:
-            from olearning_sim_tpu.engine import defense as defense_mod
-
-            def my_shard(p):
-                flat = _flat_pad_leaf(p, dpn)
-                s = flat.shape[0] // dpn
-                return jax.lax.dynamic_slice(
-                    flat, (jax.lax.axis_index("dp") * s,), (s,)
-                )
-
-            shard_params0 = jax.tree.map(my_shard, params)
-            opt_in = jax.tree.map(
-                lambda l, sharded: l if sharded else _to_varying(l, "dp"),
-                opt_state, core._opt_sharded,
-            )
-            (shard_params, new_opt_state), gates = jax.lax.scan(
-                commit, (shard_params0, opt_in),
-                (delta_shard_stack, buf_w, sw_w),
-            )
-            new_opt_state = jax.tree.map(
-                lambda l, sharded: l if sharded else jax.lax.pmax(l, "dp"),
-                new_opt_state, core._opt_sharded,
-            )
-            new_params = jax.tree.map(
-                lambda s, p: defense_mod.place_coordinate_shard(
-                    s, "dp", dpn, p.shape
-                ),
-                shard_params, params,
-            )
-        else:
-            (new_params, new_opt_state), gates = jax.lax.scan(
-                commit, (params, opt_state), (delta_stack, buf_w, sw_w),
-            )
 
         metrics = RoundMetrics(
             mean_loss=sum_loss / jnp.maximum(sum_w, 1e-8),

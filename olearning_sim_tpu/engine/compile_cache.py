@@ -27,9 +27,13 @@ Observability: jax emits ``/jax/compilation_cache/cache_hits`` (persistent
 entry deserialized) and ``/jax/compilation_cache/cache_misses`` (entry
 compiled and written) monitoring events; a process-wide listener mirrors
 them into the cataloged ``ols_engine_compile_cache_hits_total`` /
-``ols_engine_compile_cache_misses_total`` counters.
+``ols_engine_compile_cache_misses_total`` counters, and stamps jax's own
+compile durations onto the default span tracer as ``compile.trace``,
+``compile.lower``, ``compile.backend`` and ``compile.cache_load`` spans
+(:func:`install_listener`), children of whatever span the compiling
+thread has open.
 
-``OLS_COMPILE_CACHE=0`` disables the whole feature.
+``OLS_COMPILE_CACHE=0`` disables the cache; the listener stays.
 """
 
 from __future__ import annotations
@@ -67,6 +71,7 @@ def enable_compile_cache() -> Optional[str]:
     Returns the active directory, or None when disabled
     (``OLS_COMPILE_CACHE=0``) or the directory cannot be created (reported
     on stderr; the process then compiles without a persistent cache)."""
+    install_listener()
     if os.environ.get("OLS_COMPILE_CACHE") == "0":
         return None
     import jax
@@ -89,22 +94,53 @@ def enable_compile_cache() -> Optional[str]:
         # start in aggregate.
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        _install_listener()
         _state["dir"] = directory
     return directory
 
 
-def _install_listener() -> None:
-    """Mirror jax's compilation-cache monitoring events into the metric
-    catalog (one listener per process; jax offers no unregister-by-name,
-    so the flag guards double counting). Counters always land in the
-    PROCESS-DEFAULT registry, resolved per event — a per-caller registry
-    would silently bind to whichever entry point enabled the cache first."""
-    if _state["listener"]:
-        return
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def install_listener() -> None:
+    """Mirror jax's compilation monitoring events into the metric catalog
+    and the span tree (one set of listeners per process; jax offers no
+    unregister-by-name, so the flag guards double counting). Idempotent;
+    :func:`enable_compile_cache` and every runner call it. Counters and
+    spans always land in the PROCESS-DEFAULT registry and tracer, resolved
+    per event — a per-caller sink would silently bind to whichever entry
+    point enabled the cache first.
+
+    The spans are recorded when jax reports the duration, in the compiling
+    thread, so their parent is the span that thread has open (round 0's
+    ``round.<operator>.train``), whose ``task_id`` and ``round_idx`` they
+    copy; a recompile in a later round names its round, phase and
+    ``fun_name``. Only the OUTERMOST interval of a thread is recorded: jax
+    reports one duration per traced jit and the functions of ``jax.numpy``
+    are jits, so a round program's trace holds hundreds of nested ones, its
+    lowering traces more, and a trace that runs an operation eagerly
+    compiles inside itself; each lies inside its caller's interval and the
+    sum would count the time many times over. jax's scalar event at an
+    interval's start carries the depth.
+
+    - ``compile.trace``: Python function to jaxpr.
+    - ``compile.lower``: jaxpr to MLIR module.
+    - ``compile.backend`` / ``compile.cache_load``: jax's backend-compile
+      interval wraps its persistent-cache lookup, so one interval is one or
+      the other: ``compile.cache_load`` (key hashing, read, deserialize;
+      ``retrieval_s`` is jax's own retrieval time) when the lookup hit,
+      else ``compile.backend`` (XLA compilation and the cache write)."""
+    with _lock:
+        if _state["listener"]:
+            return
+        _state["listener"] = True
     from jax import monitoring
 
-    from olearning_sim_tpu.telemetry import instrument
+    from olearning_sim_tpu.telemetry import default_tracer, instrument
+
+    local = threading.local()
 
     def _on_event(event: str, **kwargs) -> None:
         if event == "/jax/compilation_cache/cache_hits":
@@ -112,8 +148,42 @@ def _install_listener() -> None:
         elif event == "/jax/compilation_cache/cache_misses":
             instrument("ols_engine_compile_cache_misses_total").inc()
 
+    names = {TRACE_EVENT: "compile.trace", LOWER_EVENT: "compile.lower",
+             BACKEND_EVENT: "compile.backend"}
+
+    def _on_start(event: str, value, **kwargs) -> None:
+        if event in names:
+            local.depth = getattr(local, "depth", 0) + 1
+
+    def _on_duration(event: str, duration: float, **kwargs) -> None:
+        if event == CACHE_LOAD_EVENT:
+            local.retrieval_s = duration
+            return
+        if event not in names:
+            return
+        name, attrs = names[event], kwargs
+        if event == BACKEND_EVENT:
+            retrieval_s = getattr(local, "retrieval_s", None)
+            local.retrieval_s = None
+            if retrieval_s is not None:
+                name = "compile.cache_load"
+                attrs = dict(kwargs, retrieval_s=retrieval_s)
+        local.depth = depth = getattr(local, "depth", 1) - 1
+        if depth > 0:
+            return
+        tracer = default_tracer()
+        parent = tracer.current()
+        if parent is not None:
+            # The task and round the compile belongs to, so a reader finds
+            # a task's compiles without walking parent links.
+            attrs = dict(attrs, **{k: parent.attrs[k]
+                                   for k in ("task_id", "round_idx")
+                                   if k in parent.attrs})
+        tracer.record(name, tracer.now() - duration, duration, **attrs)
+
     monitoring.register_event_listener(_on_event)
-    _state["listener"] = True
+    monitoring.register_scalar_listener(_on_start)
+    monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def cache_stats() -> dict:

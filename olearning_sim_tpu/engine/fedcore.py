@@ -30,6 +30,7 @@ the ``weight``/``num_steps`` arrays, produced by the deviceflow trace compiler.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Callable, Optional, Tuple
@@ -276,6 +277,20 @@ def parse_float_dtype(knob: str, value):
             f"fedcore.{knob} must be a floating dtype, got {dt.name!r}"
         )
     return dt
+
+
+def _accumulate_delta(sum_delta, deltas, bw_eff, gate):
+    """``sum_delta + bw_eff . gate(f32(delta))`` leaf by leaf: the finiteness
+    gate and float32 cast of each client delta (scope ``delta_transform``)
+    and its weighted sum into the round's accumulator (scope
+    ``aggregate``)."""
+    def one(s, d):
+        with jax.named_scope("delta_transform"):
+            d = gate(d.astype(jnp.float32))
+        with jax.named_scope("aggregate"):
+            return s + jnp.tensordot(bw_eff, d, axes=(0, 0))
+
+    return jax.tree.map(one, sum_delta, deltas)
 
 
 def _to_varying(tree, axis: str):
@@ -939,22 +954,27 @@ class FedCore:
                     sum_delta, sum_w, sum_loss, count, sum_ploss, sum_dc = carry
                     n_clip = None
                 bx, by, bns, bst, buid, bw, bvp, batk = inp
-                if controlled:
-                    deltas, losses, dcis = jax.vmap(
-                        self._local_train,
-                        in_axes=(None, 0, 0, 0, 0, 0, None, None, None, 0),
-                    )(params, bx, by, bns, bst, buid, base_key, round_idx,
-                      server_c, bvp)
-                else:
-                    deltas, losses = jax.vmap(
-                        self._local_train,
-                        in_axes=(None, 0, 0, 0, 0, 0, None, None),
-                    )(params, bx, by, bns, bst, buid, base_key, round_idx)
-                if with_attack:
-                    deltas = _attack_deltas(deltas, batk)
-                # Resilience gate (_finite_client_mask): a diverged client
-                # contributes nothing, finite clients bitwise unchanged.
-                ok = _finite_client_mask(losses, deltas)
+                with jax.named_scope("client_train"):
+                    if controlled:
+                        deltas, losses, dcis = jax.vmap(
+                            self._local_train,
+                            in_axes=(None, 0, 0, 0, 0, 0, None, None, None,
+                                     0),
+                        )(params, bx, by, bns, bst, buid, base_key,
+                          round_idx, server_c, bvp)
+                    else:
+                        deltas, losses = jax.vmap(
+                            self._local_train,
+                            in_axes=(None, 0, 0, 0, 0, 0, None, None),
+                        )(params, bx, by, bns, bst, buid, base_key,
+                          round_idx)
+                with jax.named_scope("delta_transform"):
+                    if with_attack:
+                        deltas = _attack_deltas(deltas, batk)
+                    # Resilience gate (_finite_client_mask): a diverged
+                    # client contributes nothing, finite clients bitwise
+                    # unchanged.
+                    ok = _finite_client_mask(losses, deltas)
 
                 def gate(d):
                     return jnp.where(
@@ -964,29 +984,28 @@ class FedCore:
                 bw_eff = jnp.where(ok, bw, 0.0)
                 defense_ys = None
                 if defense is not None:
-                    d32 = jax.tree.map(
-                        lambda d: gate(d.astype(jnp.float32)), deltas
-                    )
-                    d32, too_big = _clip_client_deltas(d32, clip_norm)
-                    n_clip = n_clip + jnp.logical_and(
-                        bw_eff > 0, too_big
-                    ).sum().astype(jnp.float32)
-                    sum_delta = jax.tree.map(
-                        lambda s, d: s + jnp.tensordot(bw_eff, d, axes=(0, 0)),
-                        sum_delta, d32,
-                    )
+                    with jax.named_scope("delta_transform"):
+                        d32 = jax.tree.map(
+                            lambda d: gate(d.astype(jnp.float32)), deltas
+                        )
+                        d32, too_big = _clip_client_deltas(d32, clip_norm)
+                        n_clip = n_clip + jnp.logical_and(
+                            bw_eff > 0, too_big
+                        ).sum().astype(jnp.float32)
+                    with jax.named_scope("aggregate"):
+                        sum_delta = jax.tree.map(
+                            lambda s, d: s + jnp.tensordot(
+                                bw_eff, d, axes=(0, 0)),
+                            sum_delta, d32,
+                        )
                     if defense_gather:
                         # The gathering aggregators/scores need every
                         # client's (gated, clipped) delta — emitted from the
                         # scan and all-gathered after it.
                         defense_ys = (d32, bw_eff)
                 else:
-                    sum_delta = jax.tree.map(
-                        lambda s, d: s + jnp.tensordot(
-                            bw_eff, gate(d.astype(jnp.float32)), axes=(0, 0)
-                        ),
-                        sum_delta, deltas,
-                    )
+                    sum_delta = _accumulate_delta(sum_delta, deltas, bw_eff,
+                                                  gate)
                 sum_w = sum_w + bw_eff.sum()
                 sum_loss = sum_loss + jnp.where(ok, bw * losses, 0.0).sum()
                 count = count + (bw_eff > 0).sum().astype(jnp.float32)
@@ -1010,11 +1029,12 @@ class FedCore:
                     )
                     ys = (losses, new_bvp)
                 elif personalized:
-                    new_vp, plosses = jax.vmap(
-                        self._personal_train,
-                        in_axes=(0, None, 0, 0, 0, 0, 0, 0, None, None),
-                    )(bvp, params, bx, by, bns, bst, buid, bw > 0,
-                      base_key, round_idx)
+                    with jax.named_scope("client_train"):
+                        new_vp, plosses = jax.vmap(
+                            self._personal_train,
+                            in_axes=(0, None, 0, 0, 0, 0, 0, 0, None, None),
+                        )(bvp, params, bx, by, bns, bst, buid, bw > 0,
+                          base_key, round_idx)
                     # Keep a client's previous personal params when its
                     # personal branch diverged — a non-finite v_k would
                     # otherwise stay poisoned forever. For participating
@@ -1061,169 +1081,173 @@ class FedCore:
                     lambda a: a.reshape((c_local,) + a.shape[2:]), new_vparams
                 )
 
-            # Cross-device FedAvg: the Pulsar gradient transport of the
-            # reference becomes one collective over the dp axis of the ICI
-            # mesh — a full psum of the weighted delta on the replicated
-            # path, or a reduce-scatter (each chip keeps the cross-replica
-            # sum for its 1/dp of the coordinates) under the sharded
-            # server update.
-            sum_w = jax.lax.psum(sum_w, "dp")
-            sum_loss = jax.lax.psum(sum_loss, "dp")
-            count = jax.lax.psum(count, "dp")
-            sum_ploss = jax.lax.psum(sum_ploss, "dp")
-            if defense is not None:
-                n_clip = jax.lax.psum(n_clip, "dp")
+            with jax.named_scope("aggregate"):
+                # Cross-device FedAvg: the Pulsar gradient transport of the
+                # reference becomes one collective over the dp axis of the ICI
+                # mesh — a full psum of the weighted delta on the replicated
+                # path, or a reduce-scatter (each chip keeps the cross-replica
+                # sum for its 1/dp of the coordinates) under the sharded
+                # server update.
+                sum_w = jax.lax.psum(sum_w, "dp")
+                sum_loss = jax.lax.psum(sum_loss, "dp")
+                count = jax.lax.psum(count, "dp")
+                sum_ploss = jax.lax.psum(sum_ploss, "dp")
+                if defense is not None:
+                    n_clip = jax.lax.psum(n_clip, "dp")
 
-            denom = jnp.maximum(sum_w, 1e-8)
-            mean_delta = delta_shards = None
-            if not (defense_gather and robust_agg):
-                # Weighted-mean aggregation (a robust aggregator replaces
-                # it entirely below, so its collective is skipped then).
-                if shard_update:
-                    delta_shards = jax.tree.map(
-                        lambda s: jax.lax.psum_scatter(
-                            _flat_pad_leaf(s, dpn), "dp",
-                            scatter_dimension=0, tiled=True,
-                        ) / denom,
-                        sum_delta,
-                    )
-                else:
-                    sum_delta = jax.lax.psum(sum_delta, "dp")
-                    mean_delta = jax.tree.map(lambda s: s / denom, sum_delta)
-            anomaly_score = jnp.float32(0.0)
-            if defense_gather:
-                # Sharded robust aggregation: one all_to_all re-lays the
-                # clipped per-client deltas so THIS device holds every
-                # client for 1/dp of the coordinates — peak
-                # O(clients x params / dp) instead of the full
-                # O(clients x params) matrix an all_gather would
-                # replicate. Each coordinate's client column is intact, so
-                # the per-coordinate sort/window statistics are bit-for-bit
-                # those of the gathered formulation.
-                from olearning_sim_tpu.engine import defense as defense_mod
-
-                d_pc, w_pc = defense_out
-                # The participant mask is the only thing replicated in
-                # full — O(clients) bytes.
-                w_all = jax.lax.all_gather(
-                    w_pc.reshape((c_local,)), "dp", tiled=True
-                )
-                participants = w_all > 0
-                shards = jax.tree.map(
-                    lambda a: defense_mod.shard_client_deltas(
-                        a.reshape((c_local,) + a.shape[2:]), "dp", dpn
-                    ),
-                    d_pc,
-                )
-                center_shards = None
-                if robust_agg:
-                    agg_shards = jax.tree.map(
-                        lambda s: defense_mod.robust_leaf_aggregate(
-                            s, participants, aggregator, trim_fraction
-                        ),
-                        shards,
-                    )
-                    if aggregator == "median":
-                        center_shards = agg_shards
+                denom = jnp.maximum(sum_w, 1e-8)
+                mean_delta = delta_shards = None
+                if not (defense_gather and robust_agg):
+                    # Weighted-mean aggregation (a robust aggregator replaces
+                    # it entirely below, so its collective is skipped then).
                     if shard_update:
-                        # Same coordinate partition as the sharded server
-                        # update (_flat_pad_leaf pads identically), so the
-                        # robust aggregate feeds the sharded optimizer
-                        # directly — no reconstruction collective at all.
-                        delta_shards = agg_shards
-                    else:
-                        mean_delta = jax.tree.map(
-                            lambda s, p: defense_mod.place_coordinate_shard(
-                                s, "dp", dpn, p.shape
-                            ),
-                            agg_shards, params,
+                        delta_shards = jax.tree.map(
+                            lambda s: jax.lax.psum_scatter(
+                                _flat_pad_leaf(s, dpn), "dp",
+                                scatter_dimension=0, tiled=True,
+                            ) / denom,
+                            sum_delta,
                         )
-                if defense_score:
-                    if center_shards is None:
-                        center_shards = jax.tree.map(
+                    else:
+                        sum_delta = jax.lax.psum(sum_delta, "dp")
+                        mean_delta = jax.tree.map(
+                            lambda s: s / denom, sum_delta
+                        )
+                anomaly_score = jnp.float32(0.0)
+                if defense_gather:
+                    # Sharded robust aggregation: one all_to_all re-lays the
+                    # clipped per-client deltas so THIS device holds every
+                    # client for 1/dp of the coordinates — peak
+                    # O(clients x params / dp) instead of the full
+                    # O(clients x params) matrix an all_gather would
+                    # replicate. Each coordinate's client column is intact, so
+                    # the per-coordinate sort/window statistics are bit-for-bit
+                    # those of the gathered formulation.
+                    from olearning_sim_tpu.engine import defense as defense_mod
+
+                    d_pc, w_pc = defense_out
+                    # The participant mask is the only thing replicated in
+                    # full — O(clients) bytes.
+                    w_all = jax.lax.all_gather(
+                        w_pc.reshape((c_local,)), "dp", tiled=True
+                    )
+                    participants = w_all > 0
+                    shards = jax.tree.map(
+                        lambda a: defense_mod.shard_client_deltas(
+                            a.reshape((c_local,) + a.shape[2:]), "dp", dpn
+                        ),
+                        d_pc,
+                    )
+                    center_shards = None
+                    if robust_agg:
+                        agg_shards = jax.tree.map(
                             lambda s: defense_mod.robust_leaf_aggregate(
-                                s, participants, "median", trim_fraction
+                                s, participants, aggregator, trim_fraction
                             ),
                             shards,
                         )
-                    # Krum-style distances from per-shard partial squared
-                    # distances combined with one psum; sqrt after the sum
-                    # recovers the gathered formulation's scores.
-                    partial = functools.reduce(
-                        jnp.add,
-                        [defense_mod.partial_distance_sq(s, c)
-                         for s, c in zip(jax.tree.leaves(shards),
-                                         jax.tree.leaves(center_shards))],
-                    )
-                    scores = jnp.where(
-                        participants,
-                        jnp.sqrt(jax.lax.psum(partial, "dp")),
-                        0.0,
-                    )
-                    # Each shard exits with its own clients' scores (same
-                    # layout as client_loss).
-                    anomaly_score = jax.lax.dynamic_slice(
-                        scores,
-                        (jax.lax.axis_index("dp") * c_local,),
-                        (c_local,),
-                    )
-            # Server optimizer consumes the negative mean delta as a
-            # pseudo-gradient (FedOpt formulation).
-            if shard_update:
-                # Cross-replica sharded weight update (arXiv 2004.13336):
-                # update THIS chip's 1/dp coordinate slice with the
-                # optimizer state that lives sharded the same way, then
-                # stitch the fresh params from the disjoint shards (exact
-                # — each coordinate has exactly one contributor).
-                from olearning_sim_tpu.engine import defense as defense_mod
+                        if aggregator == "median":
+                            center_shards = agg_shards
+                        if shard_update:
+                            # Same coordinate partition as the sharded server
+                            # update (_flat_pad_leaf pads identically), so the
+                            # robust aggregate feeds the sharded optimizer
+                            # directly — no reconstruction collective at all.
+                            delta_shards = agg_shards
+                        else:
+                            mean_delta = jax.tree.map(
+                                lambda s, p: defense_mod.place_coordinate_shard(
+                                    s, "dp", dpn, p.shape
+                                ),
+                                agg_shards, params,
+                            )
+                    if defense_score:
+                        if center_shards is None:
+                            center_shards = jax.tree.map(
+                                lambda s: defense_mod.robust_leaf_aggregate(
+                                    s, participants, "median", trim_fraction
+                                ),
+                                shards,
+                            )
+                        # Krum-style distances from per-shard partial squared
+                        # distances combined with one psum; sqrt after the sum
+                        # recovers the gathered formulation's scores.
+                        partial = functools.reduce(
+                            jnp.add,
+                            [defense_mod.partial_distance_sq(s, c)
+                             for s, c in zip(jax.tree.leaves(shards),
+                                             jax.tree.leaves(center_shards))],
+                        )
+                        scores = jnp.where(
+                            participants,
+                            jnp.sqrt(jax.lax.psum(partial, "dp")),
+                            0.0,
+                        )
+                        # Each shard exits with its own clients' scores (same
+                        # layout as client_loss).
+                        anomaly_score = jax.lax.dynamic_slice(
+                            scores,
+                            (jax.lax.axis_index("dp") * c_local,),
+                            (c_local,),
+                        )
+            with jax.named_scope("server_update"):
+                # Server optimizer consumes the negative mean delta as a
+                # pseudo-gradient (FedOpt formulation).
+                if shard_update:
+                    # Cross-replica sharded weight update (arXiv 2004.13336):
+                    # update THIS chip's 1/dp coordinate slice with the
+                    # optimizer state that lives sharded the same way, then
+                    # stitch the fresh params from the disjoint shards (exact
+                    # — each coordinate has exactly one contributor).
+                    from olearning_sim_tpu.engine import defense as defense_mod
 
-                def my_shard(p):
-                    flat = _flat_pad_leaf(p, dpn)
-                    s = flat.shape[0] // dpn
-                    return jax.lax.dynamic_slice(
-                        flat, (jax.lax.axis_index("dp") * s,), (s,)
-                    )
+                    def my_shard(p):
+                        flat = _flat_pad_leaf(p, dpn)
+                        s = flat.shape[0] // dpn
+                        return jax.lax.dynamic_slice(
+                            flat, (jax.lax.axis_index("dp") * s,), (s,)
+                        )
 
-                shard_params = jax.tree.map(my_shard, params)
-                pseudo_grad = jax.tree.map(
-                    lambda d, p: (-d).astype(p.dtype),
-                    delta_shards, shard_params,
-                )
-                # Replicated state (Adam's count) stays whole on every
-                # chip; type it varying on entry and re-type on exit (pmax
-                # over identical values — a bitwise no-op) so it can cross
-                # the sharded update on VMA runtimes. The sharded/
-                # replicated split comes from the build-time template
-                # (self._opt_sharded) — a shape test here would see
-                # shard-LOCAL leaves and misclassify them.
-                opt_in = jax.tree.map(
-                    lambda l, sharded: l if sharded
-                    else _to_varying(l, "dp"),
-                    opt_state, self._opt_sharded,
-                )
-                updates, new_opt_state = alg.server_optimizer.update(
-                    pseudo_grad, opt_in, shard_params
-                )
-                new_shards = optax.apply_updates(shard_params, updates)
-                new_opt_state = jax.tree.map(
-                    lambda l, sharded: l if sharded
-                    else jax.lax.pmax(l, "dp"),
-                    new_opt_state, self._opt_sharded,
-                )
-                new_params = jax.tree.map(
-                    lambda s, p: defense_mod.place_coordinate_shard(
-                        s, "dp", dpn, p.shape
-                    ),
-                    new_shards, params,
-                )
-            else:
-                pseudo_grad = jax.tree.map(
-                    lambda d, p: (-d).astype(p.dtype), mean_delta, params
-                )
-                updates, new_opt_state = alg.server_optimizer.update(
-                    pseudo_grad, opt_state, params
-                )
-                new_params = optax.apply_updates(params, updates)
+                    shard_params = jax.tree.map(my_shard, params)
+                    pseudo_grad = jax.tree.map(
+                        lambda d, p: (-d).astype(p.dtype),
+                        delta_shards, shard_params,
+                    )
+                    # Replicated state (Adam's count) stays whole on every
+                    # chip; type it varying on entry and re-type on exit (pmax
+                    # over identical values — a bitwise no-op) so it can cross
+                    # the sharded update on VMA runtimes. The sharded/
+                    # replicated split comes from the build-time template
+                    # (self._opt_sharded) — a shape test here would see
+                    # shard-LOCAL leaves and misclassify them.
+                    opt_in = jax.tree.map(
+                        lambda l, sharded: l if sharded
+                        else _to_varying(l, "dp"),
+                        opt_state, self._opt_sharded,
+                    )
+                    updates, new_opt_state = alg.server_optimizer.update(
+                        pseudo_grad, opt_in, shard_params
+                    )
+                    new_shards = optax.apply_updates(shard_params, updates)
+                    new_opt_state = jax.tree.map(
+                        lambda l, sharded: l if sharded
+                        else jax.lax.pmax(l, "dp"),
+                        new_opt_state, self._opt_sharded,
+                    )
+                    new_params = jax.tree.map(
+                        lambda s, p: defense_mod.place_coordinate_shard(
+                            s, "dp", dpn, p.shape
+                        ),
+                        new_shards, params,
+                    )
+                else:
+                    pseudo_grad = jax.tree.map(
+                        lambda d, p: (-d).astype(p.dtype), mean_delta, params
+                    )
+                    updates, new_opt_state = alg.server_optimizer.update(
+                        pseudo_grad, opt_state, params
+                    )
+                    new_params = optax.apply_updates(params, updates)
             new_server_c = None
             if controlled:
                 # c <- c + (|S|/N) * weighted-mean dc_i (SCAFFOLD eq. 5 with
@@ -1492,23 +1516,27 @@ class FedCore:
                     sum_delta, sum_w, sum_loss, count, sum_ploss, sum_dc = carry
                     n_clip = None
                 bx, by, bns, bst, buid, bw, bvp, batk = inp
-                if controlled:
-                    deltas, losses, dcis = jax.vmap(
-                        train_fn,
-                        in_axes=(None, 0, 0, 0, 0, 0, None, None, None, 0),
-                    )(params, bx, by, bns, bst, buid, base_key, round_idx,
-                      server_c, bvp)
-                else:
-                    deltas, losses = jax.vmap(
-                        train_fn,
-                        in_axes=(None, 0, 0, 0, 0, 0, None, None),
-                    )(params, bx, by, bns, bst, buid, base_key, round_idx)
-                # Per-client deltas pinned to (dp over clients, mp per
-                # specs) straight out of the vmapped train body.
-                deltas = pin_clients(deltas)
-                if with_attack:
-                    deltas = _attack_deltas(deltas, batk)
-                ok = _finite_client_mask(losses, deltas)
+                with jax.named_scope("client_train"):
+                    if controlled:
+                        deltas, losses, dcis = jax.vmap(
+                            train_fn,
+                            in_axes=(None, 0, 0, 0, 0, 0, None, None, None,
+                                     0),
+                        )(params, bx, by, bns, bst, buid, base_key,
+                          round_idx, server_c, bvp)
+                    else:
+                        deltas, losses = jax.vmap(
+                            train_fn,
+                            in_axes=(None, 0, 0, 0, 0, 0, None, None),
+                        )(params, bx, by, bns, bst, buid, base_key,
+                          round_idx)
+                    # Per-client deltas pinned to (dp over clients, mp per
+                    # specs) straight out of the vmapped train body.
+                    deltas = pin_clients(deltas)
+                with jax.named_scope("delta_transform"):
+                    if with_attack:
+                        deltas = _attack_deltas(deltas, batk)
+                    ok = _finite_client_mask(losses, deltas)
 
                 def gate(d):
                     return jnp.where(
@@ -1517,24 +1545,23 @@ class FedCore:
 
                 bw_eff = jnp.where(ok, bw, 0.0)
                 if defense is not None:
-                    d32 = jax.tree.map(
-                        lambda d: gate(d.astype(jnp.float32)), deltas
-                    )
-                    d32, too_big = _clip_client_deltas(d32, clip_norm)
-                    n_clip = n_clip + jnp.logical_and(
-                        bw_eff > 0, too_big
-                    ).sum().astype(jnp.float32)
-                    sum_delta = jax.tree.map(
-                        lambda s, d: s + jnp.tensordot(bw_eff, d, axes=(0, 0)),
-                        sum_delta, d32,
-                    )
+                    with jax.named_scope("delta_transform"):
+                        d32 = jax.tree.map(
+                            lambda d: gate(d.astype(jnp.float32)), deltas
+                        )
+                        d32, too_big = _clip_client_deltas(d32, clip_norm)
+                        n_clip = n_clip + jnp.logical_and(
+                            bw_eff > 0, too_big
+                        ).sum().astype(jnp.float32)
+                    with jax.named_scope("aggregate"):
+                        sum_delta = jax.tree.map(
+                            lambda s, d: s + jnp.tensordot(
+                                bw_eff, d, axes=(0, 0)),
+                            sum_delta, d32,
+                        )
                 else:
-                    sum_delta = jax.tree.map(
-                        lambda s, d: s + jnp.tensordot(
-                            bw_eff, gate(d.astype(jnp.float32)), axes=(0, 0)
-                        ),
-                        sum_delta, deltas,
-                    )
+                    sum_delta = _accumulate_delta(sum_delta, deltas, bw_eff,
+                                                  gate)
                 sum_delta = pin_params(sum_delta)
                 sum_w = sum_w + bw_eff.sum()
                 sum_loss = sum_loss + jnp.where(ok, bw * losses, 0.0).sum()
@@ -1556,11 +1583,12 @@ class FedCore:
                     )
                     ys = (losses, new_bvp)
                 elif personalized:
-                    new_vp, plosses = jax.vmap(
-                        self._personal_train,
-                        in_axes=(0, None, 0, 0, 0, 0, 0, 0, None, None),
-                    )(bvp, params, bx, by, bns, bst, buid, bw > 0,
-                      base_key, round_idx)
+                    with jax.named_scope("client_train"):
+                        new_vp, plosses = jax.vmap(
+                            self._personal_train,
+                            in_axes=(0, None, 0, 0, 0, 0, 0, 0, None, None),
+                        )(bvp, params, bx, by, bns, bst, buid, bw > 0,
+                          base_key, round_idx)
                     okp = jnp.isfinite(plosses)
                     for d in jax.tree.leaves(new_vp):
                         okp = jnp.logical_and(
@@ -1606,27 +1634,37 @@ class FedCore:
             # is a GSPMD-inserted collective here.
             denom = jnp.maximum(sum_w, 1e-8)
             if shard_update:
-                # Flat (dp, mp) coordinate shards straight from the
-                # weighted sum (O(params/(dp*mp)) optimizer state).
-                flat_sh = NamedSharding(mesh, P(("dp", "mp")))
-                delta_flat = jax.tree.map(
-                    lambda s: wsc(
-                        _flat_pad_leaf(s, self._shard_pad), flat_sh
-                    ) / denom,
-                    sum_delta,
-                )
-                new_params, new_opt_state = self._apply_auto_sharded_update(
-                    params, opt_state, delta_flat
-                )
+                with jax.named_scope("aggregate"):
+                    # Flat (dp, mp) coordinate shards straight from the
+                    # weighted sum (O(params/(dp*mp)) optimizer state).
+                    flat_sh = NamedSharding(mesh, P(("dp", "mp")))
+                    delta_flat = jax.tree.map(
+                        lambda s: wsc(
+                            _flat_pad_leaf(s, self._shard_pad), flat_sh
+                        ) / denom,
+                        sum_delta,
+                    )
+                with jax.named_scope("server_update"):
+                    new_params, new_opt_state = (
+                        self._apply_auto_sharded_update(
+                            params, opt_state, delta_flat
+                        )
+                    )
             else:
-                mean_delta = jax.tree.map(lambda s: s / denom, sum_delta)
-                pseudo_grad = jax.tree.map(
-                    lambda d, p: (-d).astype(p.dtype), mean_delta, params
-                )
-                updates, new_opt_state = alg.server_optimizer.update(
-                    pseudo_grad, opt_state, params
-                )
-                new_params = pin_params(optax.apply_updates(params, updates))
+                with jax.named_scope("aggregate"):
+                    mean_delta = jax.tree.map(
+                        lambda s: s / denom, sum_delta
+                    )
+                with jax.named_scope("server_update"):
+                    pseudo_grad = jax.tree.map(
+                        lambda d, p: (-d).astype(p.dtype), mean_delta, params
+                    )
+                    updates, new_opt_state = alg.server_optimizer.update(
+                        pseudo_grad, opt_state, params
+                    )
+                    new_params = pin_params(
+                        optax.apply_updates(params, updates)
+                    )
             new_server_c = None
             if controlled:
                 frac = count / jnp.maximum(true_n, 1.0)
@@ -2226,13 +2264,15 @@ class FedCore:
                     sum_delta, sum_w, sum_loss, count = carry
                     n_clip = None
                 bx, by, bns, bst, buid, bw, batk = inp
-                deltas, losses = jax.vmap(
-                    self._local_train,
-                    in_axes=(None, 0, 0, 0, 0, 0, None, None),
-                )(params, bx, by, bns, bst, buid, base_key, round_idx)
-                if with_attack:
-                    deltas = _attack_deltas(deltas, batk)
-                ok = _finite_client_mask(losses, deltas)
+                with jax.named_scope("client_train"):
+                    deltas, losses = jax.vmap(
+                        self._local_train,
+                        in_axes=(None, 0, 0, 0, 0, 0, None, None),
+                    )(params, bx, by, bns, bst, buid, base_key, round_idx)
+                with jax.named_scope("delta_transform"):
+                    if with_attack:
+                        deltas = _attack_deltas(deltas, batk)
+                    ok = _finite_client_mask(losses, deltas)
 
                 def gate(d):
                     return jnp.where(
@@ -2241,24 +2281,23 @@ class FedCore:
 
                 bw_eff = jnp.where(ok, bw, 0.0)
                 if defense is not None:
-                    d32 = jax.tree.map(
-                        lambda d: gate(d.astype(jnp.float32)), deltas
-                    )
-                    d32, too_big = _clip_client_deltas(d32, clip_norm)
-                    n_clip = n_clip + jnp.logical_and(
-                        bw_eff > 0, too_big
-                    ).sum().astype(jnp.float32)
-                    sum_delta = jax.tree.map(
-                        lambda s, d: s + jnp.tensordot(bw_eff, d, axes=(0, 0)),
-                        sum_delta, d32,
-                    )
+                    with jax.named_scope("delta_transform"):
+                        d32 = jax.tree.map(
+                            lambda d: gate(d.astype(jnp.float32)), deltas
+                        )
+                        d32, too_big = _clip_client_deltas(d32, clip_norm)
+                        n_clip = n_clip + jnp.logical_and(
+                            bw_eff > 0, too_big
+                        ).sum().astype(jnp.float32)
+                    with jax.named_scope("aggregate"):
+                        sum_delta = jax.tree.map(
+                            lambda s, d: s + jnp.tensordot(
+                                bw_eff, d, axes=(0, 0)),
+                            sum_delta, d32,
+                        )
                 else:
-                    sum_delta = jax.tree.map(
-                        lambda s, d: s + jnp.tensordot(
-                            bw_eff, gate(d.astype(jnp.float32)), axes=(0, 0)
-                        ),
-                        sum_delta, deltas,
-                    )
+                    sum_delta = _accumulate_delta(sum_delta, deltas, bw_eff,
+                                                  gate)
                 sum_w = sum_w + bw_eff.sum()
                 sum_loss = sum_loss + jnp.where(ok, bw * losses, 0.0).sum()
                 count = count + (bw_eff > 0).sum().astype(jnp.float32)
@@ -2299,24 +2338,26 @@ class FedCore:
             # the resident program (each device's partial is its
             # monolithic scan total, so the psum reduces the identical
             # operands).
-            sum_w = jax.lax.psum(sum_w, "dp")
-            sum_loss = jax.lax.psum(sum_loss, "dp")
-            count = jax.lax.psum(count, "dp")
-            stragglers = jax.lax.psum(stragglers, "dp")
-            if n_clip is not None:
-                n_clip = jax.lax.psum(n_clip[0], "dp")
-            else:
-                n_clip = jnp.float32(0.0)
-            sum_delta = jax.lax.psum(sum_delta, "dp")
-            denom = jnp.maximum(sum_w, 1e-8)
-            mean_delta = jax.tree.map(lambda s: s / denom, sum_delta)
-            pseudo_grad = jax.tree.map(
-                lambda d, p: (-d).astype(p.dtype), mean_delta, params
-            )
-            updates, new_opt_state = alg.server_optimizer.update(
-                pseudo_grad, opt_state, params
-            )
-            new_params = optax.apply_updates(params, updates)
+            with jax.named_scope("aggregate"):
+                sum_w = jax.lax.psum(sum_w, "dp")
+                sum_loss = jax.lax.psum(sum_loss, "dp")
+                count = jax.lax.psum(count, "dp")
+                stragglers = jax.lax.psum(stragglers, "dp")
+                if n_clip is not None:
+                    n_clip = jax.lax.psum(n_clip[0], "dp")
+                else:
+                    n_clip = jnp.float32(0.0)
+                sum_delta = jax.lax.psum(sum_delta, "dp")
+                denom = jnp.maximum(sum_w, 1e-8)
+                mean_delta = jax.tree.map(lambda s: s / denom, sum_delta)
+            with jax.named_scope("server_update"):
+                pseudo_grad = jax.tree.map(
+                    lambda d, p: (-d).astype(p.dtype), mean_delta, params
+                )
+                updates, new_opt_state = alg.server_optimizer.update(
+                    pseudo_grad, opt_state, params
+                )
+                new_params = optax.apply_updates(params, updates)
             metrics = RoundMetrics(
                 mean_loss=sum_loss / denom,
                 weight_sum=sum_w,
@@ -2729,9 +2770,12 @@ class FedCore:
     def _build_evaluate(self):
         @jax.jit
         def evaluate(params, x, y):
-            logits = self.apply_fn(params, x)
-            loss = optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
-            acc = (logits.argmax(-1) == y).mean()
+            with jax.named_scope("evaluate"):
+                logits = self.apply_fn(params, x)
+                loss = optax.softmax_cross_entropy_with_integer_labels(
+                    logits, y
+                ).mean()
+                acc = (logits.argmax(-1) == y).mean()
             return loss, acc
 
         return evaluate
@@ -2889,17 +2933,27 @@ class FedCore:
         )
         return float(loss), float(acc)
 
-    def evaluate(self, params, x, y) -> Tuple[float, float]:
-        """Centralized eval of the global model, batched on device."""
+    def evaluate(self, params, x, y, stage=None) -> Tuple[float, float]:
+        """Centralized eval of the global model, batched on device.
+        ``stage`` — ``stage(name)`` gives a context manager entered around
+        each batch's ``place`` (data to the device), ``compute`` (dispatch
+        of the evaluate program) and ``fetch`` (the blocking read of loss
+        and accuracy); the runner passes its span factory."""
+        if stage is None:
+            stage = lambda name: contextlib.nullcontext()  # noqa: E731
         bs = self.config.eval_batch_size
         n = x.shape[0]
         losses, accs, seen = [], [], 0
         for i in range(0, n, bs):
-            xb, yb = x[i : i + bs], y[i : i + bs]
-            l, a = self._evaluate(params, jnp.asarray(xb), jnp.asarray(yb))
-            w = len(yb)
-            losses.append(float(l) * w)
-            accs.append(float(a) * w)
+            with stage("place"):
+                xb = jnp.asarray(x[i : i + bs])
+                yb = jnp.asarray(y[i : i + bs])
+            with stage("compute"):
+                l, a = self._evaluate(params, xb, yb)
+            with stage("fetch"):
+                w = len(yb)
+                losses.append(float(l) * w)
+                accs.append(float(a) * w)
             seen += w
         return sum(losses) / seen, sum(accs) / seen
 

@@ -122,6 +122,7 @@ def build_pp_round_step(core, model, microbatches):
     from olearning_sim_tpu.engine.fedcore import (
         RoundMetrics,
         ServerState,
+        _accumulate_delta,
         _finite_client_mask,
         _tree_l2_sq,
     )
@@ -243,9 +244,10 @@ def build_pp_round_step(core, model, microbatches):
         def block_step(carry, inp):
             sum_delta, sum_w, sum_loss, count = carry
             bx, by, bns, bst, buid, bw = inp
-            deltas, losses = jax.vmap(
-                local_train, in_axes=(0, 0, 0, 0, 0)
-            )(bx, by, bns, bst, buid)
+            with jax.named_scope("client_train"):
+                deltas, losses = jax.vmap(
+                    local_train, in_axes=(0, 0, 0, 0, 0)
+                )(bx, by, bns, bst, buid)
             # Resilience gate: a diverged client contributes nothing
             # (same helper as the dense program). The mask must agree
             # across pp stages — a non-finite value confined to ONE
@@ -261,12 +263,7 @@ def build_pp_round_step(core, model, microbatches):
                 )
 
             bw_eff = jnp.where(ok, bw, 0.0)
-            sum_delta = jax.tree.map(
-                lambda s, d: s + jnp.tensordot(
-                    bw_eff, gate(d.astype(jnp.float32)), axes=(0, 0)
-                ),
-                sum_delta, deltas,
-            )
+            sum_delta = _accumulate_delta(sum_delta, deltas, bw_eff, gate)
             sum_w = sum_w + bw_eff.sum()
             sum_loss = sum_loss + jnp.where(ok, bw * losses, 0.0).sum()
             count = count + (bw_eff > 0).sum().astype(jnp.float32)
@@ -281,10 +278,11 @@ def build_pp_round_step(core, model, microbatches):
         # deltas are stage-identical after grad_fix's psum, the block
         # deltas stage-local slices), so the cross-replica reduction is a
         # psum over dp only.
-        sum_w = jax.lax.psum(sum_w, "dp")
-        sum_loss = jax.lax.psum(sum_loss, "dp")
-        count = jax.lax.psum(count, "dp")
-        sum_delta = jax.lax.psum(sum_delta, "dp")
+        with jax.named_scope("aggregate"):
+            sum_w = jax.lax.psum(sum_w, "dp")
+            sum_loss = jax.lax.psum(sum_loss, "dp")
+            count = jax.lax.psum(count, "dp")
+            sum_delta = jax.lax.psum(sum_delta, "dp")
         return (sum_delta["rest"], sum_delta["blocks"], sum_w, sum_loss,
                 count, client_loss)
 
@@ -307,20 +305,22 @@ def build_pp_round_step(core, model, microbatches):
             x, y, num_samples, num_steps, uid, weight,
         )
         denom = jnp.maximum(sum_w, 1e-8)
-        mean_delta = unstack_block_params(
-            jax.tree.map(lambda s: s / denom, d_rest),
-            jax.tree.map(lambda s: s / denom, d_blocks),
-        )
+        with jax.named_scope("aggregate"):
+            mean_delta = unstack_block_params(
+                jax.tree.map(lambda s: s / denom, d_rest),
+                jax.tree.map(lambda s: s / denom, d_blocks),
+            )
         # Dense FedOpt server update — identical math and state layout to
         # the dp-only program's (the pipeline only changed WHERE the
         # per-client compute ran).
-        pseudo_grad = jax.tree.map(
-            lambda d, p: (-d).astype(p.dtype), mean_delta, state.params
-        )
-        updates, new_opt_state = alg.server_optimizer.update(
-            pseudo_grad, state.opt_state, state.params
-        )
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("server_update"):
+            pseudo_grad = jax.tree.map(
+                lambda d, p: (-d).astype(p.dtype), mean_delta, state.params
+            )
+            updates, new_opt_state = alg.server_optimizer.update(
+                pseudo_grad, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
         metrics = RoundMetrics(
             mean_loss=sum_loss / denom,
             weight_sum=sum_w,

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import json
 import math
 import os
@@ -219,11 +220,8 @@ class SimulationRunner:
         self.perf = perf  # PerformanceManager (optional)
         self.registry = registry  # telemetry MetricsRegistry (optional)
         self.tracer = tracer  # telemetry SpanTracer (optional)
-        # Operators whose round step has executed once for this task: the
-        # first execution's wall time is the compile-dominated one and lands
-        # in the distinct ols_engine_compile_duration_seconds gauge. Keyed
-        # by operator only — a second population's (possibly cache-hit)
-        # first execution must not overwrite the real compile time.
+        # Operators whose round step has executed once for this task
+        # (_note_first_compile).
         self._compiled_once: set = set()
         self.model_io = model_io
         self.warm_start_path = warm_start_path
@@ -514,21 +512,86 @@ class SimulationRunner:
             raise RuntimeError(f"deviceflow NotifyComplete failed for {routing_key}: {msg}")
 
     # -------------------------------------------------------------- telemetry
+    def _tracer(self):
+        from olearning_sim_tpu.telemetry import default_tracer
+
+        return self.tracer if self.tracer is not None else default_tracer()
+
     @contextlib.contextmanager
     def _phase(self, operator_name: str, phase: str, round_idx: int):
-        """Span + per-phase latency histogram around one round phase."""
-        from olearning_sim_tpu.telemetry import default_tracer, instrument
+        """Span + per-phase latency histogram around one round phase; the
+        histogram is fed the span's own duration. Yields the span (None
+        under a disabled tracer)."""
+        from olearning_sim_tpu.telemetry import instrument
 
-        tracer = self.tracer if self.tracer is not None else default_tracer()
-        t0 = time.perf_counter()
-        with tracer.span(f"round.{operator_name}.{phase}",
-                         task_id=self.task_id, round_idx=round_idx):
-            yield
+        with self._tracer().span(f"round.{operator_name}.{phase}",
+                                 task_id=self.task_id,
+                                 round_idx=round_idx) as span:
+            # A disabled tracer times nothing; the histogram still does.
+            t0 = time.perf_counter() if span is None else None
+            yield span
         instrument(
             "ols_engine_round_phase_duration_seconds", self.registry
         ).labels(
             task_id=self.task_id, operator=operator_name, phase=phase
-        ).observe(time.perf_counter() - t0)
+        ).observe(span.duration_s if span is not None
+                  else time.perf_counter() - t0)
+
+    def _stage(self, operator_name: str, phase: str, stage: str,
+               round_idx: int):
+        """Span ``round.<operator>.<phase>.<stage>``: one sub-stage of a
+        phase, a child of the phase span the caller has open."""
+        return self._tracer().span(
+            f"round.{operator_name}.{phase}.{stage}",
+            task_id=self.task_id, round_idx=round_idx,
+        )
+
+    def _note_first_compile(self, operator_name: str, train_span) -> None:
+        """``ols_engine_compile_duration_seconds``: what jax spent tracing,
+        lowering and compiling (or loading from the persistent cache)
+        inside the operator's first ``train`` phase — the ``compile.*``
+        spans the compile listener recorded under it (engine/compile_cache)
+        — not the first round's wall time. Keyed by operator only: a second
+        population's (possibly cache-hit) first execution must not
+        overwrite the real compile time."""
+        if operator_name in self._compiled_once:
+            return
+        self._compiled_once.add(operator_name)
+        if train_span is None:
+            return
+        from olearning_sim_tpu.telemetry import instrument
+
+        end = train_span.start_s + train_span.duration_s
+        instrument(
+            "ols_engine_compile_duration_seconds", self.registry
+        ).labels(task_id=self.task_id, operator=operator_name).set(sum(
+            s.duration_s for s in self._tracer().spans()
+            if s.name.startswith("compile.")
+            and s.thread_id == train_span.thread_id
+            and train_span.start_s <= s.start_s <= end
+        ))
+
+    def _count_work(self, span, p: DataPopulation, trace: ClientTrace,
+                    clients_trained: int) -> None:
+        """Work counts of one train launch, on its ``host_transfer`` span
+        (set once the device has answered): what the round program computed
+        against what the round needed. The program trains every resident
+        row, padding and withheld clients included, and under
+        ``use_multiplicity`` every local sample each step."""
+        if span is None:
+            return
+        cfg = self.core.config
+        n_local = int(p.dataset.x.shape[1])
+        span.attrs.update(
+            clients_resident=int(p.dataset.num_clients),
+            clients_released=int(trace.num_released),
+            clients_trained=clients_trained,
+            local_steps=int(cfg.max_local_steps),
+            samples_computed_per_step=(n_local
+                                       if cfg.use_multiplicity(n_local)
+                                       else int(cfg.batch_size)),
+            samples_needed_per_step=min(int(cfg.batch_size), n_local),
+        )
 
     # -------------------------------------------------------------- operators
     def _completion_times(self, p: DataPopulation, round_idx: int,
@@ -609,99 +672,105 @@ class SimulationRunner:
         from olearning_sim_tpu.telemetry import instrument
 
         with self._phase(operator.name, "select", round_idx):
-            # Compile over REAL clients only — released slots must never be
-            # spent on zero-weight padding clients (which would silently
-            # shrink effective participation).
-            trace = compile_trace(
-                json.loads(operator.deviceflow_strategy) if (
-                    operator.use_deviceflow and operator.deviceflow_strategy
-                ) else None,
-                p.dataset.num_real_clients,
-                round_idx,
-                task_id=self.task_id,
-                operator=operator.name,
-                seed=self.trace_seed,
-            )
+            stage = functools.partial(self._stage, operator.name, "select",
+                                      round_idx=round_idx)
             real = p.dataset.num_real_clients
             strace = None
-            if self.scenario is not None:
-                # Scenario availability (diurnal/charging/spike/churn)
-                # intersects the dispatch-strategy trace: a client
-                # participates only if both release it, and arrives at
-                # the later of the two times (feeds pacing/async).
-                strace = self._scenario_model(p).round_trace(round_idx)
-                trace = combine_traces(trace, strace.as_client_trace())
-            mask = np.zeros(p.dataset.num_clients, trace.participate.dtype)
-            mask[:real] = trace.participate
-            if self._quarantine is not None:
-                # Quarantined clients are masked out exactly like churned-out
-                # devices: zero weight, zero contribution, compiled program
-                # unchanged.
-                mask[:real] = mask[:real] * self._quarantine.active_mask(
-                    p.name, real
-                ).astype(mask.dtype)
+            with stage("compile_trace"):
+                # Compile over REAL clients only — released slots must
+                # never be spent on zero-weight padding clients (which
+                # would silently shrink effective participation).
+                trace = compile_trace(
+                    json.loads(operator.deviceflow_strategy) if (
+                        operator.use_deviceflow
+                        and operator.deviceflow_strategy
+                    ) else None,
+                    real,
+                    round_idx,
+                    task_id=self.task_id,
+                    operator=operator.name,
+                    seed=self.trace_seed,
+                )
+                if self.scenario is not None:
+                    # Scenario availability (diurnal/charging/spike/churn)
+                    # intersects the dispatch-strategy trace: a client
+                    # participates only if both release it, and arrives at
+                    # the later of the two times (feeds pacing/async).
+                    strace = self._scenario_model(p).round_trace(round_idx)
+                    trace = combine_traces(trace, strace.as_client_trace())
+            with stage("mask"):
+                mask = np.zeros(p.dataset.num_clients,
+                                trace.participate.dtype)
+                mask[:real] = trace.participate
+                if self._quarantine is not None:
+                    # Quarantined clients are masked out exactly like
+                    # churned-out devices: zero weight, zero contribution,
+                    # compiled program unchanged.
+                    mask[:real] = mask[:real] * self._quarantine.active_mask(
+                        p.name, real
+                    ).astype(mask.dtype)
             pace: Optional[RoundPacing] = None
-            completion_dev = None
             aplan = None
             async_completion = None
-            if self.async_config is not None:
-                # Buffered async rounds: simulate the cohort's arrivals
-                # and assign commit windows in completion-time order.
-                # Deterministic for (config, trace_seed, operator,
-                # population, round) — rollback/resume replays the exact
-                # commit sequence.
-                from olearning_sim_tpu.engine import async_rounds
+            if self.async_config is not None or self.deadline is not None:
+                with stage("plan"):
+                    if self.async_config is not None:
+                        # Buffered async rounds: simulate the cohort's
+                        # arrivals and assign commit windows in
+                        # completion-time order. Deterministic for (config,
+                        # trace_seed, operator, population, round) —
+                        # rollback/resume replays the exact commit sequence.
+                        from olearning_sim_tpu.engine import async_rounds
 
-                async_completion = self._completion_times(
-                    p, round_idx, operator, trace,
-                    self.async_config.pacing_config(),
-                )
-                aplan = async_rounds.plan_async_round(
-                    self.async_config, async_completion, mask[:real] > 0,
-                    p.dataset.num_clients,
-                )
-            if self.deadline is not None:
-                pace = self._plan_pacing(p, round_idx, operator, trace,
-                                         mask[:real] > 0)
-                if not pace.quorum_met:
-                    # Quorum enforced BEFORE any device transfer or round
-                    # step launch (state untouched): a starved cohort must
-                    # degrade through the failure policy, not silently
-                    # aggregate.
-                    self._rlog.record(
-                        DEADLINE_MISS, point="runner.deadline",
-                        task_id=self.task_id, round_idx=round_idx,
-                        population=p.name, on_time=pace.n_on_time,
-                        required=pace.quorum_required,
-                        selected=pace.n_selected, deadline_s=pace.deadline_s,
-                    )
-                    raise DeadlineMissError(
-                        f"round {round_idx} population {p.name}: "
-                        f"{pace.n_on_time} on-time of {pace.n_selected} "
-                        f"selected is below the quorum of "
-                        f"{pace.quorum_required} "
-                        f"(deadline {pace.deadline_s:.3f}s)"
-                    )
-                # Over-selection: non-selected eligible clients sit this
-                # round out (indistinguishable from churn to the program).
-                mask[:real] = np.where(pace.selected, mask[:real], 0)
-                if p.store is None:
-                    comp_full = np.full(p.dataset.num_clients, np.inf,
-                                        np.float32)
-                    comp_full[:real] = pace.completion
-                    completion_dev = global_put(
-                        comp_full, self.core.plan.client_sharding()
-                    )
-            participate = num_steps = None
+                        async_completion = self._completion_times(
+                            p, round_idx, operator, trace,
+                            self.async_config.pacing_config(),
+                        )
+                        aplan = async_rounds.plan_async_round(
+                            self.async_config, async_completion,
+                            mask[:real] > 0, p.dataset.num_clients,
+                        )
+                    if self.deadline is not None:
+                        pace = self._plan_pacing(p, round_idx, operator,
+                                                 trace, mask[:real] > 0)
+                        if not pace.quorum_met:
+                            # Quorum enforced BEFORE any device transfer or
+                            # round step launch (state untouched): a starved
+                            # cohort must degrade through the failure
+                            # policy, not silently aggregate.
+                            self._rlog.record(
+                                DEADLINE_MISS, point="runner.deadline",
+                                task_id=self.task_id, round_idx=round_idx,
+                                population=p.name, on_time=pace.n_on_time,
+                                required=pace.quorum_required,
+                                selected=pace.n_selected,
+                                deadline_s=pace.deadline_s,
+                            )
+                            raise DeadlineMissError(
+                                f"round {round_idx} population {p.name}: "
+                                f"{pace.n_on_time} on-time of "
+                                f"{pace.n_selected} selected is below the "
+                                f"quorum of {pace.quorum_required} "
+                                f"(deadline {pace.deadline_s:.3f}s)"
+                            )
+                        # Over-selection: non-selected eligible clients sit
+                        # this round out (indistinguishable from churn to
+                        # the program).
+                        mask[:real] = np.where(pace.selected, mask[:real], 0)
+            completion_dev = participate = num_steps = None
             if p.store is None:
-                participate = global_put(
-                    mask, self.core.plan.client_sharding()
-                )
-                if p.num_steps is not None:
-                    num_steps = global_put(
-                        np.asarray(p.num_steps, np.int32),
-                        self.core.plan.client_sharding(),
-                    )
+                with stage("place"):
+                    sharding = self.core.plan.client_sharding()
+                    if pace is not None:
+                        comp_full = np.full(p.dataset.num_clients, np.inf,
+                                            np.float32)
+                        comp_full[:real] = pace.completion
+                        completion_dev = global_put(comp_full, sharding)
+                    participate = global_put(mask, sharding)
+                    if p.num_steps is not None:
+                        num_steps = global_put(
+                            np.asarray(p.num_steps, np.int32), sharding
+                        )
         if p.store is not None:
             # Streamed population: per-client arrays stay on the host —
             # FedCore.stream_round stages the cohort block by block with
@@ -709,8 +778,7 @@ class SimulationRunner:
             return self._run_train_streamed(
                 p, round_idx, operator, trace, strace, mask, pace
             )
-        t_step0 = time.perf_counter()
-        with self._phase(operator.name, "train", round_idx):
+        with self._phase(operator.name, "train", round_idx) as train_span:
             state = self.states[p.name]
             pace_kwargs = {}
             if pace is not None:
@@ -791,20 +859,13 @@ class SimulationRunner:
                         p.dataset, y=clean_y_dev
                     )
             self.states[p.name] = state
-        with self._phase(operator.name, "host_transfer", round_idx):
+        with self._phase(operator.name, "host_transfer", round_idx) as span:
             # The device_get is the host sync point: "train" above measures
             # async dispatch; this interval covers real device execution.
             client_loss = np.asarray(jax.device_get(metrics.client_loss))
-        if operator.name not in self._compiled_once:
-            # First execution of the compiled round step for this operator:
-            # wall time is compile-dominated and is recorded distinctly so
-            # steady-state latency stays unpolluted.
-            self._compiled_once.add(operator.name)
-            instrument(
-                "ols_engine_compile_duration_seconds", self.registry
-            ).labels(task_id=self.task_id, operator=operator.name).set(
-                time.perf_counter() - t_step0
-            )
+            clients_trained = int(metrics.clients_trained)
+            self._count_work(span, p, trace, clients_trained)
+        self._note_first_compile(operator.name, train_span)
         ok = np.isfinite(client_loss)
         flagged = None
         clipped = 0
@@ -873,7 +934,7 @@ class SimulationRunner:
             )
         rec = {
             "mean_loss": float(metrics.mean_loss),
-            "clients_trained": int(metrics.clients_trained),
+            "clients_trained": clients_trained,
             "released": trace.num_released,
             "dropped": trace.num_dropped,
             "sim_duration_s": trace.round_duration(),
@@ -1059,8 +1120,7 @@ class SimulationRunner:
                 and strace.label_shift.any()):
             kwargs["label_shift"] = strace.label_shift
             kwargs["label_classes"] = self._label_classes(p, p.dataset.y)
-        t_step0 = time.perf_counter()
-        with self._phase(operator.name, "train", round_idx):
+        with self._phase(operator.name, "train", round_idx) as train_span:
             state = self.states[p.name]
             state, metrics, sstats = self.core.stream_round(
                 state, p.store,
@@ -1070,15 +1130,11 @@ class SimulationRunner:
                 **kwargs,
             )
             self.states[p.name] = state
-        with self._phase(operator.name, "host_transfer", round_idx):
+        with self._phase(operator.name, "host_transfer", round_idx) as span:
             client_loss = np.asarray(jax.device_get(metrics.client_loss))
-        if operator.name not in self._compiled_once:
-            self._compiled_once.add(operator.name)
-            instrument(
-                "ols_engine_compile_duration_seconds", self.registry
-            ).labels(task_id=self.task_id, operator=operator.name).set(
-                time.perf_counter() - t_step0
-            )
+            clients_trained = int(metrics.clients_trained)
+            self._count_work(span, p, trace, clients_trained)
+        self._note_first_compile(operator.name, train_span)
         ok = np.isfinite(client_loss)
         clipped = 0
         if self.defense is not None:
@@ -1101,7 +1157,7 @@ class SimulationRunner:
             )
         rec = {
             "mean_loss": float(metrics.mean_loss),
-            "clients_trained": int(metrics.clients_trained),
+            "clients_trained": clients_trained,
             "released": trace.num_released,
             "dropped": trace.num_dropped,
             "sim_duration_s": trace.round_duration(),
@@ -1194,7 +1250,10 @@ class SimulationRunner:
                     x, y = p.eval_data
                     with self._phase("convergence", "eval", round_idx):
                         eval_loss, eval_acc = self.core.evaluate(
-                            self.states[p.name].params, x, y
+                            self.states[p.name].params, x, y,
+                            stage=functools.partial(
+                                self._stage, "convergence", "eval",
+                                round_idx=round_idx),
                         )
                     break
         if eval_acc is None:
@@ -1299,11 +1358,12 @@ class SimulationRunner:
             return None
         return self._convergence.record()
 
-    def _run_eval(self, p: DataPopulation) -> Dict[str, Any]:
+    def _run_eval(self, p: DataPopulation, stage=None) -> Dict[str, Any]:
         rec: Dict[str, Any] = {"eval_loss": None, "eval_acc": None}
         if p.eval_data is not None:
             x, y = p.eval_data
-            loss, acc = self.core.evaluate(self.states[p.name].params, x, y)
+            loss, acc = self.core.evaluate(self.states[p.name].params, x, y,
+                                           stage=stage)
             rec.update(eval_loss=loss, eval_acc=acc)
         personal = self.personal_states.get(p.name)
         if personal is not None:
@@ -1936,9 +1996,9 @@ class SimulationRunner:
         """One full round: barriers, operators, accounting, checkpoint,
         model export. Returns "ok", "stop" (cooperative stop observed), or
         "final" (final-round stop barrier tolerated)."""
-        from olearning_sim_tpu.telemetry import default_tracer, instrument
+        from olearning_sim_tpu.telemetry import instrument
 
-        tracer = self.tracer if self.tracer is not None else default_tracer()
+        tracer = self._tracer()
         t_round0 = time.perf_counter()
         if not self.operator_flow.start():
             if self.stop_event is not None and self.stop_event.is_set():
@@ -1985,7 +2045,9 @@ class SimulationRunner:
                         ok_by_population[p.name] = r.pop("ok_mask")
                     elif operator.kind == "eval":
                         with self._phase(operator.name, "eval", round_idx):
-                            r = self._run_eval(p)
+                            r = self._run_eval(p, functools.partial(
+                                self._stage, operator.name, "eval",
+                                round_idx=round_idx))
                         ok_by_population[p.name] = np.ones(
                             p.dataset.num_clients, bool
                         )
@@ -2098,15 +2160,25 @@ class SimulationRunner:
         pass; finish()`` — the stepping API is what lets a
         :class:`MultiTaskDispatcher` interleave several tasks' compiled
         round programs on one process."""
-        for p in self.populations:
-            if p.name not in self.states:
-                # crc32, not hash(): str hashes are PYTHONHASHSEED-randomized
-                # per process, which would silently diverge the "replicated"
-                # ServerState across multi-controller processes (and break
-                # restart reproducibility). Same pattern as phone_farm.py.
-                self.states[p.name] = self.core.init_state(
-                    jax.random.key(zlib.crc32(self.task_id.encode()) & 0x7FFFFFFF)
-                )
+        # The compile.* spans of the task's tree (and the compile gauge
+        # read from them) need jax's compile events listened to, also for a
+        # runner that no task bridge built.
+        from olearning_sim_tpu.engine.compile_cache import install_listener
+
+        install_listener()
+        with self._tracer().span("bridge.init_state", task_id=self.task_id):
+            for p in self.populations:
+                if p.name not in self.states:
+                    # crc32, not hash(): str hashes are PYTHONHASHSEED-
+                    # randomized per process, which would silently diverge
+                    # the "replicated" ServerState across multi-controller
+                    # processes (and break restart reproducibility). Same
+                    # pattern as phone_farm.py.
+                    self.states[p.name] = self.core.init_state(
+                        jax.random.key(
+                            zlib.crc32(self.task_id.encode()) & 0x7FFFFFFF
+                        )
+                    )
         start_round = self._try_resume()
         if start_round == 0 and self.model_io is not None:
             start_round = self._resume_from_exports()

@@ -40,6 +40,7 @@ committed round instead of replaying from zero.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -158,6 +159,24 @@ def build_runner_from_taskconfig(
     TaskManager retires finished tasks' series from."""
     if not isinstance(tc, pb.TaskConfig):
         tc = json2taskconfig(tc)
+    from olearning_sim_tpu.telemetry import default_tracer
+
+    # The task's span tree (docs/observability.md): bridge.build with its
+    # children bridge.build_fedcore, bridge.generate and bridge.place, all
+    # carrying the task id; the runner adds bridge.init_state and round.*.
+    tracer = default_tracer()
+    span = functools.partial(tracer.span, task_id=tc.taskID.taskID)
+    with span("bridge.build"):
+        return _build_runner(tc, span, plan, task_repo, deviceflow,
+                             stop_event, perf, checkpointer, cost_oracle,
+                             registry)
+
+
+def _build_runner(tc: pb.TaskConfig, span, plan, task_repo, deviceflow,
+                  stop_event, perf, checkpointer, cost_oracle,
+                  registry) -> SimulationRunner:
+    """:func:`build_runner_from_taskconfig`'s body; ``span(name)`` opens a
+    span of the task's tree."""
     # Persistent XLA compilation cache: every task-bridge build (fresh
     # submits, bench children, supervisor relaunches after a crash) shares
     # the durable cache under artifacts/, so a relaunched or repeated
@@ -199,15 +218,16 @@ def build_runner_from_taskconfig(
     cfg = FedCoreConfig.from_dict(fed_cfg)
     algorithm = algorithm_from_config(algo_cfg.pop("name", "fedavg"), **algo_cfg)
     input_shape = tuple(model_cfg.get("input_shape", [])) or None
-    core = build_fedcore(
-        model_cfg.get("name", "mlp2"),
-        algorithm,
-        plan,
-        cfg,
-        model_overrides=model_cfg.get("overrides"),
-        input_shape=input_shape,
-        microbatches=parallel.microbatches,
-    )
+    with span("bridge.build_fedcore"):
+        core = build_fedcore(
+            model_cfg.get("name", "mlp2"),
+            algorithm,
+            plan,
+            cfg,
+            model_overrides=model_cfg.get("overrides"),
+            input_shape=input_shape,
+            microbatches=parallel.microbatches,
+        )
 
     # Scenario traces + streamed cohorts ride the same blob
     # (docs/performance.md):
@@ -280,55 +300,56 @@ def build_runner_from_taskconfig(
         num_clients = sum(nums)
         eval_data = None
         pop_classes = num_classes
-        if td.dataPath:
-            # Real dataset: honor dataPath + dataTransferType (reference
-            # download_data_files, utils_run_task.py:174-325). The archive's
-            # test split (or a held-out tail) is the central eval set.
-            from olearning_sim_tpu.data import load_population
+        with span("bridge.generate"):
+            if td.dataPath:
+                # Real dataset: honor dataPath + dataTransferType (reference
+                # download_data_files, utils_run_task.py:174-325). The archive's
+                # test split (or a held-out tail) is the central eval set.
+                from olearning_sim_tpu.data import load_population
 
-            real_cfg = data_cfg.get("real", {})
-            text_kwargs = (
-                {"vocab_size": vocab_size, "seq_len": int(input_shape[0])}
-                if is_text else {}
-            )
-            ds, eval_data, data_classes = load_population(
-                td.dataPath,
-                num_clients=num_clients,
-                n_local=int(real_cfg.get("n_local", syn.get("n_local", 20))),
-                scheme=real_cfg.get("scheme", "dirichlet"),
-                alpha=float(real_cfg.get("alpha", syn.get("dirichlet_alpha") or 0.5)),
-                seed=int(syn.get("seed", 0)),
-                transfer_type=td.dataTransferType,
-                storage_settings=params.get("storage"),
-                eval_n=data_cfg.get("eval_n"),
-                **text_kwargs,
-            )
-            if data_classes > model_classes:
-                raise ValueError(
-                    f"dataset at {td.dataPath!r} has {data_classes} classes "
-                    f"but the model's head emits only {model_classes}"
+                real_cfg = data_cfg.get("real", {})
+                text_kwargs = (
+                    {"vocab_size": vocab_size, "seq_len": int(input_shape[0])}
+                    if is_text else {}
                 )
-            pop_classes = data_classes
-        elif is_text:
-            ds = make_synthetic_text_dataset(
-                seed=int(syn.get("seed", 0)),
-                num_clients=num_clients,
-                n_local=int(syn.get("n_local", 20)),
-                seq_len=int(input_shape[0]),
-                num_classes=num_classes,
-                vocab_size=vocab_size,
-                dirichlet_alpha=syn.get("dirichlet_alpha"),
-            )
-        else:
-            ds = make_synthetic_dataset(
-                seed=int(syn.get("seed", 0)),
-                num_clients=num_clients,
-                n_local=int(syn.get("n_local", 20)),
-                input_shape=input_shape,
-                num_classes=num_classes,
-                dirichlet_alpha=syn.get("dirichlet_alpha"),
-                class_sep=float(syn.get("class_sep", 2.0)),
-            )
+                ds, eval_data, data_classes = load_population(
+                    td.dataPath,
+                    num_clients=num_clients,
+                    n_local=int(real_cfg.get("n_local", syn.get("n_local", 20))),
+                    scheme=real_cfg.get("scheme", "dirichlet"),
+                    alpha=float(real_cfg.get("alpha", syn.get("dirichlet_alpha") or 0.5)),
+                    seed=int(syn.get("seed", 0)),
+                    transfer_type=td.dataTransferType,
+                    storage_settings=params.get("storage"),
+                    eval_n=data_cfg.get("eval_n"),
+                    **text_kwargs,
+                )
+                if data_classes > model_classes:
+                    raise ValueError(
+                        f"dataset at {td.dataPath!r} has {data_classes} classes "
+                        f"but the model's head emits only {model_classes}"
+                    )
+                pop_classes = data_classes
+            elif is_text:
+                ds = make_synthetic_text_dataset(
+                    seed=int(syn.get("seed", 0)),
+                    num_clients=num_clients,
+                    n_local=int(syn.get("n_local", 20)),
+                    seq_len=int(input_shape[0]),
+                    num_classes=num_classes,
+                    vocab_size=vocab_size,
+                    dirichlet_alpha=syn.get("dirichlet_alpha"),
+                )
+            else:
+                ds = make_synthetic_dataset(
+                    seed=int(syn.get("seed", 0)),
+                    num_clients=num_clients,
+                    n_local=int(syn.get("n_local", 20)),
+                    input_shape=input_shape,
+                    num_classes=num_classes,
+                    dirichlet_alpha=syn.get("dirichlet_alpha"),
+                    class_sep=float(syn.get("class_sep", 2.0)),
+                )
         store = None
         if scenario is not None and scenario.streamed:
             # Streamed population: never placed whole — the round engine
@@ -337,23 +358,27 @@ def build_runner_from_taskconfig(
 
             store = HostClientStore.from_dataset(ds)
         else:
-            ds = ds.pad_for(plan, cfg.block_clients).place(plan)
+            with span("bridge.place"):
+                ds = ds.pad_for(plan, cfg.block_clients).place(plan)
         cls = np.zeros(ds.num_clients, int)
         start = 0
         for ci, n in enumerate(nums):
             cls[start : start + n] = ci
             start += n
         if eval_data is None and not td.dataPath and data_cfg.get("eval_n"):
-            if is_text:
-                eval_data = make_central_text_eval_set(
-                    int(syn.get("seed", 0)), int(data_cfg["eval_n"]),
-                    int(input_shape[0]), num_classes, vocab_size=vocab_size,
-                )
-            else:
-                eval_data = make_central_eval_set(
-                    int(syn.get("seed", 0)), int(data_cfg["eval_n"]), input_shape,
-                    num_classes, class_sep=float(syn.get("class_sep", 2.0)),
-                )
+            with span("bridge.generate"):
+                if is_text:
+                    eval_data = make_central_text_eval_set(
+                        int(syn.get("seed", 0)), int(data_cfg["eval_n"]),
+                        int(input_shape[0]), num_classes,
+                        vocab_size=vocab_size,
+                    )
+                else:
+                    eval_data = make_central_eval_set(
+                        int(syn.get("seed", 0)), int(data_cfg["eval_n"]),
+                        input_shape, num_classes,
+                        class_sep=float(syn.get("class_sep", 2.0)),
+                    )
         # Heterogeneous compute profiles: {"<device_class>": local_steps}
         # (Ditto/BASELINE config 5); unlisted classes run max_local_steps.
         profiles = data_cfg.get("compute_profiles") or {}

@@ -13,7 +13,6 @@ import contextlib
 import dataclasses
 import json
 import math
-import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -77,24 +76,22 @@ class PerformanceManager:
     """Records timings, answers performance queries, controls the profiler."""
 
     def __init__(self, repo: Optional[TableRepo] = None, keep_last: int = 4096,
-                 resilience_log=None, registry=None, tracer=None):
+                 resilience_log=None, registry=None):
         # No repo by default: queries are answered from the bounded in-memory
         # window. Pass a repo to persist every row for external analysis —
         # retention is then the caller's policy (rows are append-only).
         # ``resilience_log`` — the ResilienceLog whose counters get_resilience
         # reports; pass the runner's instance when it is not the process-
         # global default (ResilienceConfig(log=...)).
-        # ``registry`` / ``tracer`` — telemetry sinks this manager fronts
-        # (None resolves the process defaults): every recorded timing also
-        # feeds the live metrics registry, and stop_trace flushes the
-        # tracer's runner spans next to the XLA trace. get_performance
-        # answers stay computed from the recorded RoundTiming rows
-        # themselves — the façade adds lenses, it never changes the numbers.
+        # ``registry`` — the telemetry sink this manager fronts (None
+        # resolves the process default): every recorded timing also feeds
+        # the live metrics registry. get_performance answers stay
+        # computed from the recorded RoundTiming rows themselves — the
+        # façade adds lenses, it never changes the numbers.
         self.repo = repo
         self.keep_last = keep_last
         self.resilience_log = resilience_log
         self.registry = registry
-        self.tracer = tracer
         self._lock = threading.RLock()
         self._timings: Dict[str, List[RoundTiming]] = {}
         # task_id -> monotonic time of the last repo rehydration scan: a
@@ -105,7 +102,6 @@ class PerformanceManager:
         self.rehydrate_ttl_s = 30.0
         self._rehydrate_scans: Dict[str, float] = {}
         self._trace_dir: Optional[str] = None
-        self._trace_span_mark: float = 0.0
 
     # ------------------------------------------------------------- recording
     def record_round(self, timing: RoundTiming) -> None:
@@ -369,22 +365,17 @@ class PerformanceManager:
     # -------------------------------------------------------------- profiler
     def start_trace(self, logdir: str) -> bool:
         """Begin a ``jax.profiler`` trace (XLA op-level timeline viewable in
-        TensorBoard/Perfetto). One trace at a time. A start that raises
-        (unwritable logdir, half-initialized profiler session) leaves this
-        manager armed for the next attempt instead of wedged "in a trace"
-        forever."""
+        TensorBoard/Perfetto). The program's spans are in it too: every
+        ``SpanTracer`` span open while the session runs is a host event of
+        the profile, on its clock (telemetry/tracing.py). One trace at a
+        time. A start that raises (unwritable logdir, half-initialized
+        profiler session) leaves this manager armed for the next attempt
+        instead of wedged "in a trace" forever."""
         import jax
-
-        from olearning_sim_tpu.telemetry import default_tracer
 
         with self._lock:
             if self._trace_dir is not None:
                 return False
-            tracer = self.tracer if self.tracer is not None else \
-                default_tracer()
-            # Spans before this watermark belong to earlier rounds/traces
-            # and have no counterpart in the XLA capture starting now.
-            self._trace_span_mark = tracer.now()
             try:
                 jax.profiler.start_trace(logdir)
             except BaseException:
@@ -398,8 +389,6 @@ class PerformanceManager:
             self._trace_dir = logdir
             return True
 
-    RUNNER_SPAN_FILE = "runner_spans.trace.json"
-
     def stop_trace(self) -> Optional[str]:
         import jax
 
@@ -408,13 +397,4 @@ class PerformanceManager:
                 return None
             jax.profiler.stop_trace()
             out, self._trace_dir = self._trace_dir, None
-        # Flush the runner-level spans as Perfetto trace_event JSON next to
-        # the XLA trace, so one directory opens both timelines. Best-effort:
-        # span export must never turn a successful XLA capture into an error.
-        from olearning_sim_tpu.telemetry import default_tracer
-
-        tracer = self.tracer if self.tracer is not None else default_tracer()
-        with contextlib.suppress(Exception):
-            tracer.export(os.path.join(out, self.RUNNER_SPAN_FILE),
-                          since_s=self._trace_span_mark)
         return out

@@ -869,11 +869,15 @@ class TaskManager:
         self._own_jobs[task_id] = job_id
         entered = self._queue_entered.pop(task_id, None)
         if entered is not None:
-            from olearning_sim_tpu.telemetry import instrument
+            from olearning_sim_tpu.telemetry import default_tracer, instrument
 
-            instrument("ols_taskmgr_task_wait_seconds").observe(
-                time.monotonic() - entered
-            )
+            waited = time.monotonic() - entered
+            instrument("ols_taskmgr_task_wait_seconds").observe(waited)
+            # The root of the task's span tree (docs/observability.md): the
+            # same interval, stamped now that its end is known.
+            tracer = default_tracer()
+            tracer.record("task.queue_wait", tracer.now() - waited, waited,
+                          task_id=task_id)
         if self._pool is not None:
             # Consume the pending placement: the worker's HBM share is
             # charged and the row's worker_id records where it landed.

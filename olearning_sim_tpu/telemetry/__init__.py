@@ -1,13 +1,15 @@
 """Unified telemetry: metrics registry, span tracing, exporters.
 
-Three layers, all stdlib-only:
+Three layers, stdlib-only (spans use ``jax.profiler`` where the process
+has already imported JAX, never importing it themselves):
 
 - :mod:`telemetry.metrics` — thread-safe ``Counter`` / ``Gauge`` /
   ``Histogram`` behind a :class:`MetricsRegistry` (process default +
   injectable instances);
 - :mod:`telemetry.tracing` — :class:`SpanTracer` producing parent-linked
-  wall-clock spans exportable as Chrome/Perfetto ``trace_event`` JSON (so
-  runner spans open next to ``jax.profiler`` XLA traces);
+  wall-clock spans that are also ``jax.profiler`` trace annotations (host
+  events of any running profile, on its clock) and export as
+  Chrome/Perfetto ``trace_event`` JSON for runs without a profiler;
 - :mod:`telemetry.exporters` — Prometheus text exposition
   (:func:`render_prometheus` + :class:`MetricsHTTPServer`) and JSON
   snapshots (:func:`snapshot` / :func:`dump_json`) for bench artifacts.
@@ -93,8 +95,10 @@ CATALOG = {
     ),
     "ols_engine_compile_duration_seconds": (
         GAUGE,
-        "First-execution wall-clock of the compiled round step per "
-        "(task, operator) — dominated by XLA compilation",
+        "Seconds jax spent tracing, lowering and compiling (or loading "
+        "from the persistent cache) inside the operator's first train "
+        "phase: the sum of the compile.* spans under it, not that "
+        "round's wall time",
         ("task_id", "operator"),
     ),
     "ols_engine_rounds_total": (

@@ -1,11 +1,13 @@
 """Span tracer: causal, parent-linked wall-clock spans -> Perfetto JSON.
 
 ``jax.profiler`` answers "what did XLA do" at op granularity; this module
-answers "what did the *runner* do" — which round, which operator, which
-phase — at host granularity. Both export to the same Chrome ``trace_event``
-JSON format, so a runner-span file opens in Perfetto/chrome://tracing right
-next to the XLA timeline (and ``PerformanceManager.stop_trace`` writes one
-beside every captured XLA trace).
+answers "what did the *program* do" — which task, which round, which
+operator, phase and stage — at host granularity. Every span also enters a
+``jax.profiler.TraceAnnotation`` of the same name, so whenever a profiler
+session is running (``PerformanceManager.start_trace``, a benchmark's own)
+the spans are host events of the profile itself, on the profiler's clock,
+next to the device ops. :meth:`SpanTracer.export` writes the spans alone as
+Chrome ``trace_event`` JSON, for runs without a profiler.
 
 Usage::
 
@@ -22,12 +24,22 @@ trace_event ``B``/``E`` model Perfetto reconstructs per tid).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None in a process that has not
+    imported JAX: no profiler session can be running there, and a span must
+    not be what imports JAX."""
+    profiler = sys.modules.get("jax.profiler")
+    return getattr(profiler, "TraceAnnotation", None)
 
 
 @dataclasses.dataclass
@@ -64,13 +76,23 @@ class _ActiveSpan:
         self._tracer = tracer
         self.span = span
         self._t0 = 0.0
+        self._annotation = None
 
     def __enter__(self) -> Span:
         self._t0 = time.perf_counter()
         self._tracer._stack().append(self.span)
+        # With no profiler session running a TraceMe is a flag check; with
+        # one, the span is a host event of the profile, attributes as stats
+        # (those set after entry are on the Span only).
+        annotation = _trace_annotation()
+        if annotation is not None:
+            self._annotation = annotation(self.span.name, **self.span.attrs)
+            self._annotation.__enter__()
         return self.span
 
     def __exit__(self, exc_type, exc, tb):
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         self.span.duration_s = time.perf_counter() - self._t0
         if exc_type is not None:
             self.span.attrs["error"] = f"{exc_type.__name__}: {str(exc)[:200]}"
@@ -106,7 +128,8 @@ class SpanTracer:
         self.enabled = enabled
         self._lock = threading.Lock()
         self._local = threading.local()
-        self._spans: List[Span] = []
+        # A deque drops its oldest entry in O(1) once full.
+        self._spans: collections.deque = collections.deque(maxlen=keep_last)
         self._next_id = 1
         self._epoch = time.perf_counter()
 
@@ -133,11 +156,36 @@ class SpanTracer:
             attrs=dict(attrs),
         ))
 
+    def current(self) -> Optional[Span]:
+        """The innermost span open on the calling thread, or None."""
+        stack = self._stack()
+        return stack[-1] if stack else None
+
+    def record(self, name: str, start_s: float, duration_s: float,
+               **attrs: Any) -> Optional[Span]:
+        """A finished span stamped after the fact (an interval somebody
+        else timed, e.g. JAX's compile events): ``start_s`` is on this
+        tracer's clock (:meth:`now`), the parent is the innermost span open
+        on the calling thread. Returns the span, or None when disabled."""
+        if not self.enabled:
+            return None
+        parent = self.current()
+        with self._lock:
+            span_id = self._next_id
+            self._next_id += 1
+        span = Span(
+            name=name, span_id=span_id,
+            parent_id=parent.span_id if parent is not None else None,
+            start_s=start_s, duration_s=duration_s,
+            thread_id=threading.get_ident() & 0x7FFFFFFF,
+            attrs=dict(attrs),
+        )
+        self._finish(span)
+        return span
+
     def _finish(self, span: Span) -> None:
         with self._lock:
             self._spans.append(span)
-            if len(self._spans) > self.keep_last:
-                del self._spans[: len(self._spans) - self.keep_last]
 
     # ---------------------------------------------------------------- reads
     def spans(self, name: Optional[str] = None) -> List[Span]:

@@ -83,35 +83,41 @@ def test_runner_records_perf():
 
 
 def test_profiler_trace(tmp_path):
-    import jax
+    """start_trace/stop_trace capture one profile that holds the program's
+    spans as host events (``/host:CPU`` plane, read with
+    ``jax.profiler.ProfileData``) beside XLA's own — no side file."""
+    import glob
+
     import jax.numpy as jnp
+    from jax.profiler import ProfileData
 
     from olearning_sim_tpu.telemetry import SpanTracer
 
     tracer = SpanTracer()
-    perf = PerformanceManager(tracer=tracer)
+    perf = PerformanceManager()
     with tracer.span("before.window"):
-        pass  # predates the trace: must NOT appear in the flushed file
+        pass  # predates the trace: must NOT appear in the profile
     logdir = str(tmp_path / "trace")
     assert perf.start_trace(logdir)
     assert not perf.start_trace(logdir)  # one at a time
-    with tracer.span("round.train", round_idx=0):
+    with tracer.span("round.train", task_id="perf-task", round_idx=0) as span:
         jnp.square(jnp.arange(8.0)).block_until_ready()
     assert perf.stop_trace() == logdir
     assert perf.stop_trace() is None
-    # Trace artifacts were written.
+    # Trace artifacts were written, and the runner-span side file is gone.
     found = [f for _, _, fs in os.walk(logdir) for f in fs]
     assert found, "no trace files written"
-    # The runner-span Perfetto file landed next to the XLA trace.
-    span_file = os.path.join(logdir, PerformanceManager.RUNNER_SPAN_FILE)
-    assert os.path.exists(span_file)
-    import json as _json
-
-    with open(span_file) as f:
-        doc = _json.load(f)
-    assert any(ev["name"] == "round.train" for ev in doc["traceEvents"])
-    # Windowed: only spans inside this trace's interval are flushed.
-    assert not any(ev["name"] == "before.window" for ev in doc["traceEvents"])
+    assert not [f for f in found if f.endswith(".trace.json")]
+    (path,) = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                        recursive=True)
+    host = [ev for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events]
+    (event,) = [ev for ev in host if ev.name == "round.train"]
+    assert dict(event.stats) == {"task_id": "perf-task", "round_idx": 0}
+    assert event.duration_ns * 1e-9 == pytest.approx(span.duration_s,
+                                                     abs=2e-3)
+    assert not [ev for ev in host if ev.name == "before.window"]
 
 
 def test_percentile_linear_interpolation():

@@ -228,7 +228,162 @@ def test_disabled_tracer_records_nothing():
     tracer = SpanTracer(enabled=False)
     with tracer.span("x"):
         pass
+    assert tracer.record("y", 0.0, 1.0) is None
     assert tracer.spans() == []
+
+
+def test_record_parents_to_the_open_span_of_the_calling_thread():
+    """``record`` stamps a finished span after the fact: it keeps the start
+    and duration it is given, parents to the innermost span the CALLING
+    thread has open (not another thread's), and to nothing outside one."""
+    tracer = SpanTracer()
+    assert tracer.current() is None
+    top = tracer.record("compile.backend", 1.5, 0.25, fun_name="f")
+    assert (top.parent_id, top.start_s, top.duration_s) == (None, 1.5, 0.25)
+    with tracer.span("round.train", round_idx=0) as outer:
+        with tracer.span("round.train.train") as inner:
+            assert tracer.current() is inner
+            child = tracer.record("compile.trace", tracer.now() - 0.5, 0.5,
+                                  fun_name="round_step")
+            other = []
+            t = threading.Thread(target=lambda: other.append(
+                tracer.record("elsewhere", 0.0, 1.0)))
+            t.start()
+            t.join(10.0)
+        sibling = tracer.record("compile.lower", tracer.now(), 0.0)
+    assert child.parent_id == inner.span_id
+    assert sibling.parent_id == outer.span_id
+    assert other[0].parent_id is None
+    assert other[0].thread_id != child.thread_id
+    assert child.attrs == {"fun_name": "round_step"}
+    # Recorded spans are ordinary spans: listed, and exported.
+    names = [s.name for s in tracer.spans()]
+    assert names.count("compile.trace") == 1 and "elsewhere" in names
+    assert any(ev["name"] == "compile.trace" and ev["dur"] == 0.5e6
+               for ev in tracer.to_trace_events())
+    ids = [s.span_id for s in tracer.spans()]
+    assert len(set(ids)) == len(ids)
+
+
+def test_compile_listener_records_outermost_intervals_under_the_open_span(
+        fresh_telemetry):
+    """jax's own compile events, replayed: nested intervals (the jits a
+    trace or a lowering traces inside itself) are dropped, a backend
+    interval whose cache lookup hit is a ``compile.cache_load``, and every
+    span names the task and round of the span it was recorded under."""
+    from jax import monitoring
+
+    from olearning_sim_tpu.engine import compile_cache as cc
+
+    _, tracer = fresh_telemetry
+    cc.install_listener()
+    cc.install_listener()                      # idempotent: one listener
+
+    def interval(event, seconds, inner=(), **kw):
+        monitoring.record_scalar(event, 0.0, **kw)
+        for args in inner:
+            interval(*args)
+        monitoring.record_event_duration_secs(event, seconds, **kw)
+
+    with tracer.span("round.train.train", task_id="T", round_idx=0) as phase:
+        interval(cc.TRACE_EVENT, 0.5, fun_name="round_step", inner=[
+            (cc.TRACE_EVENT, 0.1), (cc.TRACE_EVENT, 0.2)])
+        interval(cc.LOWER_EVENT, 0.25, fun_name="jit(round_step)", inner=[
+            (cc.TRACE_EVENT, 0.05)])
+        # A miss compiles; a hit reports its retrieval inside the interval.
+        interval(cc.BACKEND_EVENT, 2.0, fun_name="jit(round_step)")
+        monitoring.record_scalar(cc.BACKEND_EVENT, 0.0, fun_name="jit(ev)")
+        monitoring.record_event_duration_secs(cc.CACHE_LOAD_EVENT, 0.75)
+        monitoring.record_event_duration_secs(cc.BACKEND_EVENT, 1.0,
+                                              fun_name="jit(ev)")
+        monitoring.record_event_duration_secs("/jax/some/other", 9.0)
+    interval(cc.TRACE_EVENT, 0.125, fun_name="eager")   # no span open
+    got = [(s.name, s.duration_s, s.attrs.get("fun_name"), s.parent_id)
+           for s in tracer.spans() if s.name.startswith("compile.")]
+    assert got == [
+        ("compile.trace", 0.5, "round_step", phase.span_id),
+        ("compile.lower", 0.25, "jit(round_step)", phase.span_id),
+        ("compile.backend", 2.0, "jit(round_step)", phase.span_id),
+        ("compile.cache_load", 1.0, "jit(ev)", phase.span_id),
+        ("compile.trace", 0.125, "eager", None),
+    ]
+    spans = [s for s in tracer.spans() if s.name.startswith("compile.")]
+    assert all(s.attrs["task_id"] == "T" and s.attrs["round_idx"] == 0
+               for s in spans[:4])
+    assert spans[3].attrs["retrieval_s"] == 0.75
+    assert "task_id" not in spans[4].attrs
+    # Stamped to end when jax reported them.
+    assert all(s.start_s + s.duration_s <= tracer.now() for s in spans)
+
+
+def test_span_window_keeps_the_newest():
+    tracer = SpanTracer(keep_last=3)
+    for i in range(5):
+        with tracer.span("s", i=i):
+            pass
+    assert [s.attrs["i"] for s in tracer.spans()] == [2, 3, 4]
+
+
+def _host_events(logdir, prefix):
+    """(name, start_ns, duration_ns, stats) of the ``/host:CPU`` plane's
+    events whose name starts with ``prefix``, from the capture's
+    ``.xplane.pb`` read with ``jax.profiler.ProfileData``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                        recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefix):
+                    events.append((ev.name, ev.start_ns, ev.duration_ns,
+                                   dict(ev.stats)))
+    return events
+
+
+def test_spans_are_host_events_of_a_profiler_capture(tmp_path):
+    """Every span enters a ``jax.profiler.TraceAnnotation``: while any
+    profiler session runs, the program's spans are host events of the
+    profile itself — on its clock, attributes as stats — and spans outside
+    the session are not in it."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    tracer = SpanTracer()
+    with tracer.span("tel.before"):
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracer.span("tel.round.train", task_id="T", round_idx=3) as outer:
+            with tracer.span("tel.round.train.select", task_id="T",
+                             round_idx=3) as inner:
+                jnp.square(jnp.arange(8.0)).block_until_ready()
+                time.sleep(0.01)
+            time.sleep(0.005)
+        tracer.record("tel.recorded", tracer.now() - 0.001, 0.001)
+    finally:
+        jax.profiler.stop_trace()
+    events = {e[0]: e for e in _host_events(str(tmp_path), "tel.")}
+    assert set(events) == {"tel.round.train", "tel.round.train.select"}
+    for span in (outer, inner):
+        _, start_ns, duration_ns, stats = events[span.name]
+        assert stats == {"task_id": "T", "round_idx": 3}
+        # The annotation sits just inside the span's own clock pair.
+        assert duration_ns * 1e-9 == pytest.approx(span.duration_s, abs=2e-3)
+    # One clock: the two events are as far apart in the profile as the two
+    # spans are on the tracer's clock, and nest the same way.
+    gap_profile = (events[inner.name][1] - events[outer.name][1]) * 1e-9
+    assert gap_profile == pytest.approx(inner.start_s - outer.start_s,
+                                        abs=2e-3)
+    assert events[inner.name][2] <= events[outer.name][2]
 
 
 # ------------------------------------------------------- e2e instrumentation
@@ -313,8 +468,28 @@ def test_two_round_run_emits_round_phase_metrics(fresh_telemetry, tmp_path):
                         task_id="tel-task", status="ok") == 2
     assert _label_value(reg.get("ols_engine_device_rounds_total"),
                         task_id="tel-task") == 16  # 8 clients x 2 rounds
+    # The compile gauge is what jax spent tracing, lowering and compiling
+    # under the operator's first train phase (the compile.* spans there),
+    # not that round's wall time; the phase histogram is fed the phase
+    # spans' own durations.
     compile_g = reg.get("ols_engine_compile_duration_seconds")
-    assert _label_value(compile_g, task_id="tel-task", operator="train") > 0
+    first_train = min((s for s in tracer.spans()
+                       if s.name == "round.train.train"),
+                      key=lambda s: s.start_s)
+    under = [s for s in tracer.spans() if s.name.startswith("compile.")
+             and s.parent_id == first_train.span_id]
+    assert {"compile.trace", "compile.lower", "compile.backend"} <= {
+        s.name for s in under}
+    assert all(s.attrs["task_id"] == "tel-task" and s.attrs["round_idx"] == 0
+               for s in under)
+    assert _label_value(compile_g, task_id="tel-task", operator="train") == (
+        pytest.approx(sum(s.duration_s for s in under)))
+    assert 0 < sum(s.duration_s for s in under) < first_train.duration_s
+    sums = {dict(zip(phases.label_names, k))["phase"]: child.sum
+            for k, child in phases.children()
+            if dict(zip(phases.label_names, k))["operator"] == "train"}
+    assert sums["train"] == pytest.approx(sum(
+        s.duration_s for s in tracer.spans() if s.name == "round.train.train"))
     assert _label_value(reg.get("ols_fedcore_round_steps_total"),
                         algorithm="fedavg") == 2
     assert _label_value(reg.get("ols_checkpoint_save_bytes_total"),
