@@ -33,6 +33,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -127,6 +128,69 @@ class ControlState(struct.PyTreeNode):
     server_control: Any
 
 
+# ``sample_mode: "auto"``: wasted training FLOPs per gathered byte above
+# which the minibatch is gathered. Read on one TPU v5 lite chip (jax 0.9.0,
+# libtpu 0.0.34) on 2026-09-28, PR 26's tree on d96ac51, both realizations
+# timed at eight ratios (PERF.md section 6, PR 26). With no more than twice
+# the batch in local rows: multiplicity 3-4% faster at 6.1e2 (mlp2, 64 rows
+# for 32) and 1.4e3 (cnn4, 50 for 32); gather 1.7x and 1.6x faster at 2.0e5
+# (ViT-Tiny 50/32) and 1.7e6 (ResNet-18 40/20), and a 1.26x shorter round
+# at 3.2e7 (DistilBERT 24/16, the benchmark's cell). The constant sits at
+# the low end of that bracket because cnn4's two readings interpolate to a
+# crossover at 2e3, and no lower so that the toy programs of the audit grid
+# (3.5e3) and of tests/benchmark (1.7e3) stay what analysis/budgets.json
+# and those tests hold them to. With more than twice the batch gather won
+# at every ratio read: 2.1x at 4.3e3 (mlp2 256/32), 1.8x at 1.3e4 (cnn4
+# 200/32), 4.8x at 1.9e4 (mlp2 1,024/32), and mlp2's readings interpolate
+# to a crossover at 2.15x the batch (7e2) — hence the rule's second clause.
+GATHER_FLOP_PER_BYTE = 4e3
+
+
+def auto_uses_multiplicity(n_local: int, batch_size: int, row_bytes: int,
+                           row_train_flops: Callable[[], float]) -> bool:
+    """The ``sample_mode: "auto"`` rule, a function of shapes and — only
+    where they do not decide — of what the model costs to train on one row
+    (``row_train_flops()``). With no more local rows than the batch,
+    multiplicity computes fewer rows than a gathered batch and gathers
+    nothing. With more than twice the batch it trains over twice the rows
+    the step needs, and gather won every such reading on the chip. Between
+    the two it trains ``n_local - batch_size`` rows a step whose weight is
+    zero where gathering moves ``batch_size`` rows: gather when the wasted
+    FLOPs per moved byte pass ``GATHER_FLOP_PER_BYTE``."""
+    if n_local <= batch_size:
+        return True
+    if n_local > 2 * batch_size:
+        return False
+    wasted = (n_local - batch_size) * row_train_flops()
+    moved = batch_size * row_bytes
+    return wasted <= GATHER_FLOP_PER_BYTE * moved
+
+
+def _matmul_flops(jaxpr) -> float:
+    """2 FLOPs per multiply-add of every ``dot_general`` and convolution in
+    ``jaxpr``, sub-jaxprs included (a ``scan`` body times its length, every
+    other one once: a ``while`` counts one trip, a ``cond`` all branches)."""
+    total = 0.0
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "dot_general":
+            (lhs_contract, _), _ = eqn.params["dimension_numbers"]
+            lhs = eqn.invars[0].aval.shape
+            total += 2.0 * math.prod(eqn.outvars[0].aval.shape) * math.prod(
+                lhs[d] for d in lhs_contract)
+        elif name == "conv_general_dilated":
+            rhs = eqn.invars[1].aval.shape
+            _, in_dim, *spatial = eqn.params["dimension_numbers"].rhs_spec
+            total += 2.0 * math.prod(eqn.outvars[0].aval.shape) * (
+                rhs[in_dim] * math.prod(rhs[d] for d in spatial))
+        else:
+            trips = eqn.params["length"] if name == "scan" else 1
+            total += trips * sum(
+                _matmul_flops(sub)
+                for sub in jax.core.jaxprs_in_params(eqn.params))
+    return total
+
+
 @dataclasses.dataclass(frozen=True)
 class FedCoreConfig:
     batch_size: int = 32
@@ -146,9 +210,13 @@ class FedCoreConfig:
     # the dynamic gather disappears from the hot loop and the fwd/bwd runs
     # over n_local samples instead of batch_size. The two modes are
     # mathematically identical for the same index draw (not bitwise: the
-    # reductions accumulate in different orders). "auto" picks multiplicity
-    # when n_local <= 2 * batch_size (profiling: the gather alone cost
-    # ~4.6ms per 128-client block-step on v5e).
+    # reductions accumulate in different orders). "auto" lets the core
+    # choose per (local-set size, row shape and dtype, model): see
+    # ``auto_uses_multiplicity`` for the rule and ``FedCore.use_multiplicity``
+    # for the one place it is asked. For a model that sows an auxiliary
+    # loss the two are different estimators of that term (multiplicity:
+    # the whole local set; gather: the minibatch); "auto" may choose
+    # either and the aux estimator follows the choice.
     sample_mode: str = "auto"
     # lax.scan unroll factor for the local-SGD step loop. Unrolling lets XLA
     # fuse/pipeline across sequential steps (the per-step tensors are small,
@@ -200,18 +268,9 @@ class FedCoreConfig:
                     f"FedCoreConfig.{fld} must be an int >= 1, got {v!r}"
                 )
         if self.sample_mode not in ("auto", "gather", "multiplicity"):
-            # Checked here (not only lazily in use_multiplicity) so a bad
-            # value fails at submit validation, not at first trace.
+            # Checked here so a bad value fails at submit validation, not
+            # at first trace.
             raise ValueError(f"unknown sample_mode {self.sample_mode!r}")
-
-    def use_multiplicity(self, n_local: int) -> bool:
-        if self.sample_mode == "multiplicity":
-            return True
-        if self.sample_mode == "gather":
-            return False
-        if self.sample_mode != "auto":
-            raise ValueError(f"unknown sample_mode {self.sample_mode!r}")
-        return n_local <= 2 * self.batch_size
 
     @classmethod
     def from_dict(cls, obj: dict) -> "FedCoreConfig":
@@ -435,6 +494,8 @@ class FedCore:
         self.config = config
         self.param_specs = param_specs
         self._pp_train = pp_train
+        # use_multiplicity's answers, by (n_local, row shape).
+        self._multiplicity: dict = {}
         if plan.pp > 1 and pp_train is None:
             raise ValueError(
                 "plan has pp > 1 but no pp_train=(model, microbatches) was "
@@ -602,6 +663,41 @@ class FedCore:
         )
 
     # ------------------------------------------------------- local training
+    def use_multiplicity(self, n_local: int, row_shape: Tuple[int, ...],
+                         row_dtype: Any) -> bool:
+        """Whether local training realizes its minibatch as multiplicity
+        weights over a client's ``n_local`` rows (else gathers
+        ``batch_size`` of them): ``config.sample_mode`` and, under
+        ``"auto"``, :func:`auto_uses_multiplicity` on what this model
+        costs to train on one row of that shape and dtype. Decided at the
+        first ask for ``(n_local, row_shape)`` and remembered, so
+        ``_masked_sgd``, which asks while it traces, and the runner's work
+        counts get one answer even where the host holds the rows in another
+        float width than the program is given (the streamed path)."""
+        mode = self.config.sample_mode
+        if mode != "auto":
+            return mode == "multiplicity"
+        key = (int(n_local), tuple(row_shape))
+        if key not in self._multiplicity:
+            self._multiplicity[key] = auto_uses_multiplicity(
+                n_local, self.config.batch_size,
+                math.prod(row_shape) * np.dtype(row_dtype).itemsize,
+                lambda: self._row_train_flops(row_shape, row_dtype),
+            )
+        return self._multiplicity[key]
+
+    def _row_train_flops(self, row_shape: Tuple[int, ...],
+                         row_dtype: Any) -> float:
+        """FLOPs to train this model on one row: the matmul and convolution
+        work of its one-row forward pass, times 3 for the forward,
+        input-gradient and weight-gradient passes. Counted from the jaxpr,
+        so the chip and a CPU test price a model alike
+        (``Lowered.cost_analysis`` has no TPU implementation)."""
+        p_shapes = jax.eval_shape(self.init_params_fn, jax.random.key(0))
+        row = jax.ShapeDtypeStruct((1,) + tuple(row_shape), row_dtype)
+        return 3.0 * _matmul_flops(
+            jax.make_jaxpr(self.apply_fn)(p_shapes, row).jaxpr)
+
     def _masked_sgd(self, params0, opt_state0, x, y, num_samples, steps_eff,
                     key, persample_loss_fn, penalty_fn=None,
                     grad_transform=None, varying_init=False):
@@ -616,17 +712,18 @@ class FedCore:
         unreduced losses plus an already-weighted auxiliary loss (0.0 for
         models without one);
         ``penalty_fn(params) -> scalar`` optional regularizer (FedProx).
-        The minibatch is realized either by gathering rows or — for small
-        local sets — as multiplicity weights over the full set (see
-        ``FedCoreConfig.sample_mode``); both produce mathematically
-        identical gradients for the same index draw (up to float reduction
-        order).
+        The minibatch is realized either by gathering rows or as
+        multiplicity weights over the full local set, as
+        :meth:`use_multiplicity` decides for this ``x``; both produce
+        mathematically identical gradients for the same index draw (up to
+        float reduction order). An auxiliary loss is the exception: it sees
+        the rows the model is run on, the whole local set or the minibatch.
         """
         cfg = self.config
         alg = self.algorithm
         n = jnp.maximum(num_samples, 1)
         n_local = x.shape[0]
-        use_mult = cfg.use_multiplicity(n_local)
+        use_mult = self.use_multiplicity(n_local, x.shape[1:], x.dtype)
         # SGD without momentum has an empty optimizer state; then masking is
         # cheaper as update-scaling (one fused multiply) than as a
         # double-buffered tree_where over params AND state.
@@ -713,7 +810,9 @@ class FedCore:
     def _persample(self, p, xb, yb):
         """Shared per-sample CE + (weighted) model aux loss. In multiplicity
         mode the aux term sees the client's full local set rather than the
-        sampled minibatch — both are unbiased regularizer estimates."""
+        sampled minibatch — both are unbiased regularizer estimates, and
+        which one a ``sample_mode: "auto"`` build trains with follows
+        :meth:`use_multiplicity`."""
         if self.apply_aux_fn is None:
             logits = self.apply_fn(p, xb)
             aux = jnp.float32(0.0)

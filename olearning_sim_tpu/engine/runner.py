@@ -576,20 +576,23 @@ class SimulationRunner:
         """Work counts of one train launch, on its ``host_transfer`` span
         (set once the device has answered): what the round program computed
         against what the round needed. The program trains every resident
-        row, padding and withheld clients included, and under
-        ``use_multiplicity`` every local sample each step."""
+        row, padding and withheld clients included, and where the core
+        chose multiplicity (``FedCore.use_multiplicity``) every local
+        sample each step."""
         if span is None:
             return
         cfg = self.core.config
-        n_local = int(p.dataset.x.shape[1])
+        x = p.dataset.x
+        n_local = int(x.shape[1])
         span.attrs.update(
             clients_resident=int(p.dataset.num_clients),
             clients_released=int(trace.num_released),
             clients_trained=clients_trained,
             local_steps=int(cfg.max_local_steps),
-            samples_computed_per_step=(n_local
-                                       if cfg.use_multiplicity(n_local)
-                                       else int(cfg.batch_size)),
+            samples_computed_per_step=(
+                n_local
+                if self.core.use_multiplicity(n_local, x.shape[2:], x.dtype)
+                else int(cfg.batch_size)),
             samples_needed_per_step=min(int(cfg.batch_size), n_local),
         )
 

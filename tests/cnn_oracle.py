@@ -5,7 +5,8 @@ Implements the same network as ``olearning_sim_tpu/models/cnn.py::CNN``
 entirely in NumPy float32, forward and backward, with FedAvg local SGD
 using the engine's exact RNG streams (fold_in(fold_in(base_key, uid),
 round) then fold_in(key, step) -> randint) and multiplicity-weighted
-minibatches (the engine's auto sample mode at n_local <= 2 * batch). No
+minibatches (what the engine's auto sample mode picks for cnn4 at the
+sizes tests/test_parity_cnn.py runs: see ``FedCore.use_multiplicity``). No
 code is shared with the engine beyond jax.random for RNG stream
 reproduction — RNG is an input, not the system under test.
 

@@ -15,8 +15,14 @@ from olearning_sim_tpu.engine import (
     fedyogi,
     make_synthetic_dataset,
 )
-from olearning_sim_tpu.engine.client_data import make_central_eval_set
-from olearning_sim_tpu.engine.fedcore import FedCoreConfig
+from olearning_sim_tpu.engine.client_data import (
+    make_central_eval_set,
+    make_synthetic_text_dataset,
+)
+from olearning_sim_tpu.engine.fedcore import (
+    FedCoreConfig,
+    auto_uses_multiplicity,
+)
 from olearning_sim_tpu.parallel.mesh import make_mesh_plan
 
 INPUT_SHAPE = (16,)
@@ -126,7 +132,29 @@ def test_padding_weights_zero():
     assert (w[:10] > 0).all()
 
 
-def test_gather_and_multiplicity_modes_agree():
+def _mode_case_mlp2():
+    ds = make_synthetic_dataset(
+        SEED, 32, 12, INPUT_SHAPE, NUM_CLASSES, class_sep=4.0,
+        num_samples_range=(4, 12),  # heterogeneity: idx drawn in [0, n_c)
+    )
+    return ("mlp2", {"hidden": (32,), "num_classes": NUM_CLASSES},
+            INPUT_SHAPE, ds)
+
+
+def _mode_case_distilbert():
+    # Token rows: int32, nothing for place() to cast, gathered by row.
+    ds = make_synthetic_text_dataset(
+        SEED, 16, 12, seq_len=8, num_classes=2, vocab_size=64,
+        num_samples_range=(4, 12),
+    )
+    return ("distilbert", {"vocab_size": 64, "max_len": 8, "width": 16,
+                           "depth": 1, "heads": 2, "mlp_dim": 32,
+                           "num_classes": 2}, (8,), ds)
+
+
+@pytest.mark.parametrize("case", [_mode_case_mlp2, _mode_case_distilbert],
+                         ids=["mlp2", "distilbert"])
+def test_gather_and_multiplicity_modes_agree(case):
     """The two minibatch realizations draw the same indices and must produce
     the same training trajectory (identical math up to float reduction
     order) — the exactness claim behind FedCoreConfig.sample_mode."""
@@ -135,15 +163,15 @@ def test_gather_and_multiplicity_modes_agree():
         plan = make_mesh_plan(dp=8, mp=1)
         cfg = FedCoreConfig(batch_size=8, max_local_steps=3, block_clients=4,
                             sample_mode=mode)
+        model, overrides, input_shape, ds = case()
         core = build_fedcore(
-            "mlp2", fedavg(0.1), plan, cfg,
-            model_overrides={"hidden": (32,), "num_classes": NUM_CLASSES},
-            input_shape=INPUT_SHAPE,
+            model, fedavg(0.1), plan, cfg,
+            model_overrides=overrides, input_shape=input_shape,
         )
-        ds = make_synthetic_dataset(
-            SEED, 32, 12, INPUT_SHAPE, NUM_CLASSES, class_sep=4.0,
-            num_samples_range=(4, 12),  # heterogeneity: idx drawn in [0, n_c)
-        ).pad_for(plan, 4).place(plan, feature_dtype=None)
+        ds = ds.pad_for(plan, 4).place(plan, feature_dtype=None)
+        assert core.use_multiplicity(
+            ds.x.shape[1], ds.x.shape[2:], ds.x.dtype
+        ) == (mode == "multiplicity")
         state = core.init_state(jax.random.key(7))
         for _ in range(2):
             state, metrics = core.round_step(state, ds)
@@ -157,6 +185,58 @@ def test_gather_and_multiplicity_modes_agree():
         lambda a, b: np.testing.assert_allclose(a, b, atol=1e-3, rtol=1e-3),
         pg, pm,
     )
+
+
+# (n_local, batch, bytes of one row, FLOPs to train one row) -> multiplicity?
+# ISSUE 26's four families (float32 rows, as the issue priced them), the
+# two clauses that shapes alone decide (no more rows than a gathered batch;
+# more than twice as many), and the shapes PR 26 timed on the chip,
+# bfloat16 rows as the task bridge places them: each on the side that was
+# faster there.
+@pytest.mark.parametrize("n_local,batch,row_bytes,row_flops,multiplicity", [
+    pytest.param(24, 16, 256, 1.65e10, False, id="distilbert_24of16"),
+    pytest.param(50, 32, 12_288, 1.55e7, True, id="cnn4_50of32"),
+    pytest.param(6, 4, 32, 1.11e5, True, id="tiny_distilbert_6of4"),
+    pytest.param(64, 32, 3_136, 9.53e5, True, id="mlp2_64of32"),
+    pytest.param(16, 16, 256, 1.65e10, True, id="n_local_eq_batch"),
+    pytest.param(8, 16, 256, 1.65e10, True, id="n_local_lt_batch"),
+    pytest.param(65, 32, 3_136, 1.0, False, id="n_local_gt_twice_batch"),
+    pytest.param(64, 32, 1_568, 9.53e5, True, id="chip_mlp2_64of32"),
+    pytest.param(50, 32, 6_144, 1.55e7, True, id="chip_cnn4_50of32"),
+    pytest.param(256, 32, 1_568, 9.53e5, False, id="chip_mlp2_256of32"),
+    pytest.param(200, 32, 6_144, 1.55e7, False, id="chip_cnn4_200of32"),
+    pytest.param(50, 32, 6_144, 2.19e9, False, id="chip_vit_tiny_50of32"),
+    pytest.param(40, 20, 1_568, 2.73e9, False, id="chip_resnet18_40of20"),
+])
+def test_auto_sample_mode_rule(n_local, batch, row_bytes, row_flops,
+                               multiplicity):
+    priced = []
+
+    def price():
+        priced.append(row_flops)
+        return row_flops
+
+    assert auto_uses_multiplicity(
+        n_local, batch, row_bytes, price) == multiplicity
+    # The model is priced only where the shapes do not decide.
+    assert bool(priced) == (batch < n_local <= 2 * batch)
+
+
+def test_auto_sample_mode_prices_the_model():
+    """Under ``auto`` the core prices one row of its own model (3 x the
+    forward pass's multiply-adds) and decides once per shape."""
+    plan = make_mesh_plan(dp=8, mp=1)
+    core = build_fedcore(
+        "mlp2", fedavg(0.1), plan, FedCoreConfig(batch_size=8),
+        model_overrides={"hidden": (32,), "num_classes": NUM_CLASSES},
+        input_shape=INPUT_SHAPE,
+    )
+    flops = core._row_train_flops(INPUT_SHAPE, np.float32)
+    assert flops == 3 * 2 * (16 * 32 + 32 * NUM_CLASSES)
+    assert core.use_multiplicity(8, INPUT_SHAPE, np.float32)   # clause 1
+    assert core.use_multiplicity(12, INPUT_SHAPE, np.float32) == (
+        auto_uses_multiplicity(12, 8, 16 * 4, lambda: flops))
+    assert set(core._multiplicity) == {(8, INPUT_SHAPE), (12, INPUT_SHAPE)}
 
 
 def test_unroll_knobs_do_not_change_results():

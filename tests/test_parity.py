@@ -47,7 +47,8 @@ def np_softmax(z):
 def np_local_train(params, x, y, n, uid, base_key, round_idx,
                    correction=None):
     """One client's local SGD, multiplicity-weighted exactly like the engine
-    (FedCoreConfig.sample_mode auto -> multiplicity at n_local<=2*batch).
+    these tests build (``sample_mode="multiplicity"``, set explicitly: what
+    ``auto`` picks depends on the model's cost per row).
     ``correction`` (SCAFFOLD: c - c_i per param) is added to every step's
     gradient."""
     p = {k: v.copy() for k, v in params.items()}

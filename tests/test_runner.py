@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from olearning_sim_tpu.deviceflow import DeviceFlowService
-from olearning_sim_tpu.engine import build_fedcore, fedavg, make_synthetic_dataset
+from olearning_sim_tpu.engine import build_fedcore, fedavg, fedcore, make_synthetic_dataset
 from olearning_sim_tpu.engine.client_data import make_central_eval_set
 from olearning_sim_tpu.engine.fedcore import FedCoreConfig
 from olearning_sim_tpu.engine.runner import DataPopulation, OperatorSpec, SimulationRunner
@@ -22,14 +22,16 @@ from olearning_sim_tpu.taskmgr.status import (
     combine_task_status,
 )
 from olearning_sim_tpu.taskmgr.task_repo import TaskTableRepo
+from olearning_sim_tpu.telemetry import SpanTracer
 
 INPUT_SHAPE = (12,)
 NUM_CLASSES = 3
 
 
-def build_runner(num_clients=32, rounds=3, operators=None, deviceflow=None, repo=None):
+def build_runner(num_clients=32, rounds=3, operators=None, deviceflow=None, repo=None,
+                 tracer=None, batch_size=4):
     plan = make_mesh_plan(dp=8)
-    cfg = FedCoreConfig(batch_size=4, max_local_steps=3, block_clients=2)
+    cfg = FedCoreConfig(batch_size=batch_size, max_local_steps=3, block_clients=2)
     core = build_fedcore(
         "mlp2", fedavg(0.1), plan, cfg,
         model_overrides={"hidden": (16,), "num_classes": NUM_CLASSES},
@@ -56,6 +58,7 @@ def build_runner(num_clients=32, rounds=3, operators=None, deviceflow=None, repo
         rounds=rounds,
         task_repo=repo,
         deviceflow=deviceflow,
+        tracer=tracer,
     )
     return runner
 
@@ -75,6 +78,32 @@ def test_round_loop_trains_and_accounts():
     assert sim["devices"] == ["high", "low"]
     assert sum(sim["success_num"]) == 32
     assert sum(sim["failed_num"]) == 0
+
+
+@pytest.mark.parametrize("flop_per_byte,computed", [
+    pytest.param(0.0, 6, id="gather"),
+    pytest.param(float("inf"), 10, id="multiplicity"),
+])
+def test_work_counts_follow_the_cores_minibatch_choice(
+        monkeypatch, flop_per_byte, computed):
+    """``samples_computed_per_step`` on the ``host_transfer`` span is what
+    ``FedCore.use_multiplicity`` decided for the program that ran, on either
+    side of the ``auto`` rule's constant (10 local rows for a batch of 6:
+    the band where the model's cost decides)."""
+    monkeypatch.setattr(fedcore, "GATHER_FLOP_PER_BYTE", flop_per_byte)
+    tracer = SpanTracer()
+    runner = build_runner(rounds=2, tracer=tracer, batch_size=6)
+    assert runner.core.config.sample_mode == "auto"
+    runner.run()
+    x = runner.populations[0].dataset.x
+    decided = runner.core.use_multiplicity(x.shape[1], x.shape[2:], x.dtype)
+    assert decided == (computed == 10)
+    transfers = [s for s in tracer.spans()
+                 if s.name == "round.train.host_transfer"]
+    assert len(transfers) == 2
+    for s in transfers:
+        assert (s.attrs["samples_computed_per_step"],
+                s.attrs["samples_needed_per_step"]) == (computed, 6)
 
 
 def test_status_fusion_from_runner_output():
