@@ -26,6 +26,7 @@ import dataclasses
 import gc
 import glob
 import json
+import math
 import os
 import shutil
 import sys
@@ -442,6 +443,21 @@ def assemble(ctx: RunContext, verdict: Dict[str, Any], checked,
     return result
 
 
+def compared(run: Run) -> Dict[str, Dict[str, float]]:
+    """What decided ``correct``: each number the run's check held to a
+    limit, and the window's failed rounds, each beside its limit. A reading
+    that is not finite is written as the largest float, so the line stays
+    plain JSON."""
+    checked = run.checks[0]
+    out = {name: {"value": (float(checked.numbers[name])
+                            if math.isfinite(checked.numbers[name])
+                            else sys.float_info.max),
+                  "limit": limit}
+           for name, limit in checked.limits.items()}
+    out["failed_rounds"] = {"value": run.result["failed"], "limit": 0}
+    return out
+
+
 def main(argv: Optional[List[str]] = None,
          t_process_start_pc: Optional[float] = None) -> int:
     import argparse
@@ -455,12 +471,16 @@ def main(argv: Optional[List[str]] = None,
                         help="copy the traced stretch's .xplane.pb here")
     args = parser.parse_args(argv)
     try:
-        result = run_cell(args.workload, args.seed, args.seconds,
-                          bool(args.trace),
-                          t_process_start_pc=t_process_start_pc,
-                          keep_trace=args.keep_trace).result
+        run = run_cell(args.workload, args.seed, args.seconds,
+                       bool(args.trace),
+                       t_process_start_pc=t_process_start_pc,
+                       keep_trace=args.keep_trace)
     except (BenchmarkError, manifest.ManifestError) as e:
         print(f"benchmark FAILED: {e}", file=sys.stderr, flush=True)
         return 1
+    result = dict(run.result, compared=compared(run))    # the key comes last
+    for name, c in result["compared"].items():
+        print(f"compared {name}={c['value']:.6g} limit={c['limit']}",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0

@@ -1,5 +1,8 @@
 """BENCHMARK.json against the contract it has to meet, the files it names,
-and the composition of a task from configuration + traffic + seed."""
+and the composition of a task from configuration + traffic + seed: once for
+the real manifest, once for the rehearsal's (``rehearsal.py``: the real one
+with a second configuration landed beside it as new files and entries), so
+that what a later configuration has to meet is met here first."""
 
 import json
 import os
@@ -7,6 +10,7 @@ import re
 
 import pytest
 
+import rehearsal
 from benchmark import manifest
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -16,9 +20,21 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 WIDTH_WORDS = ("hidden", "intermediate", "latent", "state", "projection")
 
 
+@pytest.fixture(scope="module", params=["BENCHMARK.json", "rehearsal"])
+def manifest_path(request, tmp_path_factory):
+    if request.param == "rehearsal":
+        return rehearsal.write(str(tmp_path_factory.mktemp("rehearsal")))
+    return manifest.MANIFEST
+
+
 @pytest.fixture(scope="module")
-def doc():
-    with open(manifest.MANIFEST, encoding="utf-8") as f:
+def root(manifest_path):
+    return os.path.dirname(manifest_path)
+
+
+@pytest.fixture(scope="module")
+def doc(manifest_path):
+    with open(manifest_path, encoding="utf-8") as f:
         return json.load(f)
 
 
@@ -26,10 +42,10 @@ def _line(text):
     return 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
 
 
-def test_top_level_keys_and_limits(doc):
+def test_top_level_keys_and_limits(doc, manifest_path):
     assert set(doc) == {"command", "paths", "run_seconds", "configs",
                         "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(manifest.MANIFEST) <= 64 * 1024
+    assert os.path.getsize(manifest_path) <= 64 * 1024
     assert 1 <= len(doc["paths"]) <= 16
     assert all(PATH.match(p) and not p.startswith("/") and ".." not in p
                for p in doc["paths"])
@@ -42,7 +58,7 @@ def test_top_level_keys_and_limits(doc):
         assert 1 <= len(doc[key]) <= most
 
 
-def test_configs(doc):
+def test_configs(doc, root):
     names = [c["name"] for c in doc["configs"]]
     assert len(set(names)) == len(names)
     files = [c["file"] for c in doc["configs"]]
@@ -58,7 +74,7 @@ def test_configs(doc):
             assert NAME.match(key)
             assert not key.endswith(("_dim", "_rank"))
             assert not any(word in key for word in WIDTH_WORDS)
-        body = json.load(open(os.path.join(manifest.ROOT, c["file"])))
+        body = json.load(open(os.path.join(root, c["file"])))
         # The file states its own cuts, and they are the manifest's.
         assert body["reduced"] == c["reduced"]
         assert set(body["reduced_why"]) == set(c["reduced"])
@@ -68,7 +84,7 @@ def test_configs(doc):
         assert body["check"]["limits"], "a cell needs the check's limits"
 
 
-def test_workloads(doc):
+def test_workloads(doc, manifest_path):
     names = [w["name"] for w in doc["workloads"]]
     assert len(set(names)) == len(names)
     pairs = [(w["config"], w["traffic"]) for w in doc["workloads"]]
@@ -81,17 +97,19 @@ def test_workloads(doc):
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
         assert w["config"] in configs and w["chips"] in (1, 4)
         assert _line(w["why"])
-        cell = manifest.load_cell(w["name"])     # every file exists and loads
+        cell = manifest.load_cell(w["name"], manifest_path)   # files load
         assert cell.traffic["chips"] == w["chips"]
         for key in ("clients", "n_local", "operators", "warmup_rounds",
                     "trace_rounds", "check_clients"):
             assert key in cell.traffic, key
-        manifest.find_module("reference", cell.config["reference"])
+        manifest.find_module("reference", cell.config["reference"],
+                             cell.files_root)
         manifest.find_module(
-            "reference", "server_" + cell.config["algorithm"]["name"])
+            "reference", "server_" + cell.config["algorithm"]["name"],
+            cell.files_root)
 
 
-def test_metrics(doc):
+def test_metrics(doc, manifest_path):
     cells = {w["name"] for w in doc["workloads"]}
     names = [m["name"] for m in doc["end_to_end"] + doc["per_layer"]]
     assert len(set(names)) == len(names)
@@ -118,27 +136,28 @@ def test_metrics(doc):
                     if cell in m.get("workloads", cells)]
         assert len(reported) >= 2 and any(
             m["name"] == "setup_s" for m in reported)
-        assert manifest.load_cell(cell).per_layer
+        assert manifest.load_cell(cell, manifest_path).per_layer
 
 
-def test_every_metric_has_its_reader_and_they_agree(doc):
+def test_every_metric_has_its_reader_and_they_agree(doc, root):
+    files_root = os.path.join(root, doc["paths"][0])
     for m in doc["end_to_end"]:
-        reader = manifest.find_module("end_to_end", m["name"])
+        reader = manifest.find_module("end_to_end", m["name"], files_root)
         assert (reader.UNIT, reader.SOURCE) == (m["unit"], m["source"])
         assert callable(reader.read)
     for m in doc["per_layer"]:
-        reader = manifest.find_module("layer_metrics", m["name"])
+        reader = manifest.find_module("layer_metrics", m["name"], files_root)
         assert (reader.LAYER, reader.UNIT, reader.SOURCE, reader.MOVES) == (
             m["layer"], m["unit"], m["source"], m["moves"])
         assert callable(reader.read)
 
 
-def test_files_under_paths_are_named_from_name_characters(doc):
+def test_files_under_paths_are_named_from_name_characters(doc, root):
     for base in doc["paths"]:
-        for folder, dirs, files in os.walk(os.path.join(manifest.ROOT, base)):
+        for folder, dirs, files in os.walk(os.path.join(root, base)):
             dirs[:] = [d for d in dirs if d != "__pycache__"]
             for f in files:
-                rel = os.path.relpath(os.path.join(folder, f), manifest.ROOT)
+                rel = os.path.relpath(os.path.join(folder, f), root)
                 assert PATH.match(rel), rel
 
 
@@ -156,9 +175,9 @@ def test_the_harness_holds_no_cell_model_or_metric_name(doc):
 
 
 @pytest.mark.parametrize("seed", [7, 2**31 + 11])
-def test_compose_task(doc, seed):
+def test_compose_task(doc, manifest_path, seed):
     for w in doc["workloads"]:
-        cell = manifest.load_cell(w["name"])
+        cell = manifest.load_cell(w["name"], manifest_path)
         task = manifest.compose_task(cell, seed)
         assert task["task_id"] == f"{w['name']}-s{seed}"
         data = task["target"]["data"][0]

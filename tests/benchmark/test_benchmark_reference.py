@@ -11,6 +11,8 @@ import numpy as np
 import optax
 import pytest
 
+import config_contract
+import tiny_preset
 from benchmark import check, manifest
 from benchmark.reference import fedround
 
@@ -59,64 +61,55 @@ def test_distilbert_reference_matches_the_flax_model_in_float32():
     assert set(grads) == set(check.flatten(want))
 
 
-def test_the_programs_model_is_what_the_configuration_file_states():
-    """Every size in the file's ``model`` block is the size the program
-    builds for the task as composed (registry defaults + the task's
-    overrides), and the departures the file states are the program's."""
-    from olearning_sim_tpu.models import get_model
-
-    for entry in json.load(open(manifest.MANIFEST))["configs"]:
-        config = json.load(open(os.path.join(manifest.ROOT, entry["file"])))
-        stated = config["model"]
-        task_model = next(
-            op["logical_simulation"]["operator_params"]["model"]
-            for op in config["task"]["operatorflow"]["operators"]
-            if isinstance(op["logical_simulation"]["operator_params"], dict))
-        spec = get_model(task_model["name"])
-        built = {**spec.defaults, **task_model.get("overrides", {})}
-        for file_key, program_key in config["model_keys"].items():
-            assert built[program_key] == stated[file_key], file_key
-        assert list(task_model["input_shape"]) == [stated["sequence_length"]]
-        # Shapes of the model as built, without computing anything.
-        module = spec.build(**task_model.get("overrides", {}))
-        tokens = jax.ShapeDtypeStruct((1, stated["sequence_length"]), jnp.int32)
-        shapes = check_shapes(jax.eval_shape(
-            lambda t: module.init(jax.random.key(0), t), tokens)["params"])
-        W, M = stated["dim"], stated["hidden_dim"]
-        assert shapes["Embed_0/embedding"] == (stated["vocab_size"], W)
-        assert shapes["pos_embedding"] == (
-            1, stated["max_position_embeddings"], W)
-        blocks = {k.split("/")[0] for k in shapes
-                  if k.startswith("TransformerBlock_")}
-        assert len(blocks) == stated["n_layers"]
-        assert shapes["TransformerBlock_0/Dense_0/kernel"] == (W, M)
-        assert shapes["TransformerBlock_0/MultiHeadDotProductAttention_0/"
-                      "query/kernel"] == (W, stated["n_heads"],
-                                          W // stated["n_heads"])
-        # The head the file states: pooled vector -> Dense(num_classes),
-        # and no pre_classifier layer.
-        assert stated["head"] == "mean_pool_dense"
-        top = {k.split("/")[0] for k in shapes}
-        assert top == blocks | {"Embed_0", "pos_embedding", "LayerNorm_0",
-                                "Dense_0"}
-        assert shapes["Dense_0/kernel"] == (W, stated["num_classes"])
-        assert stated["layer_norm_eps"] == nn.LayerNorm().epsilon
-        assert stated["activation"] == "gelu_tanh"
-        x = jnp.linspace(-3, 3, 13)
-        reference = manifest.find_module("reference", config["reference"])
-        np.testing.assert_allclose(nn.gelu(x), reference._gelu_tanh(x),
-                                   rtol=1e-6, atol=1e-7)
-        # The published encoder's 66,362,880 parameters, less the position
-        # rows cut, plus this head.
-        assert sum(int(np.prod(s)) for s in shapes.values()) == (
-            66_362_880 - (512 - stated["max_position_embeddings"]) * W
-            + W * stated["num_classes"] + stated["num_classes"])
+def _configs():
+    with open(manifest.MANIFEST, encoding="utf-8") as f:
+        return [c["name"] for c in json.load(f)["configs"]]
 
 
-def check_shapes(tree):
-    leaves, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return {"/".join(str(getattr(p, "key", p)) for p in path): leaf.shape
-            for path, leaf in leaves}
+@pytest.mark.parametrize("name", _configs())
+def test_the_programs_model_is_what_the_configuration_file_states(name):
+    """What holds for any configuration: the task names a model of the
+    registry; every size the file's ``model`` block states through
+    ``model_keys`` is the size the program builds for the task as composed
+    (registry defaults + the task's overrides); the task's input is the
+    stated one; the model initialises; the reference and the server step
+    have the functions the check and the FLOP count call. What is one
+    model's (its tree, its head, its parameter total) is in that
+    configuration's own ``test_config_<name>.py``."""
+    config, task_model, spec = config_contract.load(name)
+    stated = config["model"]
+    built = {**spec.defaults, **task_model.get("overrides", {})}
+    for file_key, program_key in config.get("model_keys", {}).items():
+        assert built[program_key] == stated[file_key], file_key
+    assert list(task_model["input_shape"]) == config_contract.stated_input(
+        stated)
+    assert config_contract.init_shapes(spec, task_model)
+    reference = manifest.find_module("reference", config["reference"])
+    for function in ("prepare", "loss_and_grad", "layers"):
+        assert callable(getattr(reference, function)), function
+    assert reference.layers(stated)
+    server = manifest.find_module(
+        "reference", "server_" + config["algorithm"]["name"])
+    assert callable(server.step) and callable(server.recover_mean_delta)
+
+
+@pytest.mark.parametrize("name", _configs())
+def test_every_configuration_brings_its_contract_test_and_tiny_preset(name):
+    """A configuration without a contract test of its own, or without the
+    preset the CPU harness tests run it at, is refused here by name."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    contract = os.path.join(here, f"test_config_{name}.py")
+    assert os.path.exists(contract), (
+        f"configuration {name!r} has no contract test: add {contract}")
+    preset = tiny_preset.load(name)         # raises, naming the file
+    for key in ("overrides", "model", "input_shape", "traffic", "limits",
+                "why"):
+        assert key in preset, (name, key)
+
+
+def test_a_missing_tiny_preset_is_an_error_that_names_the_file():
+    with pytest.raises(FileNotFoundError, match=r"data/tiny/no_such\.json"):
+        tiny_preset.load("no_such")
 
 
 def test_minibatch_weights_are_the_engines_stream():
@@ -159,6 +152,30 @@ def test_fedadam_server_step_matches_optax_over_three_rounds(b1, b2, eps):
         for k in params:
             np.testing.assert_allclose(recovered[k], delta[k],
                                        rtol=1e-3, atol=1e-8)
+
+
+@pytest.mark.parametrize("server_lr", [1.0, 0.5])
+def test_fedavg_server_step_matches_optax_over_three_rounds(server_lr):
+    server = manifest.find_module("reference", "server_fedavg")
+    algorithm = {"name": "fedavg", "server_lr": server_lr}
+    rng = np.random.default_rng(4)
+    params = {"a/kernel": rng.standard_normal((3, 4)).astype(np.float32),
+              "b/bias": rng.standard_normal(4).astype(np.float32)}
+    tx = optax.sgd(server_lr)
+    state = tx.init(params)
+    assert check.adam_state(state) is None          # nothing to carry
+    for _ in range(3):
+        delta = {k: (1e-3 * rng.standard_normal(v.shape)).astype(np.float32)
+                 for k, v in params.items()}
+        updates, state = tx.update(
+            jax.tree.map(lambda d: -d, delta), state, params)
+        got, carried = server.step(delta, None, algorithm)
+        assert carried is None
+        for k in params:
+            np.testing.assert_allclose(got[k], np.asarray(updates[k]),
+                                       rtol=1e-6, atol=0)
+        params = {k: params[k] + got[k] for k in params}
+    assert server.recover_mean_delta(None, None, algorithm) is None
 
 
 def test_worst_leaf_floors_small_leaves_at_the_median():

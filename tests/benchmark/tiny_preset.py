@@ -9,28 +9,22 @@ import os
 
 from benchmark import manifest
 
-TINY_MODELS = {
-    "distilbert_sent140": {
-        "overrides": {"num_classes": 2, "vocab_size": 64, "max_len": 8,
-                      "width": 16, "depth": 1, "heads": 2, "mlp_dim": 32},
-        "model": {"dim": 16, "n_layers": 1, "n_heads": 2, "hidden_dim": 32,
-                  "vocab_size": 64, "sequence_length": 8,
-                  "max_position_embeddings": 8},
-        "input_shape": [8],
-        "traffic": {"clients": 16, "n_local": 6,
-                    "fedcore": {"block_clients": 4, "batch_size": 4,
-                                "max_local_steps": 2},
-                    "deviceflow": "keep"},
-        # CPU readings at this size, 4 checks each (tests/benchmark only; the
-        # chip's are in PERF.md), sound <= / carry_dtype=bf16 >= / planted
-        # server faults >=: pseudo_grad_global 0.0082 / 0.165; param_delta
-        # _global 0.0026 / 0.064 / 0.295; pseudo_grad worst leaf 0.0165 / 1.87.
-        "limits": {"clients_trained_gap": 0, "client_loss_gap": 0.05,
-                   "pseudo_grad_global_rel_l2": 0.05,
-                   "param_delta_global_rel_l2": 0.03,
-                   "pseudo_grad_rel_l2": 0.2},
-    },
-}
+TINY_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "data", "tiny")
+
+
+def load(config_name: str) -> dict:
+    """The CPU preset of configuration ``config_name``:
+    ``data/tiny/<config>.json`` with ``overrides`` (the task's model
+    overrides), ``model`` (the sizes the file's model block then states),
+    ``input_shape``, ``traffic``, ``limits`` and, as ``why``, the CPU
+    readings the limits were set from. A configuration brings its own."""
+    path = os.path.join(TINY_DIR, config_name + ".json")
+    if not os.path.exists(path):
+        raise FileNotFoundError(
+            f"configuration {config_name!r} has no tiny preset: add {path}")
+    with open(path, encoding="utf-8") as f:
+        return json.load(f)
 
 
 def write(tmp_path, base_config: str, base_traffic: str,
@@ -38,7 +32,7 @@ def write(tmp_path, base_config: str, base_traffic: str,
     """Writes ``<tmp>/BENCHMARK.json`` + ``<tmp>/bench/...`` for a tiny cell
     ``tiny.cell`` built from ``base_config``; returns the manifest path."""
     real = json.load(open(manifest.MANIFEST))
-    tiny = TINY_MODELS[base_config]
+    tiny = load(base_config)
     files = os.path.join(tmp_path, "bench")
     for sub in ("configs", "traffic", "layer_metrics"):
         os.makedirs(os.path.join(files, sub), exist_ok=True)
