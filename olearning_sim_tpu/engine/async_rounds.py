@@ -737,6 +737,7 @@ def build_async_round_step(core, num_windows: int, schedule: str,
         mean_loss=rep, weight_sum=rep, clients_trained=rep, client_loss=cl,
         personal_loss=rep, stragglers=rep,
         anomaly_score=cl if defense_score else rep, clipped=rep,
+        model_stats=rep,
     )
     stats_specs = AsyncStats(
         commits=rep, committed_weight=rep, dropped_stale=rep,

@@ -84,6 +84,12 @@ class RoundMetrics(struct.PyTreeNode):
     # L2 norm was clipped this round (0 on the defense-off path).
     anomaly_score: jnp.ndarray = struct.field(default_factory=lambda: jnp.float32(0.0))
     clipped: jnp.ndarray = struct.field(default_factory=lambda: jnp.float32(0.0))
+    # Work counts the client model sows while it trains (``apply_stats_fn``:
+    # a routed expert layer's assignments and loads), int32, summed over
+    # the round's active local steps of every computed client; scalar 0 for
+    # a model that sows none and on every program but the resident
+    # dp-manual one. ``FedCore.describe_stats`` names what is in it.
+    model_stats: jnp.ndarray = struct.field(default_factory=lambda: jnp.float32(0.0))
 
 
 @dataclasses.dataclass
@@ -144,6 +150,8 @@ class ControlState(struct.PyTreeNode):
 # 200/32), 4.8x at 1.9e4 (mlp2 1,024/32), and mlp2's readings interpolate
 # to a crossover at 2.15x the batch (7e2) — hence the rule's second clause.
 GATHER_FLOP_PER_BYTE = 4e3
+
+TASKS = ("classification", "next_token")
 
 
 def auto_uses_multiplicity(n_local: int, batch_size: int, row_bytes: int,
@@ -256,6 +264,15 @@ class FedCoreConfig:
     # use qualifies) and is mutually exclusive with tensor parallelism
     # (mp > 1).
     shard_server_update: bool = False
+    # What a sample's loss is. "classification": one class label a sample,
+    # cross-entropy of ``[n, classes]`` logits. "next_token": the model
+    # returns ``[n, L, vocab]`` logits for ``[n, L]`` tokens, the targets
+    # are the tokens themselves shifted by one, a sample's loss is the mean
+    # over its L - 1 positions, and evaluation reports that loss and the
+    # next-token accuracy; the dataset's labels are ignored. Not an
+    # engine-params knob: the task bridge sets it from the task's
+    # ``task_type``.
+    task: str = "classification"
 
     def __post_init__(self):
         # scan(unroll=0) and zero-length loops fail at trace time with
@@ -271,6 +288,9 @@ class FedCoreConfig:
             # Checked here so a bad value fails at submit validation, not
             # at first trace.
             raise ValueError(f"unknown sample_mode {self.sample_mode!r}")
+        if self.task not in TASKS:
+            raise ValueError(
+                f"unknown fedcore task {self.task!r} (known: {TASKS})")
 
     @classmethod
     def from_dict(cls, obj: dict) -> "FedCoreConfig":
@@ -291,7 +311,8 @@ class FedCoreConfig:
                 f"fedcore config must be a JSON object, got "
                 f"{type(obj).__name__}"
             )
-        known = {f.name for f in dataclasses.fields(cls)}
+        # ``task`` is not a knob here: the task's ``task_type`` sets it.
+        known = {f.name for f in dataclasses.fields(cls)} - {"task"}
         unknown = sorted(set(obj) - known)
         if unknown:
             # A typo (cary_dtype) must fail at submit time, not silently
@@ -350,6 +371,19 @@ def _accumulate_delta(sum_delta, deltas, bw_eff, gate):
             return s + jnp.tensordot(bw_eff, d, axes=(0, 0))
 
     return jax.tree.map(one, sum_delta, deltas)
+
+
+def _one_client_block(fn, in_axes):
+    """``jax.vmap(fn, in_axes)`` for a block of exactly one client, without
+    the batching: the block axis is squeezed off the mapped arguments and
+    put back on the results."""
+
+    def one(*args):
+        args = [a if axis is None else jax.tree.map(lambda t: t[0], a)
+                for a, axis in zip(args, in_axes)]
+        return jax.tree.map(lambda t: t[None], fn(*args))
+
+    return one
 
 
 def _to_varying(tree, axis: str):
@@ -468,6 +502,9 @@ class FedCore:
         param_specs: Any = None,
         apply_aux_fn: Optional[Callable[[Any, jax.Array], Tuple[jax.Array, jax.Array]]] = None,
         pp_train: Optional[Tuple[Any, Optional[int]]] = None,
+        apply_stats_fn: Optional[Callable[[Any, jax.Array], Tuple[jax.Array, jax.Array]]] = None,
+        describe_stats: Optional[Callable[[np.ndarray], dict]] = None,
+        vmap_clients: bool = True,
     ):
         """``param_specs`` — optional PartitionSpec pytree (same treedef as
         the params) sharding model tensors over the mesh ``mp`` axis
@@ -481,6 +518,19 @@ class FedCore:
         ``ce + config.aux_loss_weight * aux`` so the router stays balanced
         in the federated path too (not just under ``ep_train_step``).
 
+        ``apply_stats_fn(params, x) -> (logits, stats)`` — optional forward
+        that also returns the int32 work counts the model sows (a routed
+        expert layer's assignments); the resident dp-manual round program
+        sums them over the round into ``RoundMetrics.model_stats``, and
+        ``describe_stats(summed) -> {name: number}`` names them for the
+        runner's work counts. Mutually exclusive with ``apply_aux_fn``.
+
+        ``vmap_clients=False`` — the model cannot be ``vmap``ped over
+        per-client weights (``ModelSpec.vmap_clients``: a grouped matmul
+        batches over a leading axis only): clients are taken one at a
+        time, which the resident dp-manual program does at
+        ``block_clients`` 1 by squeezing the block axis.
+
         ``pp_train`` — ``(model, microbatches)`` for a pipeline-parallel
         mesh plan (``plan.pp > 1``): the per-client train body is then the
         stage-pipelined program of :mod:`olearning_sim_tpu.engine.
@@ -488,6 +538,9 @@ class FedCore:
         ``model``). Required iff ``plan.pp > 1``."""
         self.apply_fn = apply_fn
         self.apply_aux_fn = apply_aux_fn
+        self.apply_stats_fn = apply_stats_fn
+        self.describe_stats = describe_stats
+        self.vmap_clients = vmap_clients
         self.init_params_fn = init_params_fn
         self.algorithm = algorithm
         self.plan = plan
@@ -502,6 +555,20 @@ class FedCore:
                 "given — the pipelined per-client body needs the dense "
                 "TextTransformer instance (build_fedcore wires this)"
             )
+        if not vmap_clients and (
+                config.block_clients != 1 or plan.mp > 1 or plan.pp > 1
+                or algorithm.personalized or algorithm.control_variates):
+            raise ValueError(
+                "this model takes clients one at a time: it needs "
+                "fedcore.block_clients 1, no mp or pp mesh axis and a "
+                "plain (not personalized, no control variates) algorithm")
+        if apply_stats_fn is not None and apply_aux_fn is not None:
+            raise ValueError(
+                "apply_stats_fn and apply_aux_fn are mutually exclusive")
+        if config.task == "next_token" and algorithm.personalized:
+            raise ValueError(
+                "task 'next_token' does not compose with a personalized "
+                "algorithm: evaluate_personal scores one label a sample")
         if algorithm.personalized and algorithm.control_variates:
             raise ValueError(
                 "personalized and control_variates are mutually exclusive "
@@ -700,7 +767,8 @@ class FedCore:
 
     def _masked_sgd(self, params0, opt_state0, x, y, num_samples, steps_eff,
                     key, persample_loss_fn, penalty_fn=None,
-                    grad_transform=None, varying_init=False):
+                    grad_transform=None, varying_init=False,
+                    with_stats=False):
         """Masked local-SGD loop shared by the global and Ditto branches:
         step ``i`` samples a minibatch from the valid prefix, applies the
         local optimizer, and is a no-op when ``i >= steps_eff``. Returns
@@ -718,6 +786,10 @@ class FedCore:
         mathematically identical gradients for the same index draw (up to
         float reduction order). An auxiliary loss is the exception: it sees
         the rows the model is run on, the whole local set or the minibatch.
+
+        ``with_stats``: ``persample_loss_fn`` returns a third value, the
+        model's int32 work counts of that forward pass, and the return is
+        ``(final_params, mean_loss, counts summed over the active steps)``.
         """
         cfg = self.config
         alg = self.algorithm
@@ -741,19 +813,24 @@ class FedCore:
                 )
 
                 def loss_fn(p):
-                    losses, aux = persample_loss_fn(p, x, y)
+                    losses, aux, *stats = persample_loss_fn(p, x, y)
                     loss = (sw * losses).sum() + aux
-                    return loss + (penalty_fn(p) if penalty_fn else 0.0)
+                    loss = loss + (penalty_fn(p) if penalty_fn else 0.0)
+                    return (loss, stats[0]) if with_stats else loss
             else:
 
                 def loss_fn(p):
                     xb = jnp.take(x, idx, axis=0)
                     yb = jnp.take(y, idx, axis=0)
-                    losses, aux = persample_loss_fn(p, xb, yb)
+                    losses, aux, *stats = persample_loss_fn(p, xb, yb)
                     loss = losses.mean() + aux
-                    return loss + (penalty_fn(p) if penalty_fn else 0.0)
+                    loss = loss + (penalty_fn(p) if penalty_fn else 0.0)
+                    return (loss, stats[0]) if with_stats else loss
 
-            loss, grads = jax.value_and_grad(loss_fn)(params)
+            loss, grads = jax.value_and_grad(loss_fn, has_aux=with_stats)(
+                params)
+            if with_stats:
+                loss, stats = loss
             if grad_transform is not None:
                 grads = grad_transform(grads, params)
                 # Transforms mixing in f32 state (SCAFFOLD controls, Ditto
@@ -778,6 +855,9 @@ class FedCore:
                 carry = _tree_where(
                     active, (new_params, new_opt), (params, opt_state)
                 )
+            if with_stats:
+                return carry, (jnp.where(active, loss, 0.0),
+                               jnp.where(active, stats, 0))
             return carry, jnp.where(active, loss, 0.0)
 
         orig_dtypes = jax.tree.map(lambda p: p.dtype, params0)
@@ -796,6 +876,8 @@ class FedCore:
             step, init, jnp.arange(cfg.max_local_steps),
             unroll=min(cfg.step_unroll, cfg.max_local_steps),
         )
+        if with_stats:
+            losses, stats = losses
         if cfg.carry_dtype is not None:
             params = jax.tree.map(
                 lambda p, d: p.astype(d), params, orig_dtypes
@@ -805,27 +887,50 @@ class FedCore:
             losses.sum() / jnp.maximum(steps_eff, 1).astype(jnp.float32),
             jnp.float32(jnp.nan),
         )
+        if with_stats:
+            return params, mean_loss, stats.sum(0)
         return params, mean_loss
 
+    def _sample_scores(self, logits, xb, yb):
+        """([n] losses, [n] accuracies) of a batch under ``config.task``:
+        cross-entropy and a hit against the sample's label, or — next-token
+        — the mean over a sequence's L - 1 positions of the cross-entropy
+        and of the hits against the following token (labels ignored)."""
+        if self.config.task == "next_token":
+            with jax.named_scope("lm_loss"):
+                logits, targets = logits[:, :-1], xb[:, 1:]
+                return (
+                    optax.softmax_cross_entropy_with_integer_labels(
+                        logits, targets).mean(-1),
+                    (logits.argmax(-1) == targets).mean(-1),
+                )
+        return (optax.softmax_cross_entropy_with_integer_labels(logits, yb),
+                logits.argmax(-1) == yb)
+
     def _persample(self, p, xb, yb):
-        """Shared per-sample CE + (weighted) model aux loss. In multiplicity
-        mode the aux term sees the client's full local set rather than the
-        sampled minibatch — both are unbiased regularizer estimates, and
-        which one a ``sample_mode: "auto"`` build trains with follows
-        :meth:`use_multiplicity`."""
+        """Shared per-sample loss + (weighted) model aux loss. In
+        multiplicity mode the aux term sees the client's full local set
+        rather than the sampled minibatch — both are unbiased regularizer
+        estimates, and which one a ``sample_mode: "auto"`` build trains
+        with follows :meth:`use_multiplicity`."""
         if self.apply_aux_fn is None:
             logits = self.apply_fn(p, xb)
             aux = jnp.float32(0.0)
         else:
             logits, aux = self.apply_aux_fn(p, xb)
             aux = self.config.aux_loss_weight * aux.astype(jnp.float32)
-        return (
-            optax.softmax_cross_entropy_with_integer_labels(logits, yb), aux
-        )
+        return self._sample_scores(logits, xb, yb)[0], aux
+
+    def _persample_counted(self, p, xb, yb):
+        """:meth:`_persample` through ``apply_stats_fn``: also the model's
+        work counts of this forward pass."""
+        logits, stats = self.apply_stats_fn(p, xb)
+        return (self._sample_scores(logits, xb, yb)[0], jnp.float32(0.0),
+                stats)
 
     def _local_train(self, global_params, x, y, num_samples, num_steps, uid,
                      base_key, round_idx, server_c=None, ci=None,
-                     varying=True):
+                     varying=True, with_stats=False):
         """One client's local training: masked lax.scan over SGD steps.
 
         Per-client RNG stream: fold_in(fold_in(base_key, uid), round) — stable
@@ -837,13 +942,16 @@ class FedCore:
         refreshes by option II of the paper: c_i' = c_i - c +
         (x0 - x_K)/(K * lr) = c_i - c - delta/(K * lr). Returns an extra
         ``dci = c_i' - c_i`` (zero when the client ran no steps).
+
+        ``with_stats`` (needs ``apply_stats_fn``; not with control
+        variates): returns ``(delta, mean_loss, work counts)``.
         """
         alg = self.algorithm
         key = jax.random.fold_in(jax.random.fold_in(base_key, uid), round_idx)
         # The scan length is static; clamp so a larger requested step count is
         # an explicit cap, and metrics divide by the steps actually run.
         steps_eff = jnp.minimum(num_steps, self.config.max_local_steps)
-        persample = self._persample
+        persample = self._persample_counted if with_stats else self._persample
 
         penalty = None
         if alg.prox_mu:
@@ -856,14 +964,15 @@ class FedCore:
                     lambda g, c, cc: g + c - cc, grads, server_c, ci
                 )
 
-        params, mean_loss = self._masked_sgd(
+        params, mean_loss, *stats = self._masked_sgd(
             global_params, alg.local_optimizer.init(global_params),
             x, y, num_samples, steps_eff, key, persample, penalty_fn=penalty,
             grad_transform=grad_transform, varying_init=varying,
+            with_stats=with_stats,
         )
         delta = jax.tree.map(jnp.subtract, params, global_params)
         if ci is None:
-            return delta, mean_loss
+            return (delta, mean_loss, *stats)
         k_lr = jnp.maximum(steps_eff, 1).astype(jnp.float32) * alg.local_lr
         ran = steps_eff > 0
         dci = jax.tree.map(
@@ -982,6 +1091,9 @@ class FedCore:
         robust_agg = aggregator in ("trimmed_mean", "median")
         trace_key = (with_deadline, with_attack,
                      defense.structure_key if defense is not None else None)
+        # The model's work counts ride the block scan as one more
+        # accumulator, last in the carry.
+        counted = self.apply_stats_fn is not None and not controlled
 
         def shard_body(params, opt_state, round_idx, base_key,
                        x, y, num_samples, num_steps, uid, weight, vparams,
@@ -1041,11 +1153,17 @@ class FedCore:
             if defense is not None:
                 # Extra accumulator: participants whose delta was clipped.
                 init = init + (jnp.float32(0.0),)
+            if counted:
+                stats_shape = jax.eval_shape(
+                    self.apply_stats_fn, params, x[0, :1])[1]
+                init = init + (jnp.zeros(stats_shape.shape, jnp.int32),)
             # The carry accumulates device-varying values (per-shard client
             # sums), so its initial value must be typed as varying over dp.
             init = _to_varying(init, "dp")
 
             def block_step(carry, inp):
+                if counted:
+                    *carry, sum_stats = carry
                 if defense is not None:
                     (sum_delta, sum_w, sum_loss, count, sum_ploss, sum_dc,
                      n_clip) = carry
@@ -1062,8 +1180,12 @@ class FedCore:
                         )(params, bx, by, bns, bst, buid, base_key,
                           round_idx, server_c, bvp)
                     else:
-                        deltas, losses = jax.vmap(
-                            self._local_train,
+                        deltas, losses, *bstats = (
+                            jax.vmap if self.vmap_clients
+                            else _one_client_block)(
+                            functools.partial(self._local_train,
+                                              with_stats=True)
+                            if counted else self._local_train,
                             in_axes=(None, 0, 0, 0, 0, 0, None, None),
                         )(params, bx, by, bns, bst, buid, base_key,
                           round_idx)
@@ -1163,11 +1285,17 @@ class FedCore:
                              sum_dc)
                 if defense is not None:
                     new_carry = new_carry + (n_clip,)
+                if counted:
+                    new_carry = new_carry + (sum_stats + bstats[0].sum(0),)
                 return new_carry, ys + (defense_ys,)
 
             carry, (block_losses, new_vparams, defense_out) = jax.lax.scan(
                 block_step, init, xs, unroll=min(cfg.block_unroll, nb)
             )
+            model_stats = jnp.float32(0.0)
+            if counted:
+                *carry, sum_stats = carry
+                model_stats = jax.lax.psum(sum_stats, "dp")
             if defense is not None:
                 (sum_delta, sum_w, sum_loss, count, sum_ploss, sum_dc,
                  n_clip) = carry
@@ -1369,6 +1497,7 @@ class FedCore:
                 stragglers=stragglers,
                 anomaly_score=anomaly_score,
                 clipped=n_clip,
+                model_stats=model_stats,
             )
             return (new_params, new_opt_state, round_idx + 1, metrics,
                     new_vparams, new_server_c)
@@ -1379,6 +1508,7 @@ class FedCore:
             mean_loss=rep, weight_sum=rep, clients_trained=rep, client_loss=cl,
             personal_loss=rep, stragglers=rep,
             anomaly_score=cl if defense_score else rep, clipped=rep,
+            model_stats=rep,
         )
         # completion_time is sharded like the clients; deadline replicated.
         pace_specs = (cl, rep) if with_deadline else ()
@@ -2870,12 +3000,9 @@ class FedCore:
         @jax.jit
         def evaluate(params, x, y):
             with jax.named_scope("evaluate"):
-                logits = self.apply_fn(params, x)
-                loss = optax.softmax_cross_entropy_with_integer_labels(
-                    logits, y
-                ).mean()
-                acc = (logits.argmax(-1) == y).mean()
-            return loss, acc
+                losses, hits = self._sample_scores(
+                    self.apply_fn(params, x), x, y)
+            return losses.mean(), hits.mean()
 
         return evaluate
 
@@ -3097,26 +3224,34 @@ def build_fedcore(
     def _apply_with_inter(params, x):
         return model.apply({"params": params}, x, mutable=["intermediates"])
 
-    def _sum_aux(inter):
+    def _sown(inter, name):
         flat = jax.tree_util.tree_flatten_with_path(inter)[0]
-        leaves = [leaf for path, leaf in flat
-                  if "aux_loss" in jax.tree_util.keystr(path)]
-        return leaves
+        return [leaf for path, leaf in flat
+                if name in jax.tree_util.keystr(path)]
 
-    apply_aux_fn = None
+    apply_aux_fn = apply_stats_fn = describe_stats = None
     shapes = None
     try:
         shapes = jax.eval_shape(init_params_fn, jax.random.key(0))
         dummy = jax.ShapeDtypeStruct((1,) + in_shape, spec.input_dtype)
         _, inter_shapes = jax.eval_shape(_apply_with_inter, shapes, dummy)
-        has_aux = bool(_sum_aux(inter_shapes))
+        has_aux = bool(_sown(inter_shapes, "aux_loss"))
+        has_stats = bool(_sown(inter_shapes, "moe_stats"))
     except Exception:  # noqa: BLE001 — aux detection must never block a build
-        has_aux = False
+        has_aux = has_stats = False
+    if has_stats and not has_aux:
+        # A routed expert layer's work counts (models/moe.py DroplessMoE):
+        # one int32 vector a layer, stacked; the round program sums them.
+        from olearning_sim_tpu.models.moe import describe_stats
+
+        def apply_stats_fn(params, x):
+            logits, inter = _apply_with_inter(params, x)
+            return logits, jnp.stack(_sown(inter, "moe_stats"))
     if has_aux:
 
         def apply_aux_fn(params, x):
             logits, inter = _apply_with_inter(params, x)
-            leaves = _sum_aux(inter)
+            leaves = _sown(inter, "aux_loss")
             # MEAN over blocks, matching ep_train_step's aggregation, so the
             # same aux_loss_weight applies equal balancing pressure per
             # router in both training paths regardless of model depth.
@@ -3154,4 +3289,6 @@ def build_fedcore(
 
     return FedCore(apply_fn, init_params_fn, algorithm, plan, config,
                    param_specs=param_specs, apply_aux_fn=apply_aux_fn,
-                   pp_train=pp_train)
+                   pp_train=pp_train, apply_stats_fn=apply_stats_fn,
+                   describe_stats=describe_stats,
+                   vmap_clients=spec.vmap_clients)

@@ -572,29 +572,39 @@ class SimulationRunner:
         ))
 
     def _count_work(self, span, p: DataPopulation, trace: ClientTrace,
-                    clients_trained: int) -> None:
+                    clients_trained: int, metrics) -> None:
         """Work counts of one train launch, on its ``host_transfer`` span
         (set once the device has answered): what the round program computed
         against what the round needed. The program trains every resident
         row, padding and withheld clients included, and where the core
         chose multiplicity (``FedCore.use_multiplicity``) every local
-        sample each step."""
+        sample each step. A next-token task adds the tokens of a step, and
+        a model that counts its own work (``RoundMetrics.model_stats``: a
+        routed expert layer's assignments and loads, summed on the device
+        over the round) what ``FedCore.describe_stats`` names."""
         if span is None:
             return
         cfg = self.core.config
         x = p.dataset.x
         n_local = int(x.shape[1])
+        computed = (
+            n_local
+            if self.core.use_multiplicity(n_local, x.shape[2:], x.dtype)
+            else int(cfg.batch_size))
         span.attrs.update(
             clients_resident=int(p.dataset.num_clients),
             clients_released=int(trace.num_released),
             clients_trained=clients_trained,
             local_steps=int(cfg.max_local_steps),
-            samples_computed_per_step=(
-                n_local
-                if self.core.use_multiplicity(n_local, x.shape[2:], x.dtype)
-                else int(cfg.batch_size)),
+            samples_computed_per_step=computed,
             samples_needed_per_step=min(int(cfg.batch_size), n_local),
         )
+        if cfg.task == "next_token":
+            span.attrs["tokens_per_step"] = computed * int(x.shape[2])
+        if (self.core.describe_stats is not None
+                and np.ndim(metrics.model_stats)):
+            span.attrs.update(self.core.describe_stats(
+                np.asarray(jax.device_get(metrics.model_stats))))
 
     # -------------------------------------------------------------- operators
     def _completion_times(self, p: DataPopulation, round_idx: int,
@@ -867,7 +877,7 @@ class SimulationRunner:
             # async dispatch; this interval covers real device execution.
             client_loss = np.asarray(jax.device_get(metrics.client_loss))
             clients_trained = int(metrics.clients_trained)
-            self._count_work(span, p, trace, clients_trained)
+            self._count_work(span, p, trace, clients_trained, metrics)
         self._note_first_compile(operator.name, train_span)
         ok = np.isfinite(client_loss)
         flagged = None
@@ -1136,7 +1146,7 @@ class SimulationRunner:
         with self._phase(operator.name, "host_transfer", round_idx) as span:
             client_loss = np.asarray(jax.device_get(metrics.client_loss))
             clients_trained = int(metrics.clients_trained)
-            self._count_work(span, p, trace, clients_trained)
+            self._count_work(span, p, trace, clients_trained, metrics)
         self._note_first_compile(operator.name, train_span)
         ok = np.isfinite(client_loss)
         clipped = 0

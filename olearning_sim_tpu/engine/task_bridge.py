@@ -30,6 +30,12 @@ Arbitrary user code still works through the ``custom`` operator kind
                      "every": 1, "max_to_keep": 3}
     }
 
+What a sample's loss is follows the task's ``task_type`` (target data):
+``"next_token"`` / ``"next_token_prediction"`` train and evaluate a language
+model on the tokens themselves shifted by one (``FedCoreConfig.task``; the
+synthetic generator's class labels are then topics the loss ignores); any
+other value is classification, one label a sample.
+
 The ``checkpoint`` block is what makes a task supervisable: it gives the
 runner a ``RoundCheckpointer`` rooted at a durable per-task directory
 (``{task_id}`` is substituted; relative/omitted directories land under the
@@ -40,6 +46,7 @@ committed round instead of replaying from zero.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import json
 import os
@@ -67,6 +74,9 @@ from olearning_sim_tpu.taskmgr.codecs import json2taskconfig
 from olearning_sim_tpu.taskmgr.operator_flow import OperatorFlowController
 
 BUILTIN_PREFIX = "builtin:"
+# ``task_type`` values (task JSON, target data) that ask for the next-token
+# loss (``FedCoreConfig.task``); every other value is classification.
+NEXT_TOKEN_TASK_TYPES = ("next_token", "next_token_prediction")
 
 
 def _engine_params(tc: pb.TaskConfig) -> Dict[str, Any]:
@@ -216,6 +226,14 @@ def _build_runner(tc: pb.TaskConfig, span, plan, task_repo, deviceflow,
     # the submit validator (taskmgr/validation.py) runs the same from_dict,
     # so a typo'd or wrong-typed knob fails at submit time, not mid-round.
     cfg = FedCoreConfig.from_dict(fed_cfg)
+    # The task's ``task_type`` (target data) names what a sample's loss is.
+    task_types = {td.taskType for td in tc.target.targetData}
+    if task_types & set(NEXT_TOKEN_TASK_TYPES):
+        if len(task_types) > 1:
+            raise ValueError(
+                f"task {tc.taskID.taskID}: target data mix task types "
+                f"{sorted(task_types)}; one round program has one loss")
+        cfg = dataclasses.replace(cfg, task="next_token")
     algorithm = algorithm_from_config(algo_cfg.pop("name", "fedavg"), **algo_cfg)
     input_shape = tuple(model_cfg.get("input_shape", [])) or None
     with span("bridge.build_fedcore"):
@@ -258,7 +276,11 @@ def _build_runner(tc: pb.TaskConfig, span, plan, task_repo, deviceflow,
         )
     )
     num_classes = int(syn.get("num_classes", model_classes))
-    if num_classes > model_classes:
+    if cfg.task == "next_token":
+        # The generator's classes are topics (token bands) the next-token
+        # loss never reads; the head is the vocabulary.
+        num_classes = int(syn.get("num_classes", 2))
+    elif num_classes > model_classes:
         raise ValueError(
             f"data.synthetic.num_classes={num_classes} exceeds the model's "
             f"head size {model_classes}; labels would fall outside the logits"

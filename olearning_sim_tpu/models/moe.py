@@ -1,27 +1,32 @@
-"""Mixture-of-Experts text family: Switch-style top-1 routing with
-expert-parallel weights.
+"""Mixture-of-Experts layers and the toy text family built on one of them.
 
-The reference has no MoE (models live in user operator code — SURVEY.md
-section 2.6); this family is the rebuild's expert-parallelism (``ep``)
-scaling axis, alongside ``mp`` (tensor) and ``sp`` (sequence). The design
-is TPU-first throughout:
+Two expert layers live here, and they share nothing:
 
-- routing is realized with one-hot einsums (dispatch/combine tensors), not
-  scatters — everything lowers to MXU matmuls with static shapes;
-- per-expert FFN weights carry a leading expert axis ``[E, ...]``; under
-  expert parallelism they are annotated ``PartitionSpec("ep", ...)`` and
-  GSPMD inserts the token all-to-alls around the expert computation
-  (:mod:`olearning_sim_tpu.parallel.expert_parallel`);
-- capacity is static (``capacity_factor * tokens / E``); overflow tokens
-  fall through the residual connection (standard Switch behavior), so the
-  program has no data-dependent shapes.
+- :class:`DroplessMoE` — the routed expert layer of the ``lfm2`` family
+  (``models/lfm2.py``): a sigmoid router over the model's PUBLISHED number
+  of experts, top-k selection steered by a bias that never enters the
+  weights, SwiGLU experts, and no capacity: every (token, slot) assignment
+  to an expert this layer holds is computed, whatever the imbalance. The
+  layer is TOLD which experts it holds (``held``): it routes over all of
+  them and returns the partial sum its own experts give, which is what one
+  chip of an expert-parallel deployment computes before the exchange.
+- :class:`SwitchFFN` — a top-1, softmax, GELU toy with a STATIC capacity
+  that drops overflow tokens through the residual, kept because
+  ``tests/test_expert_parallel.py`` and the ``moe_text`` family
+  (:class:`MoETextTransformer`, 256 wide) exercise the ``ep`` mesh axis
+  with it (:mod:`olearning_sim_tpu.parallel.expert_parallel`). Nothing in
+  the benchmark runs it. Its routing is one-hot einsums with static shapes;
+  its Switch load-balancing loss (num_experts * sum_e f_e * P_e) is sown
+  into ``intermediates`` as ``aux_loss`` and picked up by ``build_fedcore``
+  and ``ep_train_step``.
 
-The Switch load-balancing auxiliary loss (num_experts * sum_e f_e * P_e) is
-sown into the ``intermediates`` collection as ``aux_loss``; training code
-adds it via ``mutable=["intermediates"]`` (see ``ep_train_step``).
+Per-expert weights of both carry a leading expert axis and names that start
+with ``expert_``; ``ep_param_specs`` shards that axis over ``ep``.
 """
 
 from __future__ import annotations
+
+from typing import Tuple
 
 import flax.linen as nn
 import jax
@@ -31,9 +36,186 @@ import numpy as np
 from olearning_sim_tpu.models.registry import ModelSpec, register_model
 
 
+# What a DroplessMoE layer sows as ``moe_stats`` on every call, one int32
+# vector: these three counts, then the assignments each held expert got.
+STATS_HEAD = ("assignments_total", "assignments_local", "assignments_computed")
+BIAS_INIT_SCALE = 0.01
+
+
+@jax.custom_vjp
+def _take_rows(x, rows, back):
+    """``x[rows]``, zeros where ``rows`` is out of range; ``rows`` picks each
+    row of ``x`` at most once and ``back`` is its inverse (row i of ``x``
+    went to ``back[i]``, out of range where it went nowhere), so the
+    cotangent is the gather ``g[back]``, not the scatter-add XLA derives
+    for a gather whose indices it cannot see are distinct."""
+    return jnp.take(x, rows, axis=0, mode="fill", fill_value=0)
+
+
+def _take_rows_fwd(x, rows, back):
+    return _take_rows(x, rows, back), (rows, back)
+
+
+def _take_rows_bwd(res, g):
+    rows, back = res
+    return jnp.take(g, back, axis=0, mode="fill", fill_value=0), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.checkpoint
+def _grouped_swiglu(xs, w1, w3, w2, sizes):
+    """``W2(silu(W1 x) * W3 x)`` of rows ``xs`` grouped by expert: group g
+    is the next ``sizes[g]`` rows and uses ``w*[g]``; rows past the groups
+    are not multiplied (what comes back in them is the kernel's to leave:
+    the caller masks them). The backward pass recomputes
+    the two hidden products rather than keep them for every row."""
+    a = jax.lax.ragged_dot(xs, w1, sizes)
+    b = jax.lax.ragged_dot(xs, w3, sizes)
+    return jax.lax.ragged_dot(jax.nn.silu(a) * b, w2, sizes)
+
+
+class DroplessMoE(nn.Module):
+    """Dropless top-k routed expert layer that is told which experts it
+    holds.
+
+    ``s = sigmoid(W_g h)`` over all ``num_experts`` (the published count:
+    the router is never cut), in float32; the ``top_k`` experts of a token
+    are those of ``top_k(s + expert_bias)``; their weights are the selected
+    ``s`` (never the biased score), divided by their sum + 1e-6 when
+    ``norm_topk_prob``, times ``routed_scaling_factor``; expert
+    ``e(h) = W2(silu(W1 h) * W3 h)``. The layer returns the weighted sum
+    over the selected experts that are in ``held`` and nothing for the
+    others: with ``held`` = every id that is the whole layer, with a share
+    of them it is that share's part of the sum, and the parts of disjoint
+    shares add up to the whole (``tests/test_lfm2.py``). Nothing stands in
+    for the chips that hold the other experts.
+
+    No capacity and no drop: the (token, slot) assignments are sorted by
+    the held expert they go to — assignments to experts not held form a
+    tail group — and the three expert products run as grouped matmuls
+    (``jax.lax.ragged_dot``) over the held groups only; the tail is never
+    multiplied, read or summed. One expert may get every
+    assignment, or none.
+
+    ``expert_bias`` steers the selection only. It is a float32 parameter
+    that takes no gradient (so local training, aggregation and the server
+    step leave it as it is); the published rule that updates it from the
+    experts' load is not in the model's config and is not implemented. It
+    is seeded normal(``BIAS_INIT_SCALE``): 0.01 changes some selections of
+    a 64-wide router (the most loaded of 8 held experts gets 1.4 times the
+    mean load for 1.25 with no bias) and keeps the share of assignments
+    that land on them within 2% from seed to seed; at 0.05 that share
+    swung 8% and the round time with it (PERF.md section 6, PR 28).
+    """
+
+    num_experts: int
+    top_k: int
+    held: Tuple[int, ...]
+    mlp_dim: int
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        B, L, W = x.shape
+        E, K, H, M = self.num_experts, self.top_k, len(self.held), self.mlp_dim
+        if not 0 < K <= E or len(set(self.held)) != H or not all(
+                0 <= e < E for e in self.held):
+            raise ValueError(
+                f"DroplessMoE: top_k={K}, held={tuple(self.held)} do not fit "
+                f"a router over {E} experts")
+        S, A = B * L, B * L * K
+        xf = x.reshape(S, W)
+        gate = self.param("gate", nn.initializers.lecun_normal(), (W, E),
+                          jnp.float32)
+        bias = self.param(
+            "expert_bias", nn.initializers.normal(BIAS_INIT_SCALE),
+            (E,), jnp.float32)
+        per_expert = nn.initializers.lecun_normal(batch_axis=(0,))
+        w1 = self.param("expert_w1", per_expert, (H, W, M), jnp.float32)
+        w3 = self.param("expert_w3", per_expert, (H, W, M), jnp.float32)
+        w2 = self.param("expert_w2", per_expert, (H, M, W), jnp.float32)
+
+        with jax.named_scope("moe.route"):
+            scores = jax.nn.sigmoid(jnp.dot(
+                xf.astype(jnp.float32), gate,
+                precision=jax.lax.Precision.HIGHEST))            # [S, E]
+            _, chosen = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(bias), K)         # [S, K]
+            weights = jnp.take_along_axis(scores, chosen, axis=-1)
+            if self.norm_topk_prob:
+                weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
+            weights = weights * self.routed_scaling_factor
+
+        with jax.named_scope("moe.dispatch"):
+            # Expert id -> its group here; H is the tail (not held).
+            group_of = np.full((E,), H, np.int32)
+            group_of[list(self.held)] = np.arange(H, dtype=np.int32)
+            group = jnp.asarray(group_of)[chosen].reshape(A)
+            here = group < H
+            sizes = (group[:, None] == jnp.arange(H, dtype=jnp.int32)
+                     ).sum(0, dtype=jnp.int32)                   # [H]
+            # Sorted position r holds assignment perm[r]; assignment j sits
+            # at inv[j]. Out of range (A) marks the tail in both: a grouped
+            # matmul kernel need not write the rows it does not multiply,
+            # and what it leaves there must reach neither the sum nor,
+            # through the backward pass, the tokens' gradients.
+            perm = jnp.argsort(group, stable=True)
+            inv = jnp.zeros_like(perm).at[perm].set(
+                jnp.arange(A, dtype=perm.dtype))
+            local = sizes.sum()
+            perm = jnp.where(jnp.arange(A) < local, perm, A)
+            inv = jnp.where(inv < local, inv, A)
+            # Row i*K + j is token i in its slot j.
+            xs = _take_rows(
+                jnp.repeat(xf.astype(self.dtype), K, axis=0), perm, inv)
+
+        with jax.named_scope("moe.experts"):
+            ye = _grouped_swiglu(xs, w1.astype(self.dtype),
+                                 w3.astype(self.dtype),
+                                 w2.astype(self.dtype), sizes)   # [A, W]
+
+        with jax.named_scope("moe.combine"):
+            yk = _take_rows(ye, inv, perm).reshape(S, K, W)
+            y = (yk.astype(jnp.float32) * weights[..., None]).sum(1)
+
+        # The choices themselves, for whoever asks for the intermediates
+        # (scripts/lfm2_routing_agreement.py); training does not.
+        self.sow("intermediates", "moe_chosen", chosen)
+        # Counted twice on purpose: what the router sent to held experts,
+        # and what the grouped products were told to cover.
+        self.sow("intermediates", "moe_stats", jnp.concatenate([
+            jnp.stack([jnp.int32(A), here.sum(dtype=jnp.int32), local]),
+            sizes]))
+        return y.reshape(B, L, W).astype(x.dtype)
+
+
+def describe_stats(stats: np.ndarray) -> dict:
+    """Work counts from the ``moe_stats`` of a model's expert layers summed
+    over some stretch of work (``[layers, 3 + held]``): the assignments
+    made, routed to held experts and computed, and the largest and the mean
+    load of a held expert (one of one layer) over that stretch."""
+    stats = np.asarray(stats, np.int64).reshape(-1, stats.shape[-1])
+    head = dict(zip(STATS_HEAD, stats[:, :len(STATS_HEAD)].sum(0).tolist()))
+    loads = stats[:, len(STATS_HEAD):]
+    return {
+        "moe_assignments_total": head["assignments_total"],
+        "moe_assignments_local": head["assignments_local"],
+        "moe_assignments_computed": head["assignments_computed"],
+        "moe_expert_load_max": int(loads.max()),
+        "moe_expert_load_mean": float(loads.mean()),
+    }
+
+
 class SwitchFFN(nn.Module):
-    """Top-1 (Switch) MoE feed-forward: route each token to one of
-    ``num_experts`` expert FFNs, weighted by the gate probability."""
+    """The toy: top-1 (Switch) routing over ``num_experts`` GELU FFNs,
+    weighted by the softmax gate probability, with a static capacity of
+    ``capacity_factor * tokens / num_experts`` slots an expert. A token
+    over capacity is DROPPED (it rides the residual unchanged); for a layer
+    that drops nothing see :class:`DroplessMoE`."""
 
     num_experts: int
     width: int

@@ -28,6 +28,10 @@ class ModelSpec:
     defaults: Dict[str, Any]
     # Input element dtype (np.int32 for token models, np.float32 otherwise).
     input_dtype: Any = np.float32
+    # False where the model cannot be ``jax.vmap``ped over per-client
+    # weights (a grouped matmul batches over a leading axis only): the
+    # round engine then takes clients one at a time (``block_clients`` 1).
+    vmap_clients: bool = True
 
     def build(self, **overrides) -> nn.Module:
         kwargs = dict(self.defaults)
@@ -50,7 +54,7 @@ def get_model(name: str) -> ModelSpec:
     import importlib
     import importlib.util
 
-    for mod in ("mlp", "cnn", "resnet", "transformer", "vit", "moe"):
+    for mod in ("mlp", "cnn", "resnet", "transformer", "vit", "moe", "lfm2"):
         qual = f"olearning_sim_tpu.models.{mod}"
         # Only true absence is optional; a present-but-broken module raises.
         if importlib.util.find_spec(qual) is not None:

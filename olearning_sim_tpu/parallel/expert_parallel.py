@@ -9,7 +9,8 @@ axis, for the :class:`~olearning_sim_tpu.models.moe.MoETextTransformer`
 family.
 
 Design (pure GSPMD auto mode — no shard_map): every per-expert leaf (leading
-dim == num_experts, names ``expert_*`` from :class:`SwitchFFN`) is annotated
+expert dim, names ``expert_*`` from :class:`SwitchFFN` and
+:class:`~olearning_sim_tpu.models.moe.DroplessMoE`) is annotated
 ``PartitionSpec("ep", ...)``; the batch is sharded over ``dp``. XLA then
 places each device's expert shard locally and inserts all-to-alls moving
 token slots to their experts' devices and back — exactly the hand-written
@@ -37,15 +38,18 @@ sharded_expert_fraction = sharded_fraction
 
 
 def ep_param_specs(params: Any, ep: int) -> Any:
-    """PartitionSpec tree: per-expert leaves (``expert_*`` with a leading
-    expert dim divisible by ``ep``) shard that dim over ``ep``; everything
-    else replicated."""
+    """PartitionSpec tree: per-expert weights (``expert_*`` leaves of two or
+    more dims whose leading expert dim is divisible by ``ep``: ``SwitchFFN``'s
+    ``expert_w1/b1/w2/b2`` and ``DroplessMoE``'s ``expert_w1/w3/w2``) shard
+    that dim over ``ep``; everything else is replicated — the routers too,
+    ``DroplessMoE``'s ``gate`` and its one-dim ``expert_bias`` over the
+    router's whole width, which every chip holds whole."""
 
     def rule(path, leaf):
         names = _path_str(path)
         if names and names[-1].startswith(_EXPERT_PREFIX):
             shape = getattr(leaf, "shape", ())
-            if shape and shape[0] % ep == 0:
+            if len(shape) >= 2 and shape[0] % ep == 0:
                 return P("ep", *([None] * (len(shape) - 1)))
         return P()
 
