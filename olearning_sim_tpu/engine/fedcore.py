@@ -41,10 +41,12 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from flax import struct
+from flax.traverse_util import flatten_dict, unflatten_dict
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from olearning_sim_tpu.engine.algorithms import Algorithm
 from olearning_sim_tpu.engine.client_data import ClientDataset
+from olearning_sim_tpu.models.lookup import LOOKUP_ROWS, TABLE
 from olearning_sim_tpu.parallel.mesh import MeshPlan, global_put, pad_to_multiple
 
 
@@ -359,6 +361,54 @@ def parse_float_dtype(knob: str, value):
     return dt
 
 
+@dataclasses.dataclass(frozen=True)
+class LookupTables:
+    """The tables a model only looks up by its integer input
+    (``models/lookup.py``), as ``build_fedcore`` found them by abstract
+    evaluation. With them a local SGD step differentiates with respect to
+    the rows the lookup returned and scatter-adds their updates into the
+    carried table (``FedCore._masked_sgd``): a row the step did not read
+    gets ``p - lr * 0 = p`` either way, so the dense table gradient, its
+    zero fill and the whole-table add leave the step.
+
+    ``paths``: each table's parameter path; the perturbation that marks
+    its rows sits beside it under ``LOOKUP_ROWS``.
+    The model's apply functions take that collection as ``rows=``.
+    ``rows_total``: rows of all the tables (the runner's work counts)."""
+
+    paths: Tuple[Tuple[str, ...], ...]
+    rows_total: int
+
+    @staticmethod
+    def rows_path(table_path):
+        return table_path[:-1] + (LOOKUP_ROWS,)
+
+    def split(self, params):
+        """``(every other leaf, the tables)``, both keyed by path."""
+        rest = flatten_dict(params)
+        return rest, {path: rest.pop(path) for path in self.paths}
+
+    def zero_rows(self, tables, ids):
+        """The zero perturbation of the rows ``ids`` looks up, a block of
+        ``ids.shape + (width,)`` a table, typed like the table (its dtype;
+        inside ``shard_map``, device-varying where it is: a replicated
+        zero's gradient would be summed over the mesh)."""
+        return unflatten_dict({
+            self.rows_path(path): jax.lax.full_like(
+                t, 0, shape=ids.shape + t.shape[1:])
+            for path, t in tables.items()})
+
+    def add_rows(self, tables, ids, row_updates):
+        """The tables with ``row_updates`` (a tree like :meth:`zero_rows`')
+        scatter-added at ``ids``; a duplicate id takes every one of its
+        updates, as the dense gradient's scatter-add gives it."""
+        row_updates = flatten_dict(row_updates)
+        return {
+            path: t.at[ids].add(
+                row_updates[self.rows_path(path)].astype(t.dtype))
+            for path, t in tables.items()}
+
+
 def _accumulate_delta(sum_delta, deltas, bw_eff, gate):
     """``sum_delta + bw_eff . gate(f32(delta))`` leaf by leaf: the finiteness
     gate and float32 cast of each client delta (scope ``delta_transform``)
@@ -505,6 +555,7 @@ class FedCore:
         apply_stats_fn: Optional[Callable[[Any, jax.Array], Tuple[jax.Array, jax.Array]]] = None,
         describe_stats: Optional[Callable[[np.ndarray], dict]] = None,
         vmap_clients: bool = True,
+        lookup_tables: Optional[LookupTables] = None,
     ):
         """``param_specs`` — optional PartitionSpec pytree (same treedef as
         the params) sharding model tensors over the mesh ``mp`` axis
@@ -531,6 +582,12 @@ class FedCore:
         time, which the resident dp-manual program does at
         ``block_clients`` 1 by squeezing the block axis.
 
+        ``lookup_tables`` — the model's lookup-only tables
+        (:class:`LookupTables`), where it marks any: ``_masked_sgd`` then
+        trains them by the rows a step reads wherever that is the dense
+        step's result (see there). Dropped on an ``mp`` > 1 plan, whose
+        program leaves every leaf to the auto partitioner.
+
         ``pp_train`` — ``(model, microbatches)`` for a pipeline-parallel
         mesh plan (``plan.pp > 1``): the per-client train body is then the
         stage-pipelined program of :mod:`olearning_sim_tpu.engine.
@@ -549,6 +606,10 @@ class FedCore:
         self._pp_train = pp_train
         # use_multiplicity's answers, by (n_local, row shape).
         self._multiplicity: dict = {}
+        self.lookup_tables = lookup_tables if plan.mp == 1 else None
+        # Whether every _masked_sgd traced so far trains the lookup tables
+        # by rows (None: none traced yet): the runner's work counts.
+        self.row_updates: Optional[bool] = None
         if plan.pp > 1 and pp_train is None:
             raise ValueError(
                 "plan has pp > 1 but no pp_train=(model, microbatches) was "
@@ -801,34 +862,56 @@ class FedCore:
         # double-buffered tree_where over params AND state.
         stateless_opt = not jax.tree.leaves(opt_state0)
 
+        # A model's lookup-only tables are trained by the rows a step reads
+        # wherever that is what the dense step computes: a stateless
+        # elementwise optimizer (a row's update is a function of its
+        # gradient alone), nothing dense added to the loss or the gradients
+        # (FedProx's pull, SCAFFOLD's and Ditto's corrections move rows the
+        # step did not read), and FedCore's own loss, which hands the rows'
+        # perturbation to the model.
+        lookup = self.lookup_tables
+        by_rows = (
+            lookup is not None and stateless_opt and penalty_fn is None
+            and grad_transform is None
+            and persample_loss_fn in (self._persample,
+                                      self._persample_counted)
+        )
+        self.row_updates = by_rows and self.row_updates is not False
+
         def step(carry, i):
             params, opt_state = carry
             k = jax.random.fold_in(key, i)
             idx = jax.random.randint(k, (cfg.batch_size,), 0, n)
-
             if use_mult:
                 sw = (
                     jnp.zeros((n_local,), jnp.float32).at[idx].add(1.0)
                     / cfg.batch_size
                 )
 
-                def loss_fn(p):
-                    losses, aux, *stats = persample_loss_fn(p, x, y)
+            def loss_fn(p, **rows):
+                if use_mult:
+                    losses, aux, *stats = persample_loss_fn(p, x, y, **rows)
                     loss = (sw * losses).sum() + aux
-                    loss = loss + (penalty_fn(p) if penalty_fn else 0.0)
-                    return (loss, stats[0]) if with_stats else loss
-            else:
-
-                def loss_fn(p):
+                else:
                     xb = jnp.take(x, idx, axis=0)
                     yb = jnp.take(y, idx, axis=0)
-                    losses, aux, *stats = persample_loss_fn(p, xb, yb)
+                    losses, aux, *stats = persample_loss_fn(p, xb, yb, **rows)
                     loss = losses.mean() + aux
-                    loss = loss + (penalty_fn(p) if penalty_fn else 0.0)
-                    return (loss, stats[0]) if with_stats else loss
+                loss = loss + (penalty_fn(p) if penalty_fn else 0.0)
+                return (loss, stats[0]) if with_stats else loss
 
-            loss, grads = jax.value_and_grad(loss_fn, has_aux=with_stats)(
-                params)
+            if by_rows:
+                # The ids are whichever rows the model is run on.
+                ids = x if use_mult else jnp.take(x, idx, axis=0)
+                rest, tables = lookup.split(params)
+                loss, grads = jax.value_and_grad(
+                    lambda r, rows: loss_fn(
+                        unflatten_dict({**r, **tables}), rows=rows),
+                    argnums=(0, 1), has_aux=with_stats,
+                )(rest, lookup.zero_rows(tables, ids))
+            else:
+                loss, grads = jax.value_and_grad(
+                    loss_fn, has_aux=with_stats)(params)
             if with_stats:
                 loss, stats = loss
             if grad_transform is not None:
@@ -839,7 +922,8 @@ class FedCore:
                 grads = jax.tree.map(
                     lambda g, p: g.astype(p.dtype), grads, params
                 )
-            updates, new_opt = alg.local_optimizer.update(grads, opt_state, params)
+            updates, new_opt = alg.local_optimizer.update(
+                grads, opt_state, None if by_rows else params)
             active = i < steps_eff
             if stateless_opt:
                 # where, not multiply-by-gate: 0 * non-finite = NaN would let
@@ -849,9 +933,16 @@ class FedCore:
                 updates = jax.tree.map(
                     lambda u: jnp.where(active, u, jnp.zeros_like(u)), updates
                 )
-                carry = (optax.apply_updates(params, updates), opt_state)
+            if by_rows:
+                new_params = unflatten_dict({
+                    **optax.apply_updates(rest, updates[0]),
+                    **lookup.add_rows(tables, ids, updates[1]),
+                })
             else:
                 new_params = optax.apply_updates(params, updates)
+            if stateless_opt:
+                carry = (new_params, opt_state)
+            else:
                 carry = _tree_where(
                     active, (new_params, new_opt), (params, opt_state)
                 )
@@ -907,24 +998,25 @@ class FedCore:
         return (optax.softmax_cross_entropy_with_integer_labels(logits, yb),
                 logits.argmax(-1) == yb)
 
-    def _persample(self, p, xb, yb):
+    def _persample(self, p, xb, yb, **rows):
         """Shared per-sample loss + (weighted) model aux loss. In
         multiplicity mode the aux term sees the client's full local set
         rather than the sampled minibatch — both are unbiased regularizer
         estimates, and which one a ``sample_mode: "auto"`` build trains
-        with follows :meth:`use_multiplicity`."""
+        with follows :meth:`use_multiplicity`. ``rows=`` (the by-rows local
+        step alone): the perturbation of the model's looked-up rows."""
         if self.apply_aux_fn is None:
-            logits = self.apply_fn(p, xb)
+            logits = self.apply_fn(p, xb, **rows)
             aux = jnp.float32(0.0)
         else:
-            logits, aux = self.apply_aux_fn(p, xb)
+            logits, aux = self.apply_aux_fn(p, xb, **rows)
             aux = self.config.aux_loss_weight * aux.astype(jnp.float32)
         return self._sample_scores(logits, xb, yb)[0], aux
 
-    def _persample_counted(self, p, xb, yb):
+    def _persample_counted(self, p, xb, yb, **rows):
         """:meth:`_persample` through ``apply_stats_fn``: also the model's
         work counts of this forward pass."""
-        logits, stats = self.apply_stats_fn(p, xb)
+        logits, stats = self.apply_stats_fn(p, xb, **rows)
         return (self._sample_scores(logits, xb, yb)[0], jnp.float32(0.0),
                 stats)
 
@@ -3184,6 +3276,27 @@ class FedCore:
         return sum(losses) / seen, sum(accs) / seen
 
 
+def _marked_lookup_tables(param_shapes, perturbation_shapes,
+                          x) -> Optional[LookupTables]:
+    """The lookup-only tables a model marks (``models/lookup.py``), read off
+    its abstract evaluation on the input ``x``: every ``LOOKUP_ROWS``
+    perturbation whose shape says it is the rows ``x`` itself looks up in
+    the ``[rows, width]`` table beside it. ``None`` where the model marks
+    nothing, or marks something else than a lookup by its integer input."""
+    params = flatten_dict(param_shapes)
+    tables = {path[:-1] + (TABLE,): rows
+              for path, rows in flatten_dict(perturbation_shapes).items()
+              if path[-1] == LOOKUP_ROWS}
+    by_input = jnp.issubdtype(x.dtype, jnp.integer) and all(
+        path in params and len(params[path].shape) == 2
+        and rows.shape == x.shape + params[path].shape[1:]
+        for path, rows in tables.items())
+    if not tables or not by_input:
+        return None
+    return LookupTables(
+        tuple(tables), sum(int(params[path].shape[0]) for path in tables))
+
+
 def build_fedcore(
     model_name: str,
     algorithm: Algorithm,
@@ -3209,8 +3322,16 @@ def build_fedcore(
             "{'parallel': {'pp': N}} block)"
         )
 
-    def apply_fn(params, x):
-        return model.apply({"params": params}, x)
+    def _variables(params, rows):
+        # ``rows``: the perturbation of a lookup-only table's looked-up rows
+        # (models/lookup.py), passed by the by-rows local step alone;
+        # without the collection the model's mark is a no-op.
+        if rows is None:
+            return {"params": params}
+        return {"params": params, "perturbations": rows}
+
+    def apply_fn(params, x, rows=None):
+        return model.apply(_variables(params, rows), x)
 
     def init_params_fn(rng):
         dummy = jnp.zeros((1,) + in_shape, spec.input_dtype)
@@ -3221,22 +3342,29 @@ def build_fedcore(
     # silently drops the sow and the router trains with no balancing
     # pressure. Detect the sow by abstract evaluation and thread it into the
     # per-client loss as config.aux_loss_weight * sum(aux).
-    def _apply_with_inter(params, x):
-        return model.apply({"params": params}, x, mutable=["intermediates"])
+    def _apply_with_inter(params, x, rows=None):
+        return model.apply(_variables(params, rows), x,
+                           mutable=["intermediates"])
 
     def _sown(inter, name):
         flat = jax.tree_util.tree_flatten_with_path(inter)[0]
         return [leaf for path, leaf in flat
                 if name in jax.tree_util.keystr(path)]
 
-    apply_aux_fn = apply_stats_fn = describe_stats = None
+    apply_aux_fn = apply_stats_fn = describe_stats = lookup_tables = None
     shapes = None
     try:
         shapes = jax.eval_shape(init_params_fn, jax.random.key(0))
         dummy = jax.ShapeDtypeStruct((1,) + in_shape, spec.input_dtype)
-        _, inter_shapes = jax.eval_shape(_apply_with_inter, shapes, dummy)
+        _, sown_shapes = jax.eval_shape(
+            lambda p, x: model.apply(
+                {"params": p}, x, mutable=["intermediates", "perturbations"]),
+            shapes, dummy)
+        inter_shapes = sown_shapes.get("intermediates", {})
         has_aux = bool(_sown(inter_shapes, "aux_loss"))
         has_stats = bool(_sown(inter_shapes, "moe_stats"))
+        lookup_tables = _marked_lookup_tables(
+            shapes, sown_shapes.get("perturbations", {}), dummy)
     except Exception:  # noqa: BLE001 — aux detection must never block a build
         has_aux = has_stats = False
     if has_stats and not has_aux:
@@ -3244,13 +3372,13 @@ def build_fedcore(
         # one int32 vector a layer, stacked; the round program sums them.
         from olearning_sim_tpu.models.moe import describe_stats
 
-        def apply_stats_fn(params, x):
-            logits, inter = _apply_with_inter(params, x)
+        def apply_stats_fn(params, x, rows=None):
+            logits, inter = _apply_with_inter(params, x, rows)
             return logits, jnp.stack(_sown(inter, "moe_stats"))
     if has_aux:
 
-        def apply_aux_fn(params, x):
-            logits, inter = _apply_with_inter(params, x)
+        def apply_aux_fn(params, x, rows=None):
+            logits, inter = _apply_with_inter(params, x, rows)
             leaves = _sown(inter, "aux_loss")
             # MEAN over blocks, matching ep_train_step's aggregation, so the
             # same aux_loss_weight applies equal balancing pressure per
@@ -3291,4 +3419,5 @@ def build_fedcore(
                    param_specs=param_specs, apply_aux_fn=apply_aux_fn,
                    pp_train=pp_train, apply_stats_fn=apply_stats_fn,
                    describe_stats=describe_stats,
-                   vmap_clients=spec.vmap_clients)
+                   vmap_clients=spec.vmap_clients,
+                   lookup_tables=lookup_tables)

@@ -578,8 +578,12 @@ class SimulationRunner:
         against what the round needed. The program trains every resident
         row, padding and withheld clients included, and where the core
         chose multiplicity (``FedCore.use_multiplicity``) every local
-        sample each step. A next-token task adds the tokens of a step, and
-        a model that counts its own work (``RoundMetrics.model_stats``: a
+        sample each step. A next-token task adds the tokens of a step, a
+        model that marks lookup-only tables (``FedCore.lookup_tables``) the
+        tables' rows beside the rows a client's step writes (the ids of the
+        rows the model is run on where the core trains them by rows,
+        ``FedCore.row_updates``; every row where it falls back to the dense
+        update), and a model that counts its own work (``RoundMetrics.model_stats``: a
         routed expert layer's assignments and loads, summed on the device
         over the round) what ``FedCore.describe_stats`` names."""
         if span is None:
@@ -601,6 +605,13 @@ class SimulationRunner:
         )
         if cfg.task == "next_token":
             span.attrs["tokens_per_step"] = computed * int(x.shape[2])
+        tables = self.core.lookup_tables
+        if tables is not None:
+            span.attrs.update(
+                table_rows_total=tables.rows_total,
+                table_rows_written_per_step=(
+                    computed * math.prod(x.shape[2:]) * len(tables.paths)
+                    if self.core.row_updates else tables.rows_total))
         if (self.core.describe_stats is not None
                 and np.ndim(metrics.model_stats)):
             span.attrs.update(self.core.describe_stats(

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from olearning_sim_tpu.models.lookup import LookupOnlyEmbed
 from olearning_sim_tpu.models.registry import ModelSpec, register_model
 
 
@@ -107,13 +108,17 @@ class TextTransformer(nn.Module):
         # the mean-pool reduces over the global sequence via psum.
         # NOTE: parallel/pipeline.py mirrors this method's prologue/epilogue
         # by param name — change both together (the pipeline dense-parity
-        # test fails if they drift).
+        # test fails if they drift). The lookup is MARKED as the table's
+        # only reader (models/lookup.py; still nn.Embed's parameter,
+        # Embed_0/embedding), so FedCore's local step trains the rows the
+        # step looked up instead of a dense table gradient. The mark is a
+        # no-op outside that step: the pipeline's mirror needs no change.
         ring = self.attention_impl == "ring"
         pad_mask = tokens != self.pad_id
-        emb = nn.Embed(
+        emb = LookupOnlyEmbed(
             self.vocab_size, self.width,
             embedding_init=nn.initializers.normal(stddev=0.02),
-            param_dtype=jnp.float32,
+            param_dtype=jnp.float32, name="Embed_0",
         )(tokens)
         pos = self.param(
             "pos_embedding",

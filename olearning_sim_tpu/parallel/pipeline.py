@@ -286,7 +286,9 @@ class _PipelineGraph:
     pos_embedding / LayerNorm_0 / Dense_0) — restructuring the dense model
     into setup()-style methods would rename every param and break existing
     checkpoints, so the mirror is kept and
-    ``test_pp_forward_matches_dense`` enforces it stays in sync."""
+    ``test_pp_forward_matches_dense`` enforces it stays in sync. The dense
+    model marks its lookup (``models/lookup.py``); the mark is a no-op
+    outside ``FedCore``'s local step, so plain ``nn.Embed`` mirrors it."""
 
     def __init__(self, model, mesh, M: int):
         self.model = model
