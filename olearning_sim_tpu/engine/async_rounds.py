@@ -61,7 +61,22 @@ import functools
 import math
 from typing import Any, Dict, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from flax import struct
+from jax.sharding import PartitionSpec as P
+
+from olearning_sim_tpu.engine import defense as defense_mod
+from olearning_sim_tpu.engine.round_stages import (
+    RoundMetrics,
+    _flat_pad_leaf,
+    _to_varying,
+    _tree_where,
+    client_block,
+    next_state,
+    server_commit,
+)
 
 SCHEDULES = ("constant", "polynomial", "score")
 
@@ -339,6 +354,22 @@ def async_variant_key(num_windows: int, schedule: str, with_attack: bool,
             defense.structure_key if defense is not None else None)
 
 
+class AsyncStats(struct.PyTreeNode):
+    """Per-round async accounting exiting the compiled program.
+
+    ``commits`` — windows that actually committed (non-empty, not
+    staleness-dropped); ``committed_weight`` — total aggregation
+    weight across committed windows; ``dropped_stale`` — participants
+    whose window exceeded ``max_staleness`` (compute spent, update
+    discarded — the async analogue of stragglers); ``buffer_fill`` —
+    [W] per-window aggregation weight (the buffer-depth signal)."""
+
+    commits: Any
+    committed_weight: Any
+    dropped_stale: Any
+    buffer_fill: Any
+
+
 # --------------------------------------------------------------- program
 def build_async_round_step(core, num_windows: int, schedule: str,
                            with_attack: bool = False, defense=None):
@@ -350,22 +381,6 @@ def build_async_round_step(core, num_windows: int, schedule: str,
     a replicated zero scalar except under the ``score`` schedule, where
     it is the per-client [C] Apodotiko score array.
     """
-    import jax
-    import jax.numpy as jnp
-    import optax
-    from jax.sharding import PartitionSpec as P
-
-    from olearning_sim_tpu.engine.fedcore import (
-        RoundMetrics,
-        ServerState,
-        _attack_deltas,
-        _clip_client_deltas,
-        _finite_client_mask,
-        _flat_pad_leaf,
-        _to_varying,
-        _tree_where,
-    )
-
     plan = core.plan
     cfg = core.config
     alg = core.algorithm
@@ -476,39 +491,23 @@ def build_async_round_step(core, num_windows: int, schedule: str,
         def block_step(carry, inp):
             buf, buf_w, sum_loss, sum_w, count, n_clip = _unpack(carry)
             bx, by, bns, bst, buid, bw, bwin, bscore, batk = inp
-            with jax.named_scope("client_train"):
-                deltas, losses = jax.vmap(
-                    core._local_train,
-                    in_axes=(None, 0, 0, 0, 0, 0, None, None),
-                )(params, bx, by, bns, bst, buid, base_key, round_idx)
-            with jax.named_scope("delta_transform"):
-                if with_attack:
-                    deltas = _attack_deltas(deltas, batk)
-                # Finiteness gate — the same shared helper as the
-                # synchronous engine: a diverged client contributes nothing.
-                ok = _finite_client_mask(losses, deltas)
-
-            def gate(d):
-                return jnp.where(
-                    ok.reshape((-1,) + (1,) * (d.ndim - 1)), d, 0.0
-                )
-
-            bw_eff = jnp.where(ok, bw, 0.0)
-            with jax.named_scope("delta_transform"):
-                d32 = jax.tree.map(
-                    lambda d: gate(d.astype(jnp.float32)), deltas
-                )
-                defense_ys = None
-                if defense is not None:
-                    # Per-client L2 clip, the synchronous formulation (shared).
-                    d32, too_big = _clip_client_deltas(d32, clip_norm)
-                    n_clip = n_clip + jnp.logical_and(
-                        bw_eff > 0, too_big
-                    ).sum().astype(jnp.float32)
-                if with_score:
-                    # Apodotiko contribution scores reweight clients inside
-                    # their buffer (the polynomial staleness discount applies
-                    # per window at commit time).
+            # The buffered stage always gates in float32: the window buffer
+            # takes every client's delta, not a leaf-by-leaf weighted sum.
+            blk = client_block(
+                core._local_train, (None, 0, 0, 0, 0, 0, None, None),
+                (params, bx, by, bns, bst, buid, base_key, round_idx), bw,
+                vmap_clients=core.vmap_clients, attack_scale=batk,
+                clip_norm=clip_norm, f32=True,
+            )
+            d32, bw_eff = blk.d32, blk.bw_eff
+            defense_ys = None
+            if defense is not None:
+                n_clip = n_clip + blk.clipped
+            if with_score:
+                # Apodotiko contribution scores reweight clients inside
+                # their buffer (the polynomial staleness discount applies
+                # per window at commit time).
+                with jax.named_scope("delta_transform"):
                     d32 = jax.tree.map(
                         lambda d: d * bscore.reshape(
                             (-1,) + (1,) * (d.ndim - 1)
@@ -532,11 +531,10 @@ def build_async_round_step(core, num_windows: int, schedule: str,
                 buf_w = buf_w + jax.ops.segment_sum(
                     bw_eff, bwin, num_segments=W
                 )
-                sum_loss = sum_loss + jnp.where(ok, bw * losses, 0.0).sum()
-                sum_w = sum_w + bw_eff.sum()
-                count = count + (bw_eff > 0).sum().astype(jnp.float32)
+                sum_w, sum_loss, count = blk.tally(sum_w, sum_loss, count,
+                                                   loss_first=True)
             return (_pack(buf, buf_w, sum_loss, sum_w, count, n_clip),
-                    (losses, defense_ys))
+                    (blk.losses, defense_ys))
 
         carry, (block_losses, defense_out) = jax.lax.scan(
             block_step, init, xs, unroll=min(cfg.block_unroll, nb)
@@ -563,8 +561,6 @@ def build_async_round_step(core, num_windows: int, schedule: str,
             # aggregation weight here.
             delta_stack = delta_shard_stack = None
             if defense_gather:
-                from olearning_sim_tpu.engine import defense as defense_mod
-
                 d_pc, w_pc = defense_out
                 w_flat = w_pc.reshape((c_local,))
                 w_all = jax.lax.all_gather(w_flat, "dp", tiled=True)
@@ -671,17 +667,12 @@ def build_async_round_step(core, num_windows: int, schedule: str,
                 p, op = carry
                 d_w, w_w, sw = inp
                 gate = (w_w > 0) & (sw > 0)
-                pseudo = jax.tree.map(
-                    lambda d, q: (-(sw * d)).astype(q.dtype), d_w, p
-                )
-                updates, new_op = alg.server_optimizer.update(pseudo, op, p)
-                new_p = optax.apply_updates(p, updates)
-                p, op = _tree_where(gate, (new_p, new_op), (p, op))
+                committed = server_commit(alg.server_optimizer, p, op, d_w,
+                                          scale=sw)
+                p, op = _tree_where(gate, committed, (p, op))
                 return (p, op), gate.astype(jnp.float32)
 
             if shard_update:
-                from olearning_sim_tpu.engine import defense as defense_mod
-
                 def my_shard(p):
                     flat = _flat_pad_leaf(p, dpn)
                     s = flat.shape[0] // dpn
@@ -760,44 +751,11 @@ def build_async_round_step(core, num_windows: int, schedule: str,
     @functools.partial(jax.jit, donate_argnums=(0,))
     def async_round_step(state, x, y, num_samples, num_steps, uid, weight,
                          window, score, stale_alpha, max_stale, *extras):
-        new_params, new_opt_state, new_round, metrics, stats = shard_fn(
+        *new, metrics, stats = shard_fn(
             state.params, state.opt_state, state.round_idx, state.base_key,
             x, y, num_samples, num_steps, uid, weight,
             window, score, stale_alpha, max_stale, *extras,
         )
-        return (
-            ServerState(
-                params=new_params,
-                opt_state=new_opt_state,
-                round_idx=new_round,
-                base_key=state.base_key,
-            ),
-            metrics,
-            stats,
-        )
+        return next_state(state, *new), metrics, stats
 
     return async_round_step
-
-
-def _make_stats_cls():
-    from flax import struct
-
-    class AsyncStats(struct.PyTreeNode):
-        """Per-round async accounting exiting the compiled program.
-
-        ``commits`` — windows that actually committed (non-empty, not
-        staleness-dropped); ``committed_weight`` — total aggregation
-        weight across committed windows; ``dropped_stale`` — participants
-        whose window exceeded ``max_staleness`` (compute spent, update
-        discarded — the async analogue of stragglers); ``buffer_fill`` —
-        [W] per-window aggregation weight (the buffer-depth signal)."""
-
-        commits: Any
-        committed_weight: Any
-        dropped_stale: Any
-        buffer_fill: Any
-
-    return AsyncStats
-
-
-AsyncStats = _make_stats_cls()

@@ -40,58 +40,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from flax import struct
 from flax.traverse_util import flatten_dict, unflatten_dict
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from olearning_sim_tpu.engine import async_rounds, defense as defense_mod, pp_rounds
 from olearning_sim_tpu.engine.algorithms import Algorithm
 from olearning_sim_tpu.engine.client_data import ClientDataset
+from olearning_sim_tpu.engine.round_stages import (  # noqa: F401
+    ControlState,
+    PersonalState,
+    RoundMetrics,
+    ServerState,
+    _flat_pad_leaf,
+    _to_varying,
+    _tree_l2_sq,
+    _tree_where,
+    client_block,
+    jit_round_step,
+    next_state,
+    server_commit,
+)
 from olearning_sim_tpu.models.lookup import LOOKUP_ROWS, TABLE
 from olearning_sim_tpu.parallel.mesh import MeshPlan, global_put, pad_to_multiple
-
-
-class ServerState(struct.PyTreeNode):
-    """Global FL state carried across rounds (the checkpointable unit —
-    reference analogue: ``{task_id}_{round}_result_model.mnn`` round-scoped
-    model files, ``utils_run_task.py:327-397``)."""
-
-    params: Any
-    opt_state: Any
-    round_idx: jnp.ndarray  # int32 scalar
-    base_key: jax.Array     # PRNG key; per-client streams fold in (uid, round)
-
-
-class RoundMetrics(struct.PyTreeNode):
-    """Per-round aggregates (reference analogue: ``analyze_results`` success /
-    failure accounting persisted to MySQL, ``run_task.py:149-210``)."""
-
-    mean_loss: jnp.ndarray      # weight-averaged local training loss
-    weight_sum: jnp.ndarray     # total aggregation weight (participants)
-    clients_trained: jnp.ndarray  # number of clients with weight > 0
-    # Per-client mean local loss [C] (sharded over dp). Finiteness doubles as
-    # the success signal replacing subprocess exit codes
-    # (``utils_run_task.py:490-494``).
-    client_loss: jnp.ndarray
-    # Weight-averaged Ditto personal-branch loss (0 when not personalized).
-    personal_loss: jnp.ndarray = struct.field(default_factory=lambda: jnp.float32(0.0))
-    # Participating clients whose simulated completion_time exceeded the
-    # round deadline (deadline-masked aggregation; always 0 on the
-    # deadline-off path). Distinct from drops: a straggler's update exists
-    # but arrived too late to aggregate.
-    stragglers: jnp.ndarray = struct.field(default_factory=lambda: jnp.float32(0.0))
-    # Adversarial-client defense (engine/defense.py). ``anomaly_score``:
-    # per-client [C] Krum-style distance-to-median scores (sharded over dp)
-    # when scoring is enabled, scalar 0 otherwise — the runner's
-    # quarantine feedback signal. ``clipped``: participants whose delta
-    # L2 norm was clipped this round (0 on the defense-off path).
-    anomaly_score: jnp.ndarray = struct.field(default_factory=lambda: jnp.float32(0.0))
-    clipped: jnp.ndarray = struct.field(default_factory=lambda: jnp.float32(0.0))
-    # Work counts the client model sows while it trains (``apply_stats_fn``:
-    # a routed expert layer's assignments and loads), int32, summed over
-    # the round's active local steps of every computed client; scalar 0 for
-    # a model that sows none and on every program but the resident
-    # dp-manual one. ``FedCore.describe_stats`` names what is in it.
-    model_stats: jnp.ndarray = struct.field(default_factory=lambda: jnp.float32(0.0))
 
 
 @dataclasses.dataclass
@@ -115,25 +85,6 @@ class StreamStats:
     # streamed round's O(block) HBM claim, stated as a number.
     peak_hbm_bytes_est: int
     state_bytes: int             # host-resident per-client state bytes
-
-
-class PersonalState(struct.PyTreeNode):
-    """Ditto per-client personalized parameters: every leaf has a leading
-    client axis [C, ...] sharded over ``dp`` — the rebuild's answer to the
-    'per-client optimizer state at 10k clients' memory plan (SURVEY.md
-    section 7 hard parts): state lives sharded across devices and is updated
-    in place (donated) each round."""
-
-    params: Any
-
-
-class ControlState(struct.PyTreeNode):
-    """SCAFFOLD control variates (Karimireddy et al. 2020): per-client
-    ``client_controls`` c_i [C, ...] sharded over ``dp`` (same memory plan
-    as Ditto's personal params) and the replicated server control c."""
-
-    client_controls: Any
-    server_control: Any
 
 
 # ``sample_mode: "auto"``: wasted training FLOPs per gathered byte above
@@ -409,58 +360,6 @@ class LookupTables:
             for path, t in tables.items()}
 
 
-def _accumulate_delta(sum_delta, deltas, bw_eff, gate):
-    """``sum_delta + bw_eff . gate(f32(delta))`` leaf by leaf: the finiteness
-    gate and float32 cast of each client delta (scope ``delta_transform``)
-    and its weighted sum into the round's accumulator (scope
-    ``aggregate``)."""
-    def one(s, d):
-        with jax.named_scope("delta_transform"):
-            d = gate(d.astype(jnp.float32))
-        with jax.named_scope("aggregate"):
-            return s + jnp.tensordot(bw_eff, d, axes=(0, 0))
-
-    return jax.tree.map(one, sum_delta, deltas)
-
-
-def _one_client_block(fn, in_axes):
-    """``jax.vmap(fn, in_axes)`` for a block of exactly one client, without
-    the batching: the block axis is squeezed off the mapped arguments and
-    put back on the results."""
-
-    def one(*args):
-        args = [a if axis is None else jax.tree.map(lambda t: t[0], a)
-                for a, axis in zip(args, in_axes)]
-        return jax.tree.map(lambda t: t[None], fn(*args))
-
-    return one
-
-
-def _to_varying(tree, axis: str):
-    """Type a replicated value as device-varying over ``axis`` (shard_map VMA).
-
-    Needed for scan carries that start replicated (e.g. global params) but
-    accumulate shard-local data inside ``shard_map``.
-    """
-    return jax.lax.pcast(tree, (axis,), to="varying")
-
-
-def _tree_where(pred, a, b):
-    return jax.tree.map(lambda x, y: jnp.where(pred, x, y), a, b)
-
-
-def _flat_pad_leaf(p, multiple: int):
-    """Flatten a leaf and zero-pad to a multiple of ``multiple`` — the
-    coordinate layout shared by the sharded server update and the sharded
-    robust aggregation (defense.shard_client_deltas pads identically, so a
-    robust aggregate shard can feed the sharded optimizer directly)."""
-    flat = p.reshape(-1)
-    target = pad_to_multiple(flat.shape[0], multiple)
-    if target != flat.shape[0]:
-        flat = jnp.pad(flat, (0, target - flat.shape[0]))
-    return flat
-
-
 def _reshard(tree, shardings):
     """Re-lay a placed pytree under new shardings via a jitted identity —
     unlike ``jax.device_put`` this also works on multi-host meshes where the
@@ -477,65 +376,107 @@ def _dp_shardable(leaf, dp: int) -> bool:
     return len(shape) >= 1 and shape[0] > 0 and shape[0] % dp == 0
 
 
-def _tree_l2_sq(a, b):
-    leaves = jax.tree.map(lambda x, y: jnp.sum(jnp.square(x - y)), a, b)
-    return jax.tree.reduce(jnp.add, leaves, jnp.float32(0.0))
+def _call_in_roomy_frame(fn, *args):
+    """``fn(*args)`` from a Python frame of 256 KB. CPython 3.12 keeps a
+    thread's frames in 16 KB chunks and maps a fresh chunk, and unmaps it
+    again, every time a call crosses the end of the current one. Tracing a
+    round program calls across such an end tens of thousands of times, and
+    how often depends on the bytes of frames above the trace: on the text
+    of every caller, the builders' and the runner's included, and on
+    nothing the trace does (``PERF.md`` section 7 item 8: the same 13.6 M
+    lines traced took 41,464 page faults at PR 30's parent, 56,743 with one
+    more frame in the builder, 3,240 under this frame). A frame this large
+    gets a chunk of its own, with room below it for the whole trace."""
+    return fn(*args)
 
 
-def _attack_deltas(deltas, batk):
-    """Byzantine update attack: the client "trains honestly" but ships a
-    transformed delta (sign_flip = -1, scale = factor). A benign scale of
-    exactly 1.0 is a bitwise no-op, so an all-ones attack vector
-    reproduces the attack-free program's outputs. Shared by the
-    synchronous and buffered-async program builders — a change here
-    changes BOTH compiled variants."""
-    return jax.tree.map(
-        lambda d: d * batk.astype(d.dtype).reshape(
-            (-1,) + (1,) * (d.ndim - 1)
-        ),
-        deltas,
-    )
+_call_in_roomy_frame.__code__ = _call_in_roomy_frame.__code__.replace(
+    co_stacksize=1 << 15)
 
 
-def _finite_client_mask(losses, deltas):
-    """[block] bool — clients whose local training stayed finite (finite
-    loss AND every delta leaf finite). The resilience gate both program
-    builders apply: a diverged client contributes NOTHING to the
-    aggregate — without it, one NaN client poisons the global params even
-    at weight 0 (the weighted reduction turns 0 * NaN into NaN). For
-    all-finite clients the downstream selects keep untouched values, so
-    healthy rounds are bitwise unchanged."""
-    ok = jnp.isfinite(losses)
-    for d in jax.tree.leaves(deltas):
-        ok = jnp.logical_and(
-            ok, jnp.isfinite(d.reshape(d.shape[0], -1)).all(axis=1)
+class _MeshBoundary:
+    """How the clients of a resident round program lie over the mesh: the
+    hooks the one body of :meth:`FedCore._build_round_step` calls where its
+    two programs differ.
+
+    ``manual`` (``mp`` = 1): a ``shard_map`` over ``dp`` whose body sees one
+    device's clients and psums what leaves it. Otherwise (``mp`` > 1)
+    GSPMD-auto — one ``jax.jit`` and no ``shard_map``: clients are an
+    ordinary dp-sharded array axis, model tensors carry the tensor-parallel
+    layout of ``param_specs`` through sharding constraints (params, grads,
+    per-client deltas and the delta accumulators all pin to the SAME mp
+    shards, so nothing is re-laid between train and aggregate), and GSPMD
+    inserts every collective: the Megatron all-gathers/reduce-scatters
+    inside the per-client forward/backward AND the cross-replica delta
+    reductions. Models without specs (all-``P()`` trees) are replicated
+    over ``mp`` — correct but redundant; the transformer families shard
+    (parallel/tp.py)."""
+
+    def __init__(self, core: "FedCore", manual: bool):
+        self.core = core
+        self.manual = manual
+        # One "block" is block_clients PER dp shard on either boundary —
+        # the same per-device peak-memory bound: the auto body sees every
+        # client, so its block is dp times as wide.
+        self.block_width = core.config.block_clients * (
+            1 if manual else core.plan.dp)
+
+    def wrap(self, body, in_specs, out_specs):
+        """``body`` over the mesh: specs are the manual program's."""
+        if not self.manual:
+            return body
+        # Manual over dp only; mp is an AUTO axis — specs here describe
+        # the dp placement, while the mp sharding of model tensors rides
+        # in from param_specs and GSPMD inserts the TP collectives.
+        return jax.shard_map(
+            body, mesh=self.core.plan.mesh, in_specs=in_specs,
+            out_specs=out_specs, axis_names=frozenset({"dp"}),
         )
-    return ok
 
+    def psum(self, x):
+        """A per-device partial summed over ``dp``; on the auto boundary the
+        sum already ranges over every client."""
+        return jax.lax.psum(x, "dp") if self.manual else x
 
-def _clip_client_deltas(d32, clip_norm):
-    """Per-client L2 norm clip over a block of f32 deltas: a delta beyond
-    the clip sphere is rescaled onto it. where-select (not a
-    multiply-by-1) so an unclipped delta — and the whole program under
-    the disabled-clip sentinel — stays bitwise untouched. Returns
-    ``(clipped_d32, too_big)``; shared by the synchronous and
-    buffered-async program builders."""
-    norm2 = functools.reduce(
-        jnp.add,
-        [jnp.square(l.reshape(l.shape[0], -1)).sum(axis=1)
-         for l in jax.tree.leaves(d32)],
-    )
-    too_big = norm2 > clip_norm * clip_norm
-    scale = jnp.where(too_big, clip_norm / jnp.sqrt(norm2), 1.0)
-    clipped = jax.tree.map(
-        lambda d: jnp.where(
-            too_big.reshape((-1,) + (1,) * (d.ndim - 1)),
-            d * scale.reshape((-1,) + (1,) * (d.ndim - 1)),
-            d,
-        ),
-        d32,
-    )
-    return clipped, too_big
+    def varying(self, tree):
+        return _to_varying(tree, "dp") if self.manual else tree
+
+    def _pin(self, tree, shardings):
+        if self.manual or shardings is None:
+            return tree
+        return jax.tree.map(jax.lax.with_sharding_constraint, tree,
+                            shardings)
+
+    def pin_params(self, tree):
+        """Params-shaped tree on the tensor-parallel layout."""
+        return self._pin(tree, self.core._param_shardings())
+
+    def pin_clients(self, tree):
+        """Per-client params-shaped tree [B, ...]: client axis over dp,
+        tensor-parallel leaves additionally over mp."""
+        return self._pin(tree, None if self.manual
+                         else self.core._client_sharded_like(tree))
+
+    def pin_client_axis(self, v):
+        """A per-client vector [C] over dp."""
+        return self._pin(v, self.core.plan.client_sharding())
+
+    def scatter_flat(self, leaf):
+        """A summed leaf as the flat padded coordinate shards the sharded
+        server update runs on: reduce-scattered over dp, or laid over
+        (dp, mp) for GSPMD to reduce."""
+        flat = _flat_pad_leaf(leaf, self.core._shard_pad)
+        if self.manual:
+            return jax.lax.psum_scatter(flat, "dp", scatter_dimension=0,
+                                        tiled=True)
+        return jax.lax.with_sharding_constraint(
+            flat, NamedSharding(self.core.plan.mesh, P(("dp", "mp"))))
+
+    def sharded_commit(self, params, opt_state, delta_shards):
+        core = self.core
+        return (core._apply_manual_sharded_update if self.manual
+                else core._apply_auto_sharded_update)(
+            params, opt_state, delta_shards)
 
 
 class FedCore:
@@ -579,7 +520,8 @@ class FedCore:
         ``vmap_clients=False`` — the model cannot be ``vmap``ped over
         per-client weights (``ModelSpec.vmap_clients``: a grouped matmul
         batches over a leading axis only): clients are taken one at a
-        time, which the resident dp-manual program does at
+        time, which the block stage (``round_stages.client_block``: the
+        resident, streamed and buffered programs) does at
         ``block_clients`` 1 by squeezing the block axis.
 
         ``lookup_tables`` — the model's lookup-only tables
@@ -642,8 +584,8 @@ class FedCore:
             )
         # Classification flag, not a code gate: tensor parallelism is
         # ACTIVE only when the mesh has an mp axis AND at least one leaf
-        # actually shards. The builder dispatch itself keys on
-        # plan.mp > 1 (mp=1 programs never see the auto builder, so
+        # actually shards. The boundary itself keys on
+        # plan.mp > 1 (mp=1 programs never see the auto boundary, so
         # inert/all-replicated specs leave them byte-identical — the
         # lowering-equality tests in tests/test_modelparallel.py and
         # tests/test_sharded_engine.py consume this flag as that
@@ -660,7 +602,7 @@ class FedCore:
         # mp=1 (O(params/dp) per chip, updated inside the manual shard_map
         # via psum_scatter), and over BOTH (dp, mp) when the mesh has a
         # model axis (O(params/(dp*mp)) per chip; the whole mp>1 round
-        # program runs in GSPMD-auto land — see _build_round_step_auto —
+        # program runs in GSPMD-auto land — see _MeshBoundary —
         # so the flat (dp, mp) layout is an ordinary sharding constraint).
         # The PartitionSpec tree is derived once from the optimizer-state
         # structure so init_state, the program specs, and checkpoint
@@ -1108,11 +1050,6 @@ class FedCore:
         return jax.tree.map(lambda t, orig: t.astype(orig.dtype), v, vparams), mean_loss
 
     # ----------------------------------------------------------- round step
-    # The mp axis is AUTO (not manual) in the shard_map below: model tensors
-    # annotated by param_specs stay sharded over mp through the whole round
-    # program and GSPMD inserts the tensor-parallel collectives. Models
-    # without specs (all-P() trees) are replicated over mp — correct but
-    # redundant; the transformer families shard (parallel/tp.py).
     def _build_round_step(self, with_deadline: bool = False,
                           with_attack: bool = False, defense=None):
         """``with_deadline=True`` builds the deadline-masked variant: two
@@ -1138,6 +1075,15 @@ class FedCore:
         all_to_all — O(clients x params / dp) peak per device, see
         engine/defense.py).
 
+        One body serves both boundaries (:class:`_MeshBoundary`); on the
+        GSPMD-auto one the supported variants are plain, deadline, attack
+        and clip-only defense — gathering defenses (robust aggregators /
+        anomaly scoring) are rejected at :meth:`_prepare_round_args`, their
+        coordinate-sharded layout is built on manual dp collectives
+        (docs/performance.md has the composition matrix) — and under
+        ``shard_server_update`` the optimizer runs on flat coordinates
+        sharded over BOTH axes (:meth:`_apply_auto_sharded_update`).
+
         The default variant is byte-identical to the pre-deadline,
         pre-defense program."""
         if self.plan.pp > 1:
@@ -1151,29 +1097,22 @@ class FedCore:
                     "program only (no deadline/attack/defense variants); "
                     "docs/performance.md has the composition matrix"
                 )
-            from olearning_sim_tpu.engine import pp_rounds
-
             return pp_rounds.build_pp_round_step(self, *self._pp_train)
-        if self.plan.mp > 1:
-            # Model-parallel mesh: the round program is built in pure
-            # GSPMD-auto land. A shard_map that is manual over dp but AUTO
-            # over an mp axis of size > 1 check-fails XLA 0.4.x's SPMD
-            # partitioner on every lax.scan (while-op operands carry
-            # partial-manual subgroup shardings hlo_sharding_util
-            # rejects), so at mp > 1 dp becomes an ordinary array-sharding
-            # axis and GSPMD inserts ALL collectives — tensor-parallel
-            # ones from param_specs and data-parallel reductions alike.
-            # mp = 1 keeps this manual builder byte-identical to earlier
-            # releases.
-            return self._build_round_step_auto(
-                with_deadline=with_deadline, with_attack=with_attack,
-                defense=defense,
-            )
-        plan = self.plan
+        # mp > 1 takes the GSPMD-auto boundary. The manual one with mp left
+        # to the auto partitioner was written off for a check-failure of an
+        # older XLA's SPMD partitioner on every lax.scan. What is known now
+        # (PR 30's probe: jax 0.9.0, the CPU backend's 8 virtual devices,
+        # this line skipped so that mp = 2 went through the manual
+        # boundary): 14 of the 16 tests of tests/test_tp.py and
+        # tests/test_modelparallel.py pass, test_mp2_matches_mp1 and the
+        # numpy-oracle aggregation among them; the two that fail are the
+        # shard_server_update x mp tests, whose optimizer state is laid out
+        # P(("dp", "mp")) and cannot enter a shard_map that is manual over
+        # dp alone. The chip has not been asked (ROADMAP.md Design 2).
+        bd = _MeshBoundary(self, manual=self.plan.mp == 1)
         cfg = self.config
         alg = self.algorithm
-        mesh = plan.mesh
-        dpn = plan.dp
+        dpn = self.plan.dp
         shard_update = cfg.shard_server_update
         personalized = alg.personalized
         controlled = alg.control_variates
@@ -1181,15 +1120,26 @@ class FedCore:
         defense_score = defense is not None and defense.score_enabled
         aggregator = defense.aggregator if defense is not None else "mean"
         robust_agg = aggregator in ("trimmed_mean", "median")
+        if defense_gather and not bd.manual:
+            raise ValueError(
+                "robust aggregators / anomaly scoring are not supported on "
+                "a model-parallel mesh (mp > 1); use clip_norm only"
+            )
         trace_key = (with_deadline, with_attack,
                      defense.structure_key if defense is not None else None)
         # The model's work counts ride the block scan as one more
-        # accumulator, last in the carry.
-        counted = self.apply_stats_fn is not None and not controlled
+        # accumulator, last in the carry (the manual boundary's program
+        # alone: ROADMAP.md Design 1).
+        counted = (self.apply_stats_fn is not None and not controlled
+                   and bd.manual)
+        # varying typing is a manual-shard_map concern; the auto program
+        # must not ask for it (pvary outside a bound axis is an error).
+        train_fn = functools.partial(self._local_train, varying=bd.manual,
+                                     with_stats=counted)
 
-        def shard_body(params, opt_state, round_idx, base_key,
-                       x, y, num_samples, num_steps, uid, weight, vparams,
-                       server_c, true_n, *extras):
+        def body(params, opt_state, round_idx, base_key,
+                 x, y, num_samples, num_steps, uid, weight, vparams,
+                 server_c, true_n, *extras):
             # Host-side effect that runs at TRACE time only: the
             # no-recompile regression probe (tests assert this count stays
             # flat while per-round data knobs change).
@@ -1207,10 +1157,9 @@ class FedCore:
                 # so a non-binding deadline (inf) leaves aggregation
                 # bit-for-bit unchanged.
                 late = completion_time > deadline
-                stragglers = jax.lax.psum(
+                stragglers = bd.psum(
                     jnp.logical_and(weight > 0, late)
-                    .sum().astype(jnp.float32),
-                    "dp",
+                    .sum().astype(jnp.float32)
                 )
                 weight = jnp.where(late, jnp.zeros_like(weight), weight)
             if with_attack:
@@ -1218,17 +1167,20 @@ class FedCore:
             if defense is not None:
                 clip_norm, trim_fraction = extras[0], extras[1]
                 del extras[:2]
+            params = bd.pin_params(params)
+            # The clients this body sees: one device's (manual) or all.
             c_local = x.shape[0]
-            if c_local % cfg.block_clients != 0:
+            if c_local % bd.block_width != 0:
                 raise ValueError(
-                    f"per-device client count {c_local} must be a multiple of "
-                    f"block_clients={cfg.block_clients}; pad the dataset with "
+                    f"client count {c_local} must be a multiple of "
+                    f"{bd.block_width} (block_clients="
+                    f"{cfg.block_clients} a dp shard); pad the dataset with "
                     f"ClientDataset.pad_for(plan, block=config.block_clients)"
                 )
-            nb = c_local // cfg.block_clients
+            nb = c_local // bd.block_width
 
             def blocked(a):
-                return a.reshape((nb, cfg.block_clients) + a.shape[1:])
+                return a.reshape((nb, bd.block_width) + a.shape[1:])
 
             xs = (blocked(x), blocked(y), blocked(num_samples),
                   blocked(num_steps), blocked(uid), blocked(weight),
@@ -1236,9 +1188,11 @@ class FedCore:
                   if (personalized or controlled) else None,
                   blocked(attack_scale) if with_attack else None)
 
-            zero_delta = jax.tree.map(
+            # Delta accumulators live on the same mp shards as the params,
+            # so the weighted-sum scan never re-lays model tensors.
+            zero_delta = bd.pin_params(jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params
-            )
+            ))
             init = (zero_delta, jnp.float32(0.0), jnp.float32(0.0),
                     jnp.float32(0.0), jnp.float32(0.0),
                     zero_delta if controlled else jnp.float32(0.0))
@@ -1251,7 +1205,7 @@ class FedCore:
                 init = init + (jnp.zeros(stats_shape.shape, jnp.int32),)
             # The carry accumulates device-varying values (per-shard client
             # sums), so its initial value must be typed as varying over dp.
-            init = _to_varying(init, "dp")
+            init = bd.varying(init)
 
             def block_step(carry, inp):
                 if counted:
@@ -1263,70 +1217,31 @@ class FedCore:
                     sum_delta, sum_w, sum_loss, count, sum_ploss, sum_dc = carry
                     n_clip = None
                 bx, by, bns, bst, buid, bw, bvp, batk = inp
-                with jax.named_scope("client_train"):
-                    if controlled:
-                        deltas, losses, dcis = jax.vmap(
-                            self._local_train,
-                            in_axes=(None, 0, 0, 0, 0, 0, None, None, None,
-                                     0),
-                        )(params, bx, by, bns, bst, buid, base_key,
-                          round_idx, server_c, bvp)
-                    else:
-                        deltas, losses, *bstats = (
-                            jax.vmap if self.vmap_clients
-                            else _one_client_block)(
-                            functools.partial(self._local_train,
-                                              with_stats=True)
-                            if counted else self._local_train,
-                            in_axes=(None, 0, 0, 0, 0, 0, None, None),
-                        )(params, bx, by, bns, bst, buid, base_key,
-                          round_idx)
-                with jax.named_scope("delta_transform"):
-                    if with_attack:
-                        deltas = _attack_deltas(deltas, batk)
-                    # Resilience gate (_finite_client_mask): a diverged
-                    # client contributes nothing, finite clients bitwise
-                    # unchanged.
-                    ok = _finite_client_mask(losses, deltas)
-
-                def gate(d):
-                    return jnp.where(
-                        ok.reshape((-1,) + (1,) * (d.ndim - 1)), d, 0.0
-                    )
-
-                bw_eff = jnp.where(ok, bw, 0.0)
-                defense_ys = None
+                blk = client_block(
+                    train_fn,
+                    (None, 0, 0, 0, 0, 0, None, None)
+                    + ((None, 0) if controlled else ()),
+                    (params, bx, by, bns, bst, buid, base_key, round_idx)
+                    + ((server_c, bvp) if controlled else ()),
+                    bw, vmap_clients=self.vmap_clients,
+                    pin_clients=bd.pin_clients, attack_scale=batk,
+                    clip_norm=clip_norm,
+                )
+                losses, bw_eff, gate = blk.losses, blk.bw_eff, blk.gate
                 if defense is not None:
-                    with jax.named_scope("delta_transform"):
-                        d32 = jax.tree.map(
-                            lambda d: gate(d.astype(jnp.float32)), deltas
-                        )
-                        d32, too_big = _clip_client_deltas(d32, clip_norm)
-                        n_clip = n_clip + jnp.logical_and(
-                            bw_eff > 0, too_big
-                        ).sum().astype(jnp.float32)
-                    with jax.named_scope("aggregate"):
-                        sum_delta = jax.tree.map(
-                            lambda s, d: s + jnp.tensordot(
-                                bw_eff, d, axes=(0, 0)),
-                            sum_delta, d32,
-                        )
-                    if defense_gather:
-                        # The gathering aggregators/scores need every
-                        # client's (gated, clipped) delta — emitted from the
-                        # scan and all-gathered after it.
-                        defense_ys = (d32, bw_eff)
-                else:
-                    sum_delta = _accumulate_delta(sum_delta, deltas, bw_eff,
-                                                  gate)
-                sum_w = sum_w + bw_eff.sum()
-                sum_loss = sum_loss + jnp.where(ok, bw * losses, 0.0).sum()
-                count = count + (bw_eff > 0).sum().astype(jnp.float32)
+                    n_clip = n_clip + blk.clipped
+                sum_delta = bd.pin_params(blk.weighted_sum(sum_delta))
+                # The gathering aggregators/scores need every client's
+                # (gated, clipped) delta — emitted from the scan and
+                # all-gathered after it.
+                defense_ys = (blk.d32, bw_eff) if defense_gather else None
+                sum_w, sum_loss, count = blk.tally(sum_w, sum_loss, count)
                 if controlled:
                     # c_i advances only for participating clients whose
                     # update survived the finiteness gate; the server
                     # control absorbs the weighted mean correction below.
                     active = bw_eff > 0
+                    dcis, = blk.extra
 
                     def gate_active(d):
                         return jnp.where(
@@ -1378,7 +1293,7 @@ class FedCore:
                 if defense is not None:
                     new_carry = new_carry + (n_clip,)
                 if counted:
-                    new_carry = new_carry + (sum_stats + bstats[0].sum(0),)
+                    new_carry = new_carry + (sum_stats + blk.extra[0].sum(0),)
                 return new_carry, ys + (defense_ys,)
 
             carry, (block_losses, new_vparams, defense_out) = jax.lax.scan(
@@ -1387,18 +1302,18 @@ class FedCore:
             model_stats = jnp.float32(0.0)
             if counted:
                 *carry, sum_stats = carry
-                model_stats = jax.lax.psum(sum_stats, "dp")
+                model_stats = bd.psum(sum_stats)
             if defense is not None:
                 (sum_delta, sum_w, sum_loss, count, sum_ploss, sum_dc,
                  n_clip) = carry
             else:
                 sum_delta, sum_w, sum_loss, count, sum_ploss, sum_dc = carry
                 n_clip = jnp.float32(0.0)
-            client_loss = block_losses.reshape((c_local,))
+            client_loss = bd.pin_client_axis(block_losses.reshape((c_local,)))
             if personalized or controlled:
-                new_vparams = jax.tree.map(
+                new_vparams = bd.pin_clients(jax.tree.map(
                     lambda a: a.reshape((c_local,) + a.shape[2:]), new_vparams
-                )
+                ))
 
             with jax.named_scope("aggregate"):
                 # Cross-device FedAvg: the Pulsar gradient transport of the
@@ -1406,13 +1321,15 @@ class FedCore:
                 # mesh — a full psum of the weighted delta on the replicated
                 # path, or a reduce-scatter (each chip keeps the cross-replica
                 # sum for its 1/dp of the coordinates) under the sharded
-                # server update.
-                sum_w = jax.lax.psum(sum_w, "dp")
-                sum_loss = jax.lax.psum(sum_loss, "dp")
-                count = jax.lax.psum(count, "dp")
-                sum_ploss = jax.lax.psum(sum_ploss, "dp")
+                # server update. On the auto boundary the sums already range
+                # over every client and the reduction is a GSPMD-inserted
+                # collective (``bd.psum`` is the identity).
+                sum_w = bd.psum(sum_w)
+                sum_loss = bd.psum(sum_loss)
+                count = bd.psum(count)
+                sum_ploss = bd.psum(sum_ploss)
                 if defense is not None:
-                    n_clip = jax.lax.psum(n_clip, "dp")
+                    n_clip = bd.psum(n_clip)
 
                 denom = jnp.maximum(sum_w, 1e-8)
                 mean_delta = delta_shards = None
@@ -1421,14 +1338,10 @@ class FedCore:
                     # it entirely below, so its collective is skipped then).
                     if shard_update:
                         delta_shards = jax.tree.map(
-                            lambda s: jax.lax.psum_scatter(
-                                _flat_pad_leaf(s, dpn), "dp",
-                                scatter_dimension=0, tiled=True,
-                            ) / denom,
-                            sum_delta,
+                            lambda s: bd.scatter_flat(s) / denom, sum_delta
                         )
                     else:
-                        sum_delta = jax.lax.psum(sum_delta, "dp")
+                        sum_delta = bd.psum(sum_delta)
                         mean_delta = jax.tree.map(
                             lambda s: s / denom, sum_delta
                         )
@@ -1442,8 +1355,6 @@ class FedCore:
                     # replicate. Each coordinate's client column is intact, so
                     # the per-coordinate sort/window statistics are bit-for-bit
                     # those of the gathered formulation.
-                    from olearning_sim_tpu.engine import defense as defense_mod
-
                     d_pc, w_pc = defense_out
                     # The participant mask is the only thing replicated in
                     # full — O(clients) bytes.
@@ -1510,63 +1421,13 @@ class FedCore:
                             (c_local,),
                         )
             with jax.named_scope("server_update"):
-                # Server optimizer consumes the negative mean delta as a
-                # pseudo-gradient (FedOpt formulation).
                 if shard_update:
-                    # Cross-replica sharded weight update (arXiv 2004.13336):
-                    # update THIS chip's 1/dp coordinate slice with the
-                    # optimizer state that lives sharded the same way, then
-                    # stitch the fresh params from the disjoint shards (exact
-                    # — each coordinate has exactly one contributor).
-                    from olearning_sim_tpu.engine import defense as defense_mod
-
-                    def my_shard(p):
-                        flat = _flat_pad_leaf(p, dpn)
-                        s = flat.shape[0] // dpn
-                        return jax.lax.dynamic_slice(
-                            flat, (jax.lax.axis_index("dp") * s,), (s,)
-                        )
-
-                    shard_params = jax.tree.map(my_shard, params)
-                    pseudo_grad = jax.tree.map(
-                        lambda d, p: (-d).astype(p.dtype),
-                        delta_shards, shard_params,
-                    )
-                    # Replicated state (Adam's count) stays whole on every
-                    # chip; type it varying on entry and re-type on exit (pmax
-                    # over identical values — a bitwise no-op) so it can cross
-                    # the sharded update on VMA runtimes. The sharded/
-                    # replicated split comes from the build-time template
-                    # (self._opt_sharded) — a shape test here would see
-                    # shard-LOCAL leaves and misclassify them.
-                    opt_in = jax.tree.map(
-                        lambda l, sharded: l if sharded
-                        else _to_varying(l, "dp"),
-                        opt_state, self._opt_sharded,
-                    )
-                    updates, new_opt_state = alg.server_optimizer.update(
-                        pseudo_grad, opt_in, shard_params
-                    )
-                    new_shards = optax.apply_updates(shard_params, updates)
-                    new_opt_state = jax.tree.map(
-                        lambda l, sharded: l if sharded
-                        else jax.lax.pmax(l, "dp"),
-                        new_opt_state, self._opt_sharded,
-                    )
-                    new_params = jax.tree.map(
-                        lambda s, p: defense_mod.place_coordinate_shard(
-                            s, "dp", dpn, p.shape
-                        ),
-                        new_shards, params,
-                    )
+                    new_params, new_opt_state = bd.sharded_commit(
+                        params, opt_state, delta_shards)
                 else:
-                    pseudo_grad = jax.tree.map(
-                        lambda d, p: (-d).astype(p.dtype), mean_delta, params
-                    )
-                    updates, new_opt_state = alg.server_optimizer.update(
-                        pseudo_grad, opt_state, params
-                    )
-                    new_params = optax.apply_updates(params, updates)
+                    new_params, new_opt_state = server_commit(
+                        alg.server_optimizer, params, opt_state, mean_delta)
+                    new_params = bd.pin_params(new_params)
             new_server_c = None
             if controlled:
                 # c <- c + (|S|/N) * weighted-mean dc_i (SCAFFOLD eq. 5 with
@@ -1575,7 +1436,7 @@ class FedCore:
                 # dp/block_clients padding AND cohort take() subsetting, so
                 # partial participation keeps frac = |S|/N instead of
                 # collapsing to ~1 (ADVICE r3).
-                sum_dc = jax.lax.psum(sum_dc, "dp")
+                sum_dc = bd.psum(sum_dc)
                 frac = count / jnp.maximum(true_n, 1.0)
                 new_server_c = jax.tree.map(
                     lambda c, s: c + frac * (s / denom), server_c, sum_dc
@@ -1615,461 +1476,68 @@ class FedCore:
         # derived at construction.
         opt_spec = self._opt_spec if shard_update else rep
 
-        def make_shard_fn(vp_tree, sc_tree=None):
+        def make_fn(vp_tree, sc_tree):
             vp_spec = jax.tree.map(lambda _: cl, vp_tree)
             sc_spec = jax.tree.map(lambda _: rep, sc_tree)
-            # Manual over dp only; mp is an AUTO axis — specs here describe
-            # the dp placement, while the mp sharding of model tensors rides
-            # in from param_specs and GSPMD inserts the TP collectives.
-            return jax.shard_map(
-                shard_body,
-                mesh=mesh,
+            return bd.wrap(
+                body,
                 in_specs=(rep, opt_spec, rep, rep, cl, cl, cl, cl, cl,
                           cl, vp_spec, sc_spec, rep) + extra_specs,
                 out_specs=(rep, opt_spec, rep, metrics_specs, vp_spec,
                            sc_spec),
-                axis_names=frozenset({"dp"}),
             )
 
-        if controlled:
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def round_step(state: ServerState, control: ControlState,
-                           x, y, num_samples, num_steps, uid, weight, true_n,
-                           *extras):
-                (new_params, new_opt_state, new_round, metrics, new_ci,
-                 new_sc) = make_shard_fn(
-                    control.client_controls, control.server_control
-                )(
-                    state.params, state.opt_state, state.round_idx,
-                    state.base_key, x, y, num_samples, num_steps, uid,
-                    weight, control.client_controls, control.server_control,
-                    true_n, *extras,
-                )
-                return (
-                    ServerState(
-                        params=new_params,
-                        opt_state=new_opt_state,
-                        round_idx=new_round,
-                        base_key=state.base_key,
-                    ),
-                    metrics,
-                    ControlState(client_controls=new_ci, server_control=new_sc),
-                )
-        elif personalized:
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def round_step(state: ServerState, personal: PersonalState,
-                           x, y, num_samples, num_steps, uid, weight,
-                           *extras):
-                new_params, new_opt_state, new_round, metrics, new_vp, _ = (
-                    make_shard_fn(personal.params)(
-                        state.params, state.opt_state, state.round_idx,
-                        state.base_key, x, y, num_samples, num_steps, uid,
-                        weight, personal.params, None, jnp.float32(0.0),
-                        *extras,
-                    )
-                )
-                return (
-                    ServerState(
-                        params=new_params,
-                        opt_state=new_opt_state,
-                        round_idx=new_round,
-                        base_key=state.base_key,
-                    ),
-                    metrics,
-                    PersonalState(params=new_vp),
-                )
-        else:
-            shard_fn = make_shard_fn(None)
+        return jit_round_step(make_fn, personalized, controlled)
 
-            @functools.partial(jax.jit, donate_argnums=(0,))
-            def round_step(state: ServerState, x, y, num_samples, num_steps,
-                           uid, weight, *extras):
-                new_params, new_opt_state, new_round, metrics, _, _ = shard_fn(
-                    state.params, state.opt_state, state.round_idx, state.base_key,
-                    x, y, num_samples, num_steps, uid, weight, None, None,
-                    jnp.float32(0.0), *extras,
-                )
-                return (
-                    ServerState(
-                        params=new_params,
-                        opt_state=new_opt_state,
-                        round_idx=new_round,
-                        base_key=state.base_key,
-                    ),
-                    metrics,
-                )
+    def _apply_manual_sharded_update(self, params, opt_state, delta_shards):
+        """Cross-replica sharded weight update (arXiv 2004.13336) inside the
+        manual-dp ``shard_map``: update THIS chip's 1/dp coordinate slice
+        with the optimizer state that lives sharded the same way, then
+        stitch the fresh params from the disjoint shards (exact — each
+        coordinate has exactly one contributor). ``delta_shards``: this
+        chip's slice of the mean delta, flat."""
+        dpn = self.plan.dp
 
-        return round_step
-
-    def _build_round_step_auto(self, with_deadline: bool = False,
-                               with_attack: bool = False, defense=None):
-        """The mp>1 round program: same semantics as the manual
-        :meth:`_build_round_step` body, expressed entirely in GSPMD-auto
-        land (one ``jax.jit``, no ``shard_map``).
-
-        Why not the manual program: a shard_map that is manual over ``dp``
-        but auto over an ``mp`` axis of size > 1 check-fails XLA 0.4.x's
-        SPMD partitioner on every ``lax.scan`` (``Check failed:
-        sharding.IsManualSubgroup()`` on the while-op operands), so model
-        parallelism cannot ride through the manual boundary on this
-        runtime. Here clients are an ordinary dp-sharded array axis, model
-        tensors carry the tensor-parallel layout from ``param_specs`` via
-        sharding constraints (params, grads, per-client deltas, and the
-        delta accumulators all pin to the SAME mp shards — no resharding
-        collective between train and aggregate), and GSPMD inserts every
-        collective: the Megatron all-gather/reduce-scatters inside the
-        per-client forward/backward AND the cross-replica delta
-        reductions.
-
-        Supported variants: plain, deadline, attack, and clip-only
-        defense. Gathering defenses (robust aggregators / anomaly
-        scoring) are rejected at :meth:`_prepare_round_args` — their
-        coordinate-sharded layout is built on manual dp collectives
-        (docs/performance.md has the composition matrix). Under
-        ``shard_server_update`` the optimizer runs on flat coordinates
-        sharded over BOTH axes (:meth:`_apply_auto_sharded_update` —
-        O(params/(dp*mp)) resident state per chip)."""
-        plan = self.plan
-        cfg = self.config
-        alg = self.algorithm
-        mesh = plan.mesh
-        dpn = plan.dp
-        shard_update = cfg.shard_server_update
-        personalized = alg.personalized
-        controlled = alg.control_variates
-        if defense is not None and defense.gathers_deltas:
-            raise ValueError(
-                "robust aggregators / anomaly scoring are not supported on "
-                "a model-parallel mesh (mp > 1); use clip_norm only"
-            )
-        trace_key = (with_deadline, with_attack,
-                     defense.structure_key if defense is not None else None)
-
-        wsc = jax.lax.with_sharding_constraint
-        csh = NamedSharding(mesh, P("dp"))
-        specs = self.param_specs
-
-        def pin_params(tree):
-            """Params-shaped tree on the tensor-parallel layout."""
-            if specs is None:
-                return tree
-            return jax.tree.map(
-                lambda v, s: wsc(v, NamedSharding(mesh, s)), tree, specs,
-                is_leaf=lambda s: isinstance(s, P),
+        def my_shard(p):
+            flat = _flat_pad_leaf(p, dpn)
+            s = flat.shape[0] // dpn
+            return jax.lax.dynamic_slice(
+                flat, (jax.lax.axis_index("dp") * s,), (s,)
             )
 
-        def pin_clients(tree):
-            """Per-client params-shaped tree [B, ...]: client axis over
-            dp, tensor-parallel leaves additionally over mp."""
-            if specs is None:
-                return jax.tree.map(lambda v: wsc(v, csh), tree)
-            return jax.tree.map(
-                lambda v, s: wsc(v, NamedSharding(mesh, P("dp", *s))),
-                tree, specs,
-                is_leaf=lambda s: isinstance(s, P),
-            )
-
-        # varying typing is a manual-shard_map concern; the auto program
-        # must not ask for it (pvary outside a bound axis is an error on
-        # runtimes that have it).
-        train_fn = functools.partial(self._local_train, varying=False)
-
-        def body(params, opt_state, round_idx, base_key,
-                 x, y, num_samples, num_steps, uid, weight, vparams,
-                 server_c, true_n, *extras):
-            # Trace-time probe (see the manual builder).
-            self.trace_counts[trace_key] = \
-                self.trace_counts.get(trace_key, 0) + 1
-            extras = list(extras)
-            stragglers = jnp.float32(0.0)
-            attack_scale = clip_norm = trim_fraction = None
-            if with_deadline:
-                completion_time, deadline = extras[0], extras[1]
-                del extras[:2]
-                late = completion_time > deadline
-                stragglers = jnp.logical_and(
-                    weight > 0, late
-                ).sum().astype(jnp.float32)
-                weight = jnp.where(late, jnp.zeros_like(weight), weight)
-            if with_attack:
-                attack_scale = extras.pop(0)
-            if defense is not None:
-                clip_norm, trim_fraction = extras[0], extras[1]
-                del extras[:2]
-            params = pin_params(params)
-            c_total = x.shape[0]
-            # One "block" is block_clients PER dp shard, matching the
-            # manual program's per-device peak-memory bound.
-            bcg = cfg.block_clients * dpn
-            if c_total % bcg != 0:
-                raise ValueError(
-                    f"padded client count {c_total} must be a multiple of "
-                    f"block_clients*dp={bcg}; pad the dataset with "
-                    f"ClientDataset.pad_for(plan, block=config.block_clients)"
-                )
-            nb = c_total // bcg
-
-            def blocked(a):
-                return a.reshape((nb, bcg) + a.shape[1:])
-
-            xs = (blocked(x), blocked(y), blocked(num_samples),
-                  blocked(num_steps), blocked(uid), blocked(weight),
-                  jax.tree.map(blocked, vparams)
-                  if (personalized or controlled) else None,
-                  blocked(attack_scale) if with_attack else None)
-
-            # Delta accumulators live on the same mp shards as the params,
-            # so the weighted-sum scan never re-lays model tensors.
-            zero_delta = pin_params(jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params
-            ))
-            init = (zero_delta, jnp.float32(0.0), jnp.float32(0.0),
-                    jnp.float32(0.0), jnp.float32(0.0),
-                    zero_delta if controlled else jnp.float32(0.0))
-            if defense is not None:
-                init = init + (jnp.float32(0.0),)
-
-            def block_step(carry, inp):
-                if defense is not None:
-                    (sum_delta, sum_w, sum_loss, count, sum_ploss, sum_dc,
-                     n_clip) = carry
-                else:
-                    sum_delta, sum_w, sum_loss, count, sum_ploss, sum_dc = carry
-                    n_clip = None
-                bx, by, bns, bst, buid, bw, bvp, batk = inp
-                with jax.named_scope("client_train"):
-                    if controlled:
-                        deltas, losses, dcis = jax.vmap(
-                            train_fn,
-                            in_axes=(None, 0, 0, 0, 0, 0, None, None, None,
-                                     0),
-                        )(params, bx, by, bns, bst, buid, base_key,
-                          round_idx, server_c, bvp)
-                    else:
-                        deltas, losses = jax.vmap(
-                            train_fn,
-                            in_axes=(None, 0, 0, 0, 0, 0, None, None),
-                        )(params, bx, by, bns, bst, buid, base_key,
-                          round_idx)
-                    # Per-client deltas pinned to (dp over clients, mp per
-                    # specs) straight out of the vmapped train body.
-                    deltas = pin_clients(deltas)
-                with jax.named_scope("delta_transform"):
-                    if with_attack:
-                        deltas = _attack_deltas(deltas, batk)
-                    ok = _finite_client_mask(losses, deltas)
-
-                def gate(d):
-                    return jnp.where(
-                        ok.reshape((-1,) + (1,) * (d.ndim - 1)), d, 0.0
-                    )
-
-                bw_eff = jnp.where(ok, bw, 0.0)
-                if defense is not None:
-                    with jax.named_scope("delta_transform"):
-                        d32 = jax.tree.map(
-                            lambda d: gate(d.astype(jnp.float32)), deltas
-                        )
-                        d32, too_big = _clip_client_deltas(d32, clip_norm)
-                        n_clip = n_clip + jnp.logical_and(
-                            bw_eff > 0, too_big
-                        ).sum().astype(jnp.float32)
-                    with jax.named_scope("aggregate"):
-                        sum_delta = jax.tree.map(
-                            lambda s, d: s + jnp.tensordot(
-                                bw_eff, d, axes=(0, 0)),
-                            sum_delta, d32,
-                        )
-                else:
-                    sum_delta = _accumulate_delta(sum_delta, deltas, bw_eff,
-                                                  gate)
-                sum_delta = pin_params(sum_delta)
-                sum_w = sum_w + bw_eff.sum()
-                sum_loss = sum_loss + jnp.where(ok, bw * losses, 0.0).sum()
-                count = count + (bw_eff > 0).sum().astype(jnp.float32)
-                if controlled:
-                    active = bw_eff > 0
-
-                    def gate_active(d):
-                        return jnp.where(
-                            active.reshape((-1,) + (1,) * (d.ndim - 1)), d, 0.0
-                        )
-
-                    new_bvp = jax.tree.map(
-                        lambda v, d: v + gate_active(d), bvp, dcis
-                    )
-                    sum_dc = jax.tree.map(
-                        lambda s, d: s + jnp.tensordot(bw_eff, gate(d), axes=(0, 0)),
-                        sum_dc, dcis,
-                    )
-                    ys = (losses, new_bvp)
-                elif personalized:
-                    with jax.named_scope("client_train"):
-                        new_vp, plosses = jax.vmap(
-                            self._personal_train,
-                            in_axes=(0, None, 0, 0, 0, 0, 0, 0, None, None),
-                        )(bvp, params, bx, by, bns, bst, buid, bw > 0,
-                          base_key, round_idx)
-                    okp = jnp.isfinite(plosses)
-                    for d in jax.tree.leaves(new_vp):
-                        okp = jnp.logical_and(
-                            okp,
-                            jnp.isfinite(d.reshape(d.shape[0], -1)).all(axis=1),
-                        )
-                    keep = jnp.logical_or(okp, jnp.logical_not(bw > 0))
-                    new_vp = jax.tree.map(
-                        lambda nv, ov: jnp.where(
-                            keep.reshape((-1,) + (1,) * (nv.ndim - 1)), nv, ov
-                        ),
-                        new_vp, bvp,
-                    )
-                    sum_ploss = sum_ploss + jnp.where(
-                        jnp.logical_and(bw > 0, okp), bw * plosses, 0.0
-                    ).sum()
-                    ys = (losses, new_vp)
-                else:
-                    ys = (losses, None)
-                new_carry = (sum_delta, sum_w, sum_loss, count, sum_ploss,
-                             sum_dc)
-                if defense is not None:
-                    new_carry = new_carry + (n_clip,)
-                return new_carry, ys
-
-            carry, (block_losses, new_vparams) = jax.lax.scan(
-                block_step, init, xs, unroll=min(cfg.block_unroll, nb)
-            )
-            if defense is not None:
-                (sum_delta, sum_w, sum_loss, count, sum_ploss, sum_dc,
-                 n_clip) = carry
-            else:
-                sum_delta, sum_w, sum_loss, count, sum_ploss, sum_dc = carry
-                n_clip = jnp.float32(0.0)
-            client_loss = wsc(block_losses.reshape((c_total,)), csh)
-            if personalized or controlled:
-                new_vparams = pin_clients(jax.tree.map(
-                    lambda a: a.reshape((c_total,) + a.shape[2:]), new_vparams
-                ))
-
-            # The sums above already range over every client — the
-            # cross-replica reduction the manual program psums explicitly
-            # is a GSPMD-inserted collective here.
-            denom = jnp.maximum(sum_w, 1e-8)
-            if shard_update:
-                with jax.named_scope("aggregate"):
-                    # Flat (dp, mp) coordinate shards straight from the
-                    # weighted sum (O(params/(dp*mp)) optimizer state).
-                    flat_sh = NamedSharding(mesh, P(("dp", "mp")))
-                    delta_flat = jax.tree.map(
-                        lambda s: wsc(
-                            _flat_pad_leaf(s, self._shard_pad), flat_sh
-                        ) / denom,
-                        sum_delta,
-                    )
-                with jax.named_scope("server_update"):
-                    new_params, new_opt_state = (
-                        self._apply_auto_sharded_update(
-                            params, opt_state, delta_flat
-                        )
-                    )
-            else:
-                with jax.named_scope("aggregate"):
-                    mean_delta = jax.tree.map(
-                        lambda s: s / denom, sum_delta
-                    )
-                with jax.named_scope("server_update"):
-                    pseudo_grad = jax.tree.map(
-                        lambda d, p: (-d).astype(p.dtype), mean_delta, params
-                    )
-                    updates, new_opt_state = alg.server_optimizer.update(
-                        pseudo_grad, opt_state, params
-                    )
-                    new_params = pin_params(
-                        optax.apply_updates(params, updates)
-                    )
-            new_server_c = None
-            if controlled:
-                frac = count / jnp.maximum(true_n, 1.0)
-                new_server_c = jax.tree.map(
-                    lambda c, s: c + frac * (s / denom), server_c, sum_dc
-                )
-            metrics = RoundMetrics(
-                mean_loss=sum_loss / denom,
-                weight_sum=sum_w,
-                clients_trained=count,
-                client_loss=client_loss,
-                personal_loss=sum_ploss / denom,
-                stragglers=stragglers,
-                anomaly_score=jnp.float32(0.0),
-                clipped=n_clip,
-            )
-            return (new_params, new_opt_state, round_idx + 1, metrics,
-                    new_vparams, new_server_c)
-
-        if controlled:
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def round_step(state: ServerState, control: ControlState,
-                           x, y, num_samples, num_steps, uid, weight, true_n,
-                           *extras):
-                (new_params, new_opt_state, new_round, metrics, new_ci,
-                 new_sc) = body(
-                    state.params, state.opt_state, state.round_idx,
-                    state.base_key, x, y, num_samples, num_steps, uid,
-                    weight, control.client_controls, control.server_control,
-                    true_n, *extras,
-                )
-                return (
-                    ServerState(
-                        params=new_params,
-                        opt_state=new_opt_state,
-                        round_idx=new_round,
-                        base_key=state.base_key,
-                    ),
-                    metrics,
-                    ControlState(client_controls=new_ci, server_control=new_sc),
-                )
-        elif personalized:
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def round_step(state: ServerState, personal: PersonalState,
-                           x, y, num_samples, num_steps, uid, weight,
-                           *extras):
-                new_params, new_opt_state, new_round, metrics, new_vp, _ = (
-                    body(
-                        state.params, state.opt_state, state.round_idx,
-                        state.base_key, x, y, num_samples, num_steps, uid,
-                        weight, personal.params, None, jnp.float32(0.0),
-                        *extras,
-                    )
-                )
-                return (
-                    ServerState(
-                        params=new_params,
-                        opt_state=new_opt_state,
-                        round_idx=new_round,
-                        base_key=state.base_key,
-                    ),
-                    metrics,
-                    PersonalState(params=new_vp),
-                )
-        else:
-            @functools.partial(jax.jit, donate_argnums=(0,))
-            def round_step(state: ServerState, x, y, num_samples, num_steps,
-                           uid, weight, *extras):
-                new_params, new_opt_state, new_round, metrics, _, _ = body(
-                    state.params, state.opt_state, state.round_idx,
-                    state.base_key, x, y, num_samples, num_steps, uid,
-                    weight, None, None, jnp.float32(0.0), *extras,
-                )
-                return (
-                    ServerState(
-                        params=new_params,
-                        opt_state=new_opt_state,
-                        round_idx=new_round,
-                        base_key=state.base_key,
-                    ),
-                    metrics,
-                )
-
-        return round_step
+        shard_params = jax.tree.map(my_shard, params)
+        pseudo_grad = jax.tree.map(
+            lambda d, p: (-d).astype(p.dtype),
+            delta_shards, shard_params,
+        )
+        # Replicated state (Adam's count) stays whole on every
+        # chip; type it varying on entry and re-type on exit (pmax
+        # over identical values — a bitwise no-op) so it can cross
+        # the sharded update on VMA runtimes. The sharded/
+        # replicated split comes from the build-time template
+        # (self._opt_sharded) — a shape test here would see
+        # shard-LOCAL leaves and misclassify them.
+        opt_in = jax.tree.map(
+            lambda l, sharded: l if sharded
+            else _to_varying(l, "dp"),
+            opt_state, self._opt_sharded,
+        )
+        updates, new_opt_state = self.algorithm.server_optimizer.update(
+            pseudo_grad, opt_in, shard_params
+        )
+        new_shards = optax.apply_updates(shard_params, updates)
+        new_opt_state = jax.tree.map(
+            lambda l, sharded: l if sharded
+            else jax.lax.pmax(l, "dp"),
+            new_opt_state, self._opt_sharded,
+        )
+        new_params = jax.tree.map(
+            lambda s, p: defense_mod.place_coordinate_shard(
+                s, "dp", dpn, p.shape
+            ),
+            new_shards, params,
+        )
+        return new_params, new_opt_state
 
     def _apply_auto_sharded_update(self, params, opt_state, delta_flat):
         """Cross-replica sharded server update on a model-parallel mesh
@@ -2080,7 +1548,7 @@ class FedCore:
         GSPMD-auto land, and fresh params are restored to their
         tensor-parallel layout (param_specs) by one gather per leaf.
         Runs inside the jitted GSPMD-auto round program
-        (``_build_round_step_auto`` — there is no shard_map at mp>1):
+        (:class:`_MeshBoundary` — there is no shard_map at mp>1):
         ``delta_flat`` arrives as the flat mean delta pinned to
         ``P(("dp", "mp"))`` by a with_sharding_constraint, and GSPMD
         places the scatter/gather collectives."""
@@ -2351,8 +1819,6 @@ class FedCore:
         """Resolve the buffered-async program variant + launch arguments
         for one :class:`~olearning_sim_tpu.engine.async_rounds.
         AsyncRoundPlan` (see :meth:`_prepare_round_args`)."""
-        from olearning_sim_tpu.engine import async_rounds
-
         if self.plan.mp > 1:
             raise ValueError(
                 "buffered asynchronous rounds do not compose with a "
@@ -2444,7 +1910,7 @@ class FedCore:
         from olearning_sim_tpu.telemetry import instrument
 
         t0 = time.perf_counter()
-        out = fn(*args)
+        out = _call_in_roomy_frame(fn, *args)
         name = self.algorithm.name
         instrument("ols_fedcore_round_steps_total").labels(
             algorithm=name
@@ -2462,9 +1928,8 @@ class FedCore:
     # device-sized blocks with the partial aggregates carried ON DEVICE
     # across blocks and the server update applied once at round close, so
     # peak HBM is O(block) regardless of population size. The per-block
-    # computation reuses the EXACT helper chain of the resident program
-    # (_local_train -> _attack_deltas -> _finite_client_mask ->
-    # _clip_client_deltas -> the same weighted tensordot accumulation),
+    # computation is the resident program's block stage and accumulator
+    # (round_stages.client_block -> ClientBlock.weighted_sum),
     # and the client->device layout interleaves stream blocks so each
     # device folds ITS monolithic row range in the monolithic order —
     # which is what makes a >=2-block streamed round bitwise identical to
@@ -2585,47 +2050,20 @@ class FedCore:
                     sum_delta, sum_w, sum_loss, count = carry
                     n_clip = None
                 bx, by, bns, bst, buid, bw, batk = inp
-                with jax.named_scope("client_train"):
-                    deltas, losses = jax.vmap(
-                        self._local_train,
-                        in_axes=(None, 0, 0, 0, 0, 0, None, None),
-                    )(params, bx, by, bns, bst, buid, base_key, round_idx)
-                with jax.named_scope("delta_transform"):
-                    if with_attack:
-                        deltas = _attack_deltas(deltas, batk)
-                    ok = _finite_client_mask(losses, deltas)
-
-                def gate(d):
-                    return jnp.where(
-                        ok.reshape((-1,) + (1,) * (d.ndim - 1)), d, 0.0
-                    )
-
-                bw_eff = jnp.where(ok, bw, 0.0)
+                blk = client_block(
+                    self._local_train, (None, 0, 0, 0, 0, 0, None, None),
+                    (params, bx, by, bns, bst, buid, base_key, round_idx),
+                    bw, vmap_clients=self.vmap_clients, attack_scale=batk,
+                    clip_norm=clip_norm,
+                )
                 if defense is not None:
-                    with jax.named_scope("delta_transform"):
-                        d32 = jax.tree.map(
-                            lambda d: gate(d.astype(jnp.float32)), deltas
-                        )
-                        d32, too_big = _clip_client_deltas(d32, clip_norm)
-                        n_clip = n_clip + jnp.logical_and(
-                            bw_eff > 0, too_big
-                        ).sum().astype(jnp.float32)
-                    with jax.named_scope("aggregate"):
-                        sum_delta = jax.tree.map(
-                            lambda s, d: s + jnp.tensordot(
-                                bw_eff, d, axes=(0, 0)),
-                            sum_delta, d32,
-                        )
-                else:
-                    sum_delta = _accumulate_delta(sum_delta, deltas, bw_eff,
-                                                  gate)
-                sum_w = sum_w + bw_eff.sum()
-                sum_loss = sum_loss + jnp.where(ok, bw * losses, 0.0).sum()
-                count = count + (bw_eff > 0).sum().astype(jnp.float32)
-                new_carry = (sum_delta, sum_w, sum_loss, count)
+                    n_clip = n_clip + blk.clipped
+                sum_delta = blk.weighted_sum(sum_delta)
+                new_carry = (sum_delta,
+                             *blk.tally(sum_w, sum_loss, count))
                 if defense is not None:
                     new_carry = new_carry + (n_clip,)
-                return new_carry, losses
+                return new_carry, blk.losses
 
             carry, block_losses = jax.lax.scan(
                 block_step, init, xs, unroll=min(cfg.block_unroll, nb)
@@ -2672,13 +2110,8 @@ class FedCore:
                 denom = jnp.maximum(sum_w, 1e-8)
                 mean_delta = jax.tree.map(lambda s: s / denom, sum_delta)
             with jax.named_scope("server_update"):
-                pseudo_grad = jax.tree.map(
-                    lambda d, p: (-d).astype(p.dtype), mean_delta, params
-                )
-                updates, new_opt_state = alg.server_optimizer.update(
-                    pseudo_grad, opt_state, params
-                )
-                new_params = optax.apply_updates(params, updates)
+                new_params, new_opt_state = server_commit(
+                    alg.server_optimizer, params, opt_state, mean_delta)
             metrics = RoundMetrics(
                 mean_loss=sum_loss / denom,
                 weight_sum=sum_w,
@@ -2739,18 +2172,10 @@ class FedCore:
         # die with their last reference the moment this call returns.
         @functools.partial(jax.jit, donate_argnums=(0,))
         def finalize_fn(state: ServerState, acc):
-            new_params, new_opt, new_round, metrics = fin_shard(
+            *new, metrics = fin_shard(
                 state.params, state.opt_state, state.round_idx, acc
             )
-            return (
-                ServerState(
-                    params=new_params,
-                    opt_state=new_opt,
-                    round_idx=new_round,
-                    base_key=state.base_key,
-                ),
-                metrics,
-            )
+            return next_state(state, *new), metrics
 
         dpn = plan.dp
         acc_sh = jax.tree.map(
@@ -3101,8 +2526,8 @@ class FedCore:
     def _build_evaluate_personal_auto(self):
         """Ditto personal eval on a model-parallel mesh: same blocked
         weighted-mean computation as the manual builder below, in pure
-        GSPMD-auto land (the manual shard_map cannot compile at mp>1 —
-        see _build_round_step_auto)."""
+        GSPMD-auto land (why mp>1 takes it: the boundary choice in
+        _build_round_step)."""
         block = self.config.block_clients * self.plan.dp
         apply_fn = self.apply_fn
 

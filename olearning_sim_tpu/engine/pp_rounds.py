@@ -60,9 +60,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import optax
 from jax.sharding import PartitionSpec as P
+
+from olearning_sim_tpu.engine.round_stages import (
+    RoundMetrics,
+    ServerState,
+    _tree_l2_sq,
+    client_block,
+    next_state,
+    server_commit,
+)
 
 
 def validate_pp_build(model, plan, config, algorithm, microbatches):
@@ -119,13 +127,6 @@ def build_pp_round_step(core, model, microbatches):
     FedCore`; ``model`` — the dense-attention TextTransformer instance the
     core's apply/init functions wrap; ``microbatches`` — GPipe microbatch
     count M (None = pp)."""
-    from olearning_sim_tpu.engine.fedcore import (
-        RoundMetrics,
-        ServerState,
-        _accumulate_delta,
-        _finite_client_mask,
-        _tree_l2_sq,
-    )
     from olearning_sim_tpu.parallel.pipeline import (
         _PipelineGraph,
         stack_block_params,
@@ -244,30 +245,20 @@ def build_pp_round_step(core, model, microbatches):
         def block_step(carry, inp):
             sum_delta, sum_w, sum_loss, count = carry
             bx, by, bns, bst, buid, bw = inp
-            with jax.named_scope("client_train"):
-                deltas, losses = jax.vmap(
-                    local_train, in_axes=(0, 0, 0, 0, 0)
-                )(bx, by, bns, bst, buid)
             # Resilience gate: a diverged client contributes nothing
-            # (same helper as the dense program). The mask must agree
+            # (same stage as the dense program). The mask must agree
             # across pp stages — a non-finite value confined to ONE
             # stage's block slice would otherwise flip ok there only,
             # making sum_w/count/rest-deltas stage-divergent under the
             # replicated out_specs — so stages AND their verdicts.
-            ok = _finite_client_mask(losses, deltas)
-            ok = jax.lax.pmin(ok.astype(jnp.int32), "pp").astype(jnp.bool_)
-
-            def gate(d):
-                return jnp.where(
-                    ok.reshape((-1,) + (1,) * (d.ndim - 1)), d, 0.0
-                )
-
-            bw_eff = jnp.where(ok, bw, 0.0)
-            sum_delta = _accumulate_delta(sum_delta, deltas, bw_eff, gate)
-            sum_w = sum_w + bw_eff.sum()
-            sum_loss = sum_loss + jnp.where(ok, bw * losses, 0.0).sum()
-            count = count + (bw_eff > 0).sum().astype(jnp.float32)
-            return (sum_delta, sum_w, sum_loss, count), losses
+            blk = client_block(
+                local_train, (0, 0, 0, 0, 0), (bx, by, bns, bst, buid), bw,
+                agree=lambda ok: jax.lax.pmin(
+                    ok.astype(jnp.int32), "pp").astype(jnp.bool_),
+            )
+            sum_delta = blk.weighted_sum(sum_delta)
+            return ((sum_delta, *blk.tally(sum_w, sum_loss, count)),
+                    blk.losses)
 
         (sum_delta, sum_w, sum_loss, count), block_losses = jax.lax.scan(
             block_step, init, xs, unroll=min(cfg.block_unroll, nb)
@@ -314,13 +305,9 @@ def build_pp_round_step(core, model, microbatches):
         # the dp-only program's (the pipeline only changed WHERE the
         # per-client compute ran).
         with jax.named_scope("server_update"):
-            pseudo_grad = jax.tree.map(
-                lambda d, p: (-d).astype(p.dtype), mean_delta, state.params
-            )
-            updates, new_opt_state = alg.server_optimizer.update(
-                pseudo_grad, state.opt_state, state.params
-            )
-            new_params = optax.apply_updates(state.params, updates)
+            new_params, new_opt_state = server_commit(
+                alg.server_optimizer, state.params, state.opt_state,
+                mean_delta)
         metrics = RoundMetrics(
             mean_loss=sum_loss / denom,
             weight_sum=sum_w,
@@ -331,14 +318,7 @@ def build_pp_round_step(core, model, microbatches):
             anomaly_score=jnp.float32(0.0),
             clipped=jnp.float32(0.0),
         )
-        return (
-            ServerState(
-                params=new_params,
-                opt_state=new_opt_state,
-                round_idx=state.round_idx + 1,
-                base_key=state.base_key,
-            ),
-            metrics,
-        )
+        return (next_state(state, new_params, new_opt_state,
+                           state.round_idx + 1), metrics)
 
     return round_step

@@ -225,6 +225,46 @@ def test_ast_rules_clean_on_head():
     assert problems == [], "\n".join(problems)
 
 
+def test_the_client_block_stage_is_single_and_builders_import_stages():
+    """The finiteness gate is called from exactly one function of the
+    package (the block stage every round-program builder calls), and the
+    builders beside fedcore import the stages, never fedcore: builders ->
+    ``engine/round_stages.py``, one way."""
+    import ast
+
+    pkg = os.path.join(REPO, "olearning_sim_tpu")
+    callers, fedcore_imports = [], []
+
+    def calls(node, fn, rel):
+        """Every call of the gate under ``node``, by innermost function."""
+        for child in ast.iter_child_nodes(node):
+            inner = (child.name if isinstance(
+                child, (ast.FunctionDef, ast.AsyncFunctionDef)) else fn)
+            if isinstance(child, ast.Call) and getattr(
+                    child.func, "id", getattr(child.func, "attr", None)
+            ) == "_finite_client_mask":
+                callers.append(f"{rel}:{fn}")
+            calls(child, inner, rel)
+
+    for path in ast_rules._py_files(pkg):
+        rel = os.path.relpath(path, pkg)
+        tree = ast.parse(open(path, encoding="utf-8").read())
+        calls(tree, "<module>", rel)
+        if rel in ("engine/async_rounds.py", "engine/pp_rounds.py"):
+            for node in ast.walk(tree):
+                names = []
+                if isinstance(node, ast.Import):
+                    names = [a.name for a in node.names]
+                elif isinstance(node, ast.ImportFrom):
+                    names = [node.module or ""] + [
+                        f"{node.module}.{a.name}" for a in node.names]
+                fedcore_imports += [
+                    f"{rel}:{node.lineno}" for n in names
+                    if n.endswith("engine.fedcore")]
+    assert callers == ["engine/round_stages.py:client_block"], callers
+    assert fedcore_imports == [], fedcore_imports
+
+
 def test_ast_rules_wall_clock_rule():
     hits = ast_rules.lint_source(
         "import time\nnow = time.time()\n", "olearning_sim_tpu/x.py")

@@ -328,6 +328,31 @@ def test_single_buffer_constant_schedule_matches_sync(core, dataset):
                                    rtol=1e-5, atol=1e-6)
 
 
+def test_a_model_taken_one_client_at_a_time_runs_a_buffered_round():
+    """The tiny ``lfm2`` of tests/test_lfm2.py (``block_clients`` 1, no
+    ``vmap`` over clients, next-token task, one device): the buffered
+    program's block stage takes its clients one at a time, as the resident
+    program's does."""
+    from test_streaming import _lfm2_case
+
+    core, host, plan, n_real, _ = _lfm2_case()
+    ds = host.place(plan)
+    assert n_real == 4
+    released = np.array([True, True, False, True])
+    ap = plan_async_round(
+        AsyncConfig(buffer_size=2, schedule="polynomial"),
+        np.linspace(0.5, 2.0, 4).astype(np.float32), released,
+        ds.num_clients)
+    state, metrics, stats = core.round_step(
+        core.init_state(jax.random.key(0)), ds, async_plan=ap,
+        participate=jax.device_put(released.astype(np.float32),
+                                   plan.client_sharding()))
+    assert np.isfinite(float(metrics.mean_loss))
+    assert int(metrics.clients_trained) == int(released.sum()) == 3
+    assert int(stats.commits) == ap.num_windows == 2
+    assert int(state.round_idx) == 1
+
+
 def test_async_knobs_are_data_no_recompile(core, dataset):
     """Changing alpha / max_staleness / arrival order across rounds
     reuses the SAME compiled function with one trace (the lowered text is

@@ -267,3 +267,35 @@ def test_unroll_knobs_do_not_change_results():
         lambda a, b: np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6),
         pr, pu,
     )
+
+
+def test_the_launch_frame_keeps_frame_chunks_from_being_remapped():
+    """``FedCore._launch`` calls a program from ``_call_in_roomy_frame``: a
+    call pattern that crosses the end of a 16 KB chunk of frame memory again
+    and again (every depth is tried, so one of them does) maps and unmaps a
+    chunk, one page fault at least, at every crossing when called bare, and
+    takes none of those faults below the roomy frame, which has a chunk of
+    its own (``PERF.md`` section 7 item 8)."""
+    import resource
+
+    from olearning_sim_tpu.engine.fedcore import _call_in_roomy_frame
+
+    def leaf(a=0, b=0, c=0, d=0, e=0, f=0, g=0, h=0):
+        return a
+
+    def descend(depth):
+        if depth:
+            return descend(depth - 1)
+        for _ in range(50):
+            leaf()
+
+    def faults():
+        before = resource.getrusage(resource.RUSAGE_THREAD).ru_minflt
+        for depth in range(200):
+            descend(depth)
+        return resource.getrusage(resource.RUSAGE_THREAD).ru_minflt - before
+
+    faults()  # whatever the first pass touches for the first time
+    bare, roomy = faults(), _call_in_roomy_frame(faults)
+    assert roomy <= max(16, bare // 4), (bare, roomy)
+    assert _call_in_roomy_frame(lambda a, b: a - b, 3, 1) == 2

@@ -29,6 +29,7 @@ from olearning_sim_tpu.engine.client_data import (
     ClientDataset,
     HostClientStore,
     make_central_eval_set,
+    make_synthetic_text_dataset,
 )
 from olearning_sim_tpu.engine.defense import DefenseConfig
 from olearning_sim_tpu.engine.fedcore import FedCoreConfig
@@ -86,27 +87,60 @@ def _assert_states_bitwise(sa, sb):
     assert int(sa.round_idx) == int(sb.round_idx)
 
 
+def _lfm2_case():
+    """The tiny ``lfm2`` of tests/test_lfm2.py on one device: a model that
+    takes its clients one at a time (``block_clients`` 1, no ``vmap``), the
+    next-token task. (core, host dataset, plan, real clients, stream rows)"""
+    plan = make_mesh_plan(devices=jax.devices()[:1])
+    cfg = FedCoreConfig(batch_size=4, max_local_steps=2, block_clients=1,
+                        task="next_token")
+    core = build_fedcore(
+        "lfm2", fedavg(0.1), plan, cfg, input_shape=(16,),
+        model_overrides=dict(
+            vocab_size=128, max_len=16, width=64,
+            layer_types=["conv", "full_attention", "conv"],
+            num_dense_layers=1, heads=4, kv_heads=2, mlp_dim=96,
+            moe_mlp_dim=48, num_experts=16, experts_per_token=4,
+            held_experts=[0, 1]),
+    )
+    host = make_synthetic_text_dataset(
+        seed=5, num_clients=4, n_local=6, seq_len=16, num_classes=2,
+        vocab_size=128).pad_for(plan, 1)
+    return core, host, plan, 4, 2
+
+
 # ----------------------------------------------------- bitwise parity
-def test_streamed_bitwise_parity_plain(core, host_ds, placed_ds, plan):
+@pytest.mark.parametrize("model", ["mlp2", "lfm2"])
+def test_streamed_bitwise_parity_plain(model, request):
     """>=2 streamed blocks == the resident single program, bit for bit,
-    over multiple rounds (params, metrics, per-client losses)."""
+    over multiple rounds (params, metrics, per-client losses) — for a
+    vmapped block of clients and for a model whose clients the block stage
+    takes one at a time."""
+    if model == "lfm2":
+        core, host_ds, plan, n_real, stream_rows = _lfm2_case()
+        placed_ds = host_ds.place(plan)
+    else:
+        core, host_ds, placed_ds, plan = (
+            request.getfixturevalue(name)
+            for name in ("core", "host_ds", "placed_ds", "plan"))
+        n_real, stream_rows = NUM_CLIENTS, STREAM_ROWS
     sa = core.init_state(jax.random.key(0))
     sb = core.init_state(jax.random.key(0))
     store = HostClientStore.from_dataset(host_ds)
-    part = (np.random.default_rng(7).random(NUM_CLIENTS) < 0.8).astype(
+    part = (np.random.default_rng(7).random(n_real) < 0.8).astype(
         np.float32
     )
     part_pad = np.zeros(host_ds.num_clients, np.float32)
-    part_pad[:NUM_CLIENTS] = part
+    part_pad[:n_real] = part
     for _ in range(2):
         sa, ma = core.round_step(
             sa, placed_ds,
             participate=global_put(part_pad, plan.client_sharding()),
         )
         sb, mb, stats = core.stream_round(
-            sb, store, stream_rows=STREAM_ROWS, participate=part_pad
+            sb, store, stream_rows=stream_rows, participate=part_pad
         )
-        assert stats.blocks == host_ds.num_clients // STREAM_ROWS >= 2
+        assert stats.blocks == host_ds.num_clients // stream_rows >= 2
         _assert_states_bitwise(sa, sb)
         assert float(ma.mean_loss) == float(mb.mean_loss)
         assert float(ma.weight_sum) == float(mb.weight_sum)
