@@ -35,8 +35,9 @@ from __future__ import annotations
 import dataclasses
 import json
 import math
+import threading
 from datetime import datetime
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -59,6 +60,11 @@ class DispatchSchedule:
     timings: List[float]
     amounts: List[int]
     drop_lists: List[List[int]]
+    # Whether a ``specific_interval`` schedule's curve plan (see
+    # ``_curve_plan``) was found or built: one of the two is 1, both 0 for
+    # every other schedule. Bookkeeping, not part of the plan's value.
+    curve_plan_hits: int = dataclasses.field(default=0, compare=False)
+    curve_plan_builds: int = dataclasses.field(default=0, compare=False)
 
     @property
     def empty(self) -> bool:
@@ -218,9 +224,28 @@ def _specific_timing(
 
 
 # --------------------------------------------------------- specific_interval
-def _eval_rate(expression: str, t: float) -> float:
-    """Evaluate a user rate function at ``t`` in a restricted namespace."""
+def _eval_rate(expression, t: float) -> float:
+    """Evaluate a user rate function (its source, or the source compiled by
+    ``_compile_rate``) at ``t`` in a restricted namespace."""
     return float(eval(expression, {"__builtins__": {}}, {"math": math, "np": np, "t": t}))
+
+
+def _compile_rate(expression):
+    """The code ``eval`` would make of ``expression`` at every call (``eval``
+    strips a string's leading blanks, ``compile`` does not), and whether it
+    is a pure function of ``t``: ``np.random`` is the non-deterministic
+    thing the namespace reaches, so code that names ``random``, or a dunder
+    through which anything can be reached unnamed, is not."""
+    if isinstance(expression, str):
+        expression = expression.lstrip(" \t")
+    code = compile(expression, "<rate>", "eval")
+    names, todo = set(), [code]
+    while todo:
+        c = todo.pop()
+        names.update(c.co_names)
+        todo.extend(k for k in c.co_consts if hasattr(k, "co_names"))
+    pure = not any(n == "random" or n.startswith("__") for n in names)
+    return code, pure
 
 
 def _specific_interval(
@@ -297,52 +322,114 @@ def _specific_interval(
         amounts = list(sched.amounts[first:])
         drops = [list(d) for d in sched.drop_lists[first:]]
         timings[0] = float(cumulative[first])
-        return DispatchSchedule(timings, amounts, drops)
+        return dataclasses.replace(
+            sched, timings=timings, amounts=amounts, drop_lists=drops)
 
     return sched
 
 
-def _interval_schedule(
+class _CurvePlan(NamedTuple):
+    """What ``_interval_schedule`` needs of a strategy's rate curves: per
+    interval its slot seconds and the integer send count of each slot.
+    Tuples throughout: every round reads the one stored plan. Both empty
+    where the curves enclose no area."""
+
+    seconds: Tuple[Tuple[int, ...], ...]
+    sends: Tuple[Tuple[int, ...], ...]
+
+
+# Plans by the values they are a function of, most recently used last. A
+# task's relative strategy is one key for all its rounds; an absolute one
+# has a key per distinct interval list, so the bound is what keeps a long
+# schedule from growing the memo.
+CURVE_PLAN_LIMIT = 64
+_curve_plans: Dict[Any, _CurvePlan] = {}
+_curve_plans_lock = threading.Lock()
+
+
+def _curve_plan(
     total: int,
     intervals: Sequence[Sequence[int]],
     domains: Sequence[Sequence[float]],
     functions: Sequence[str],
-    drop_spec: Dict[str, Any],
-    rng: np.random.Generator,
-) -> DispatchSchedule:
-    """Reference ``_get_interval_params`` (``strategy.py:314-445``): rate
-    curves -> per-second areas -> integer send counts with residual carry."""
-    t_list: List[List[int]] = []
+) -> Tuple[_CurvePlan, bool]:
+    """The strategy's curve plan and whether it was found (True) or built.
+
+    The plan is a function of the four arguments' values and nothing else,
+    so it is kept under them (as tuples: the runner parses the strategy
+    anew every round) once built. Not kept: a plan whose expressions are
+    not pure functions of ``t`` (``_compile_rate``), values that do not
+    hash, and a build that raises, which raises again at the next call."""
+    try:
+        key = (total, tuple(tuple(i) for i in intervals),
+               tuple(tuple(d) for d in domains), tuple(functions))
+        with _curve_plans_lock:
+            plan = _curve_plans.get(key)
+            if plan is not None:
+                _curve_plans[key] = _curve_plans.pop(key)
+    except TypeError:
+        key = plan = None
+    if plan is not None:
+        return plan, True
+    plan, pure = _build_curve_plan(total, intervals, domains, functions)
+    if pure and key is not None:
+        with _curve_plans_lock:
+            _curve_plans[key] = plan
+            while len(_curve_plans) > CURVE_PLAN_LIMIT:
+                del _curve_plans[next(iter(_curve_plans))]
+    return plan, False
+
+
+def _build_curve_plan(
+    total: int,
+    intervals: Sequence[Sequence[int]],
+    domains: Sequence[Sequence[float]],
+    functions: Sequence[str],
+) -> Tuple[_CurvePlan, bool]:
+    """Reference ``_get_interval_params`` (``strategy.py:314-382``): rate
+    curves -> per-second areas -> integer send counts with residual carry.
+    Also returns whether every expression evaluated is pure.
+
+    An expression is compiled once, where its first point was evaluated
+    (so errors keep their order), and then evaluated point by point in
+    Python floats and summed in the reference's order: a send count
+    follows ``round()`` of sums of these areas, so an area that differed
+    in its last place could release another client."""
+    t_list: List[Tuple[int, ...]] = []
     area_list: List[List[float]] = []
+    all_pure = True
     for interval, domain, fn in zip(intervals, domains, functions):
         ilen = interval[1] - interval[0]
         dlen = domain[1] - domain[0]
         seconds = list(range(int(interval[0]), int(interval[1]) + 1))
         dom_pts = [domain[0] + dlen / ilen * (s - seconds[0]) for s in seconds]
         areas = []
+        if len(dom_pts) > 1:  # an interval with no slot evaluates nothing
+            code, pure = _compile_rate(fn)
+            all_pure = all_pure and pure
         for i in range(len(dom_pts) - 1):
             ts = np.linspace(dom_pts[i], dom_pts[i + 1], num=AREA_CALCULATION_NUM + 1)
-            ys = [_eval_rate(fn, float(t)) for t in ts]
+            ys = [_eval_rate(code, float(t)) for t in ts]
             area = 0.0
             for j in range(1, len(ys)):
                 seg = 0.5 * (ys[j] + ys[j - 1]) * (1.0 / AREA_CALCULATION_NUM)
                 if seg > 0:  # negative-rate segments send nothing
                     area += seg
             areas.append(area)
-        t_list.append(seconds[:-1])
+        t_list.append(tuple(seconds[:-1]))
         area_list.append(areas)
 
     totals = [sum(a) for a in area_list]
     grand = sum(totals)
     if grand <= 0:
-        return EMPTY_SCHEDULE
+        return _CurvePlan((), ()), all_pure
 
     # Split the grand total across intervals proportionally (last takes the
     # rounding remainder), then integerize each interval's per-second counts
     # with a residual-carry accumulator (reference ``strategy.py:361-382``).
     amount_per_interval = [round(t / grand * total) for t in totals]
     amount_per_interval[-1] = total - sum(amount_per_interval[:-1])
-    per_interval_sends: List[List[int]] = []
+    per_interval_sends: List[Tuple[int, ...]] = []
     for k, areas in enumerate(area_list):
         target = amount_per_interval[k]
         ideal = [a / totals[k] * target for a in areas]
@@ -355,7 +442,26 @@ def _interval_schedule(
             else:
                 sends.append(0)
                 carry = acc
-        per_interval_sends.append(sends)
+        per_interval_sends.append(tuple(sends))
+    return _CurvePlan(tuple(t_list), tuple(per_interval_sends)), all_pure
+
+
+def _interval_schedule(
+    total: int,
+    intervals: Sequence[Sequence[int]],
+    domains: Sequence[Sequence[float]],
+    functions: Sequence[str],
+    drop_spec: Dict[str, Any],
+    rng: np.random.Generator,
+) -> DispatchSchedule:
+    """Reference ``_get_interval_params`` (``strategy.py:314-445``): the
+    strategy's curve plan, found or built (it draws nothing), then this
+    call's drop draws over it, into lists of this call's own."""
+    (t_list, per_interval_sends), found = _curve_plan(
+        total, intervals, domains, functions)
+    counts = {"curve_plan_hits": int(found), "curve_plan_builds": int(not found)}
+    if not per_interval_sends:
+        return dataclasses.replace(EMPTY_SCHEDULE, **counts)
 
     # Expand interval-level drop specs to slot-level (reference
     # ``strategy.py:384-423``).
@@ -397,7 +503,7 @@ def _interval_schedule(
     drop_lists = _drop_lists(flat_amounts, drop_spec, rng) if drop_spec else [
         [] for _ in flat_amounts
     ]
-    return DispatchSchedule(timings, flat_amounts, drop_lists)
+    return DispatchSchedule(timings, flat_amounts, drop_lists, **counts)
 
 
 # ------------------------------------------------------------------- drops

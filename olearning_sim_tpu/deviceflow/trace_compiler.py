@@ -45,6 +45,11 @@ class ClientTrace:
     participate: np.ndarray  # [C] float32
     arrival_time: np.ndarray  # [C] float32, np.inf when never released
     dropped: np.ndarray  # [C] bool
+    # Whether the strategy's curve plan was found or built for this trace
+    # (``DispatchSchedule``'s counts; both 0 without a ``specific_interval``
+    # strategy). The runner puts them on its ``compile_trace`` span.
+    curve_plan_hits: int = 0
+    curve_plan_builds: int = 0
 
     @property
     def num_released(self) -> int:
@@ -130,7 +135,11 @@ def compile_trace(
 
     flow_id = f"{task_id}_{operator}_{round_idx}"
     sched = analyze_flow_strategy(strategy, flow_id, rng=rng, now=now)
-    return schedule_to_trace(sched, num_clients, rng)
+    return dataclasses.replace(
+        schedule_to_trace(sched, num_clients, rng),
+        curve_plan_hits=sched.curve_plan_hits,
+        curve_plan_builds=sched.curve_plan_builds,
+    )
 
 
 def schedule_to_trace(

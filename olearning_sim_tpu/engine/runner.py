@@ -700,7 +700,7 @@ class SimulationRunner:
                                       round_idx=round_idx)
             real = p.dataset.num_real_clients
             strace = None
-            with stage("compile_trace"):
+            with stage("compile_trace") as span:
                 # Compile over REAL clients only — released slots must
                 # never be spent on zero-weight padding clients (which
                 # would silently shrink effective participation).
@@ -715,6 +715,12 @@ class SimulationRunner:
                     operator=operator.name,
                     seed=self.trace_seed,
                 )
+                if span is not None:
+                    # Whether the strategy's rate curve was integrated in
+                    # this round (a build) or read from an earlier one.
+                    span.attrs.update(
+                        curve_plan_hits=trace.curve_plan_hits,
+                        curve_plan_builds=trace.curve_plan_builds)
                 if self.scenario is not None:
                     # Scenario availability (diurnal/charging/spike/churn)
                     # intersects the dispatch-strategy trace: a client

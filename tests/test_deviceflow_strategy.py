@@ -1,13 +1,20 @@
 """Schedule-synthesis semantics vs the reference grammar
 (``ols_core/deviceflow/non_grpc/strategy.py``)."""
 
+import copy
 import json
+import math
+import types
 from datetime import datetime
 
 import numpy as np
 import pytest
 
+import interval_schedule_oracle as oracle
+from interval_schedule_oracle import no_plans  # noqa: F401  (a fixture)
+from olearning_sim_tpu.deviceflow import strategy
 from olearning_sim_tpu.deviceflow.strategy import (
+    EMPTY_SCHEDULE,
     analyze_flow_strategy,
     analyze_real_time_strategy,
     is_real_time_dispatch,
@@ -236,3 +243,195 @@ def test_json_string_input():
     s = json.dumps(interval_spec([[0, 4]], [[0.0, 4.0]], ["1"], 8))
     sched = analyze_flow_strategy(s, "t_op_0", rng=RNG())
     assert sched.total_sent == 8
+
+
+# ------------------------------------------------- the kept curve plan
+def fresh(spec):
+    """The strategy as the runner hands it over: parsed anew every round."""
+    return json.loads(json.dumps(spec))
+
+
+def counts(sched):
+    return sched.curve_plan_hits, sched.curve_plan_builds
+
+
+@pytest.mark.parametrize("seed", oracle.SEEDS)
+@pytest.mark.parametrize("name", list(oracle.GRID))
+def test_kept_plan_gives_the_schedule_of_integrating_every_round(
+        name, seed, no_plans):
+    spec = oracle.GRID[name]
+    builds = 0
+    for round_idx in oracle.ROUNDS:
+        flow_id = f"task_train_{round_idx}"
+        rng = np.random.default_rng([seed, round_idx])
+        got = analyze_flow_strategy(fresh(spec), flow_id, rng=rng,
+                                    now=oracle.NOW)
+        want_rng = np.random.default_rng([seed, round_idx])
+        with oracle.as_before():
+            want = analyze_flow_strategy(fresh(spec), flow_id, rng=want_rng,
+                                         now=oracle.NOW)
+        assert got.timings == want.timings
+        assert got.amounts == want.amounts
+        assert got.drop_lists == want.drop_lists
+        assert [type(a) for a in got.amounts] == [int] * len(got.amounts)
+        # the plan drew nothing: the generator stands where it stood
+        assert rng.random() == want_rng.random()
+        assert sum(counts(got)) == 1 and counts(want) == (0, 0)
+        builds += got.curve_plan_builds
+    # one build a distinct interval list: 1 where relative, 3 and 2 in the
+    # two absolute strategies
+    assert builds == len(no_plans) == {
+        "absolute_one_interval": 3, "absolute_three_intervals": 2}.get(name, 1)
+
+
+def test_second_call_with_an_equal_strategy_evaluates_nothing(
+        no_plans, monkeypatch):
+    calls = []
+
+    def sin(x):
+        calls.append(x)
+        return math.sin(x)
+
+    monkeypatch.setattr(strategy, "math", types.SimpleNamespace(sin=sin))
+    spec = json.dumps(oracle.GRID["one_interval_drop_probability"])
+    first = analyze_flow_strategy(json.loads(spec), "t_op_0", rng=RNG())
+    assert counts(first) == (0, 1)
+    assert len(calls) == 20 * (strategy.AREA_CALCULATION_NUM + 1)
+    del calls[:]
+    # another parse, another round, another generator: the values are the key
+    second = analyze_flow_strategy(json.loads(spec), "t_op_1",
+                                   rng=np.random.default_rng(9))
+    assert counts(second) == (1, 0) and calls == []
+    assert second.amounts == first.amounts
+    assert second.drop_lists != first.drop_lists      # the draws are the round's
+    # any of the four values makes another plan
+    other = json.loads(spec)
+    other["flow_dispatch"]["total_dispatch_amount"] = 127
+    assert counts(analyze_flow_strategy(other, "t_op_0", rng=RNG())) == (0, 1)
+    assert len(calls) == 20 * (strategy.AREA_CALCULATION_NUM + 1)
+
+
+def test_a_callers_changes_to_a_schedule_stay_its_own(no_plans):
+    spec = oracle.GRID["three_intervals_drop_probability"]
+    kept = copy.deepcopy(analyze_flow_strategy(fresh(spec), "t_op_0", rng=RNG()))
+    mine = analyze_flow_strategy(fresh(spec), "t_op_0", rng=RNG())
+    assert counts(mine) == (1, 0)
+    mine.timings[:] = [99.0]
+    mine.amounts[:] = [0] * len(mine.amounts)
+    mine.drop_lists[0].append(-1)
+    del mine.drop_lists[1:]
+    again = analyze_flow_strategy(fresh(spec), "t_op_0", rng=RNG())
+    assert counts(again) == (1, 0)
+    assert (again.timings, again.amounts, again.drop_lists) == (
+        kept.timings, kept.amounts, kept.drop_lists)
+
+
+@pytest.mark.parametrize("expression, pure", [
+    ("math.sin(t/6.0)+1.1", True),
+    ("np.abs(np.cos(t))", True),
+    ("(lambda x: x*x)(t)", True),
+    ("np.random.random()", False),
+    ("1+np.random.default_rng(0).random()", False),
+    ("(lambda: np.random.random())()", False),
+    ("[np.random.random() for _ in (1,)][0]", False),
+    ("np.__dict__['ran'+'dom'].random()", False),
+])
+def test_an_expression_that_names_random_or_a_dunder_is_not_pure(
+        expression, pure):
+    assert strategy._compile_rate(expression)[1] is pure
+
+
+def test_an_expression_naming_random_is_built_every_call(no_plans):
+    spec = oracle.interval_strategy(
+        [[0, 4]], [[0.0, 4.0]], ["1+np.random.random()"], 40)
+    for round_idx in range(3):
+        sched = analyze_flow_strategy(fresh(spec), f"t_op_{round_idx}", rng=RNG())
+        assert counts(sched) == (0, 1) and sched.total_sent == 40
+    assert len(no_plans) == 0
+    # one such interval among pure ones keeps the whole plan out
+    spec = oracle.interval_strategy(
+        [[0, 4], [4, 8]], [[0.0, 4.0]] * 2, ["1", "1+np.random.random()"], 40)
+    for _ in range(2):
+        assert counts(analyze_flow_strategy(fresh(spec), "t_op_0", rng=RNG())) == (0, 1)
+    assert len(no_plans) == 0
+
+
+@pytest.mark.parametrize("expression", ["1/(t-t)", "math.sqrt(-1-t)", "t[0]", "{}[t]"])
+def test_an_error_that_gave_an_empty_schedule_still_does_every_call(
+        expression, no_plans):
+    spec = oracle.interval_strategy([[0, 4]], [[0.0, 4.0]], [expression], 40)
+    for _ in range(2):
+        got = analyze_flow_strategy(fresh(spec), "t_op_0", rng=RNG())
+        with oracle.as_before():
+            want = analyze_flow_strategy(fresh(spec), "t_op_0", rng=RNG())
+        assert got is EMPTY_SCHEDULE and want is EMPTY_SCHEDULE
+    assert len(no_plans) == 0
+
+
+@pytest.mark.parametrize("expression, error", [
+    ("undefined(t)", NameError),            # not one of the errors caught
+    ("abs(t)", NameError),                  # builtins are emptied
+    ("1 +", SyntaxError),
+    ("math.nope(t)", AttributeError),
+])
+def test_an_error_that_propagated_still_does_every_call(
+        expression, error, no_plans):
+    spec = oracle.interval_strategy([[0, 4]], [[0.0, 4.0]], [expression], 40)
+    for _ in range(2):
+        with pytest.raises(error):
+            analyze_flow_strategy(fresh(spec), "t_op_0", rng=RNG())
+        with oracle.as_before(), pytest.raises(error):
+            analyze_flow_strategy(fresh(spec), "t_op_0", rng=RNG())
+    assert len(no_plans) == 0
+
+
+def test_errors_keep_their_order_across_intervals(no_plans):
+    # The first interval's ZeroDivisionError is met before the second's
+    # expression is looked at, so its SyntaxError never shows; an interval
+    # with no slot evaluates nothing, so neither does its NameError.
+    for intervals, functions in (([[0, 4], [4, 8]], ["1/(t-t)", "1 +"]),
+                                 ([[4, 4], [4, 8]], ["1", "1 +"]),
+                                 ([[5, 4], [4, 8]], ["undefined", "1"])):
+        spec = oracle.interval_strategy(intervals, [[0.0, 4.0]] * 2, functions, 40)
+        got = analyze_flow_strategy(fresh(spec), "t_op_0", rng=RNG())
+        with oracle.as_before():
+            want = analyze_flow_strategy(fresh(spec), "t_op_0", rng=RNG())
+        assert (got.timings, got.amounts) == (want.timings, want.amounts)
+
+
+def test_values_that_do_not_hash_are_built_every_call(no_plans):
+    # Not JSON's to give, but a caller's dict may: numpy scalars in arrays.
+    args = (40, [[0, 4]], [[np.array(0.0), np.array(4.0)]], ["1"])
+    for _ in range(2):
+        sched = strategy._interval_schedule(*args, {}, RNG())
+        assert counts(sched) == (0, 1) and sched.amounts == [10] * 4
+    assert len(no_plans) == 0
+
+
+def test_the_memo_holds_at_most_its_bound(no_plans):
+    limit = strategy.CURVE_PLAN_LIMIT
+
+    def call(i):
+        # a long absolute schedule: another interval list every round
+        return strategy._interval_schedule(
+            10, [[0, 1 + i % 3]], [[0.0, 1.0 + i]], ["1"], {}, RNG())
+
+    for i in range(limit + 10):
+        assert counts(call(i)) == (0, 1)
+        assert len(no_plans) <= limit
+    assert len(no_plans) == limit
+    assert counts(call(limit + 9)) == (1, 0)          # the newest is kept
+    assert counts(call(10)) == (1, 0)                 # the oldest kept; now the newest
+    assert counts(call(9)) == (0, 1)                  # the one before it went
+    assert counts(call(11)) == (0, 1)                 # and 11 went for 9: least lately used
+    assert counts(call(10)) == (1, 0)
+    assert len(no_plans) == limit
+
+
+def test_schedules_without_a_curve_report_no_plan():
+    timing = flow({"total_dispatch_amount": 10, "specific_timing": {
+        "use": True, "time_type": "relative", "timings": [0], "amounts": [10]}})
+    assert counts(analyze_flow_strategy(timing, "t_op_0", rng=RNG())) == (0, 0)
+    assert counts(EMPTY_SCHEDULE) == (0, 0)
+    a = analyze_flow_strategy(oracle.GRID["zero_area"], "t_op_0", rng=RNG())
+    assert a.empty and a == EMPTY_SCHEDULE and sum(counts(a)) == 1

@@ -1,8 +1,13 @@
 """Trace compiler: schedules -> per-client masks, and engine integration."""
 
+import json
+
 import jax
 import numpy as np
+import pytest
 
+import interval_schedule_oracle as oracle
+from interval_schedule_oracle import no_plans  # noqa: F401  (a fixture)
 from olearning_sim_tpu.deviceflow import compile_trace
 from olearning_sim_tpu.engine import build_fedcore, fedavg, make_synthetic_dataset
 from olearning_sim_tpu.engine.fedcore import FedCoreConfig
@@ -147,3 +152,41 @@ def test_trace_drives_engine():
     participate = jax.device_put(tr.participate, plan.client_sharding())
     state, metrics = core.round_step(state, ds, participate=participate)
     assert int(metrics.clients_trained) == 40
+
+
+# ------------------------------------------------- the kept curve plan
+@pytest.mark.parametrize("seed", oracle.SEEDS)
+@pytest.mark.parametrize("name", list(oracle.GRID))
+def test_kept_plan_gives_the_trace_of_integrating_every_round(
+        name, seed, no_plans):
+    """The runner's path: the strategy parsed anew every round, the
+    generator ``compile_trace``'s own. The permutation is drawn after the
+    drops, so an equal trace says the generator stood where it stood."""
+    spec = json.dumps(oracle.GRID[name])
+    clients = 128
+    for round_idx in oracle.ROUNDS:
+        args = (clients, round_idx)
+        kw = dict(task_id="task", operator="train", seed=seed, now=oracle.NOW)
+        got = compile_trace(json.loads(spec), *args, **kw)
+        with oracle.as_before():
+            want = compile_trace(json.loads(spec), *args, **kw)
+        np.testing.assert_array_equal(got.participate, want.participate)
+        np.testing.assert_array_equal(got.arrival_time, want.arrival_time)
+        np.testing.assert_array_equal(got.dropped, want.dropped)
+        assert got.curve_plan_hits + got.curve_plan_builds == 1
+        assert (want.curve_plan_hits, want.curve_plan_builds) == (0, 0)
+    if name != "zero_area":
+        assert got.num_released + got.num_dropped == min(
+            clients, oracle.GRID[name]["flow_dispatch"]["total_dispatch_amount"])
+
+
+def test_trace_says_whether_the_rounds_plan_was_found_or_built(no_plans):
+    spec = json.dumps(oracle.GRID["one_interval_drop_probability"])
+    found = [(tr.curve_plan_hits, tr.curve_plan_builds) for tr in (
+        compile_trace(json.loads(spec), 128, r, seed=1) for r in range(4))]
+    assert found == [(0, 1), (1, 0), (1, 0), (1, 0)]
+    # nothing to integrate: no strategy, explicit timings, real-time dispatch
+    real_time = {"real_time_dispatch": {"use_strategy": True}}
+    for s in (None, flow_timing(50, [0], [50]), real_time):
+        tr = compile_trace(s, 128, 0, seed=1)
+        assert (tr.curve_plan_hits, tr.curve_plan_builds) == (0, 0)
