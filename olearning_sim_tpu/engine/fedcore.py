@@ -512,10 +512,11 @@ class FedCore:
 
         ``apply_stats_fn(params, x) -> (logits, stats)`` — optional forward
         that also returns the int32 work counts the model sows (a routed
-        expert layer's assignments); the resident dp-manual round program
-        sums them over the round into ``RoundMetrics.model_stats``, and
-        ``describe_stats(summed) -> {name: number}`` names them for the
-        runner's work counts. Mutually exclusive with ``apply_aux_fn``.
+        expert layer's assignments, a chunked scan's tokens and chunks);
+        the resident dp-manual round program sums them over the round into
+        ``RoundMetrics.model_stats``, and ``describe_stats(summed) ->
+        {name: number}`` names them for the runner's work counts. Mutually
+        exclusive with ``apply_aux_fn``.
 
         ``vmap_clients=False`` — the model cannot be ``vmap``ped over
         per-client weights (``ModelSpec.vmap_clients``: a grouped matmul
@@ -2736,6 +2737,7 @@ def build_fedcore(
     ``microbatches`` — GPipe microbatch count for a pipeline-parallel
     plan (``plan.pp > 1``; default pp). Rejected on non-pp plans."""
     from olearning_sim_tpu.models import get_model
+    from olearning_sim_tpu.models.registry import sown
 
     spec = get_model(model_name)
     model = spec.build(**(model_overrides or {}))
@@ -2771,11 +2773,6 @@ def build_fedcore(
         return model.apply(_variables(params, rows), x,
                            mutable=["intermediates"])
 
-    def _sown(inter, name):
-        flat = jax.tree_util.tree_flatten_with_path(inter)[0]
-        return [leaf for path, leaf in flat
-                if name in jax.tree_util.keystr(path)]
-
     apply_aux_fn = apply_stats_fn = describe_stats = lookup_tables = None
     shapes = None
     try:
@@ -2786,25 +2783,26 @@ def build_fedcore(
                 {"params": p}, x, mutable=["intermediates", "perturbations"]),
             shapes, dummy)
         inter_shapes = sown_shapes.get("intermediates", {})
-        has_aux = bool(_sown(inter_shapes, "aux_loss"))
-        has_stats = bool(_sown(inter_shapes, "moe_stats"))
+        has_aux = bool(sown(inter_shapes, "aux_loss"))
+        has_stats = (spec.work_counts is not None and jax.eval_shape(
+            spec.work_counts.gather, inter_shapes) is not None)
         lookup_tables = _marked_lookup_tables(
             shapes, sown_shapes.get("perturbations", {}), dummy)
     except Exception:  # noqa: BLE001 — aux detection must never block a build
         has_aux = has_stats = False
     if has_stats and not has_aux:
-        # A routed expert layer's work counts (models/moe.py DroplessMoE):
-        # one int32 vector a layer, stacked; the round program sums them.
-        from olearning_sim_tpu.models.moe import describe_stats
+        # The model's own work counts (``ModelSpec.work_counts``): one int32
+        # array a forward pass; the round program sums them.
+        describe_stats = spec.work_counts.describe
 
         def apply_stats_fn(params, x, rows=None):
             logits, inter = _apply_with_inter(params, x, rows)
-            return logits, jnp.stack(_sown(inter, "moe_stats"))
+            return logits, spec.work_counts.gather(inter)
     if has_aux:
 
         def apply_aux_fn(params, x, rows=None):
             logits, inter = _apply_with_inter(params, x, rows)
-            leaves = _sown(inter, "aux_loss")
+            leaves = sown(inter, "aux_loss")
             # MEAN over blocks, matching ep_train_step's aggregation, so the
             # same aux_loss_weight applies equal balancing pressure per
             # router in both training paths regardless of model depth.

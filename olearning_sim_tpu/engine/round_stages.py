@@ -70,7 +70,8 @@ class RoundMetrics(struct.PyTreeNode):
     anomaly_score: jnp.ndarray = struct.field(default_factory=lambda: jnp.float32(0.0))
     clipped: jnp.ndarray = struct.field(default_factory=lambda: jnp.float32(0.0))
     # Work counts the client model sows while it trains (``apply_stats_fn``:
-    # a routed expert layer's assignments and loads), int32, summed over
+    # a routed expert layer's assignments and loads, a chunked scan's tokens
+    # and chunks), int32, summed over
     # the round's active local steps of every computed client; scalar 0 for
     # a model that sows none and on every program but the resident
     # dp-manual one. ``FedCore.describe_stats`` names what is in it.

@@ -1,0 +1,462 @@
+"""Kimi-Linear decoder family (``model_type: kimi_linear``; sizes from the
+public ``moonshotai/Kimi-Linear-48B-A3B-Instruct`` config.json): a
+next-token language model of pre-norm residual blocks whose mixer is either
+a KDA layer (a gated delta-rule linear attention with a per-channel decay)
+or an MLA layer (latent attention, no rotary embedding), and whose
+feed-forward is a dense SwiGLU MLP in the leading dense layers and, in the
+others, a routed expert layer
+(:class:`~olearning_sim_tpu.models.moe.DroplessMoE`) plus one shared expert
+that every token passes.
+
+    h = embed(tokens)
+    for each layer:   h = h + mixer(rms(h));   h = h + ffn(rms(h))
+    logits = rms(h) @ head                       (untied: a leaf of its own)
+
+**KDA**, a head (``heads`` heads of ``kda_head_dim`` keys and values):
+``q~, k~, v~`` = three projections, each through its own causal depthwise
+convolution of ``conv_kernel`` taps and SiLU; ``q = l2norm(q~) / sqrt(d)``,
+``k = l2norm(k~)``; a per-channel log decay ``g = -exp(A_log) *
+softplus((x W_fa) W_fb + dt_bias)``; a per-head write strength ``beta =
+sigmoid(x W_b)``; the recurrence over a ``d x d`` state (keys x values)
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+and ``out = (rms_d(o) * sigmoid((x W_ga) W_gb)) W_o``. The recurrence is
+computed chunked (:func:`chunk_scan`).
+
+**MLA**: ``q = x W_q`` (``heads`` x (``qk_nope_dim`` + ``qk_rope_dim``));
+``[c, k_r] = x W_kva`` (``kv_rank`` + ``qk_rope_dim``; ``k_r`` is shared by
+all heads and, with no rotary embedding, used as it is); ``[k_n, v] =
+rms(c) W_kvb``; ``k = [k_n, k_r]``; causal softmax of ``q k^T / sqrt(qk
+width)``; ``out = concat(probs v) W_o``.
+
+What one chip of a deployment holds is a matter of the sizes given, as in
+``models/lfm2.py``: ``held_experts``, ``vocab_size`` (rows of the embedding
+and of the head held here) and ``layer_types`` (this pipeline stage's
+layers). Nothing here stands in for the other chips.
+
+Precision: float32 parameters; matmul inputs and outputs in ``dtype``
+(bfloat16); the residual stream, the norms, the router, the softmax and the
+logits in float32, and float32 for everything inside the recurrence that
+carries a decay: ``g``, its running sums, every exponential, the state, the
+triangular solve and the products with any of them (``Precision.HIGHEST``).
+
+The embedding is only looked up (:class:`~olearning_sim_tpu.models.lookup.
+LookupOnlyEmbed`), so a trainer may train it by the rows a step reads. Every
+KDA layer sows ``kda_stats`` (:data:`STATS`): the tokens and the chunks its
+scan took.
+"""
+
+from __future__ import annotations
+
+from typing import Sequence, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from olearning_sim_tpu.models.lfm2 import (
+    RMSNorm, SwiGLU, _attend, _dense_init, _mm)
+from olearning_sim_tpu.models.lookup import LookupOnlyEmbed
+from olearning_sim_tpu.models import moe
+from olearning_sim_tpu.models.registry import (
+    ModelSpec, WorkCounts, register_model, sown)
+
+# Tokens a chunk of the delta-rule scan holds: the intra-chunk system is
+# CHUNK x CHUNK, the scan over a sequence has L / CHUNK steps.
+CHUNK = 64
+L2_EPS = 1e-6
+# What a KDA layer sows as ``kda_stats`` on every call, one int32 vector.
+STATS = ("scan_tokens", "scan_chunks")
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _mm32(a, b):
+    return jnp.matmul(a, b, precision=_HIGHEST)
+
+
+@jax.checkpoint
+def _intra_chunk(q, k, G, beta):
+    """The decayed interactions inside one chunk, ``[..., C, K]`` each and
+    ``beta`` ``[..., C]``; ``G`` is the running sum of the log decay from
+    the chunk's start. Returns ``A[t, j] = beta_t sum_c k_t k_j exp(G_t -
+    G_j)`` for j < t and ``B[t, j] = sum_c q_t k_j exp(G_t - G_j)`` for j
+    <= t, zero elsewhere. The decay of a pair is one exponential of a
+    difference that is never positive: ``exp(G_t) * exp(-G_j)`` would
+    underflow one factor and overflow the other. The ``C x C x K`` array
+    lives here only (recomputed in the backward pass)."""
+    C = q.shape[-2]
+    t = np.arange(C)
+    lower = (t[:, None] >= t[None, :])[..., None]
+    decay = jnp.exp(jnp.where(
+        lower, G[..., :, None, :] - G[..., None, :, :], -jnp.inf))
+    kd = k[..., None, :, :] * decay                         # [.., t, j, K]
+    kk = (k[..., :, None, :] * kd).sum(-1)
+    strict = t[:, None] > t[None, :]
+    return (beta[..., None] * jnp.where(strict, kk, 0.0),
+            (q[..., :, None, :] * kd).sum(-1))
+
+
+@jax.checkpoint
+def _chunk_step(S, xs):
+    """One chunk against the state ``S`` ``[n, H, K, V]`` that enters it:
+    the writes ``U`` its tokens make, their outputs, and the state that
+    leaves it. Kept for the backward pass: ``S`` alone."""
+    w_v, w_k, q_in, B, k_out, decay_out = xs
+    U = w_v - _mm32(w_k, S)                                  # [n, H, C, V]
+    out = _mm32(q_in, S) + _mm32(B, U)
+    S = decay_out[..., None] * S + _mm32(jnp.swapaxes(k_out, -1, -2), U)
+    return S, out
+
+
+def chunk_scan(q, k, v, g, beta):
+    """The gated delta rule ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t))
+    S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T q_t`` from ``S_0 = 0``, in
+    chunks of :data:`CHUNK` tokens. ``q, k, g`` ``[n, L, H, K]``, ``v``
+    ``[n, L, H, V]``, ``beta`` ``[n, L, H]``, float32; ``g <= 0``. Returns
+    ``o`` ``[n, L, H, V]``.
+
+    With ``G`` the running sum of ``g`` inside a chunk, the writes ``u_t =
+    beta_t (v_t - S~_t^T k_t)`` of a chunk's tokens solve the unit
+    lower-triangular system ``(I + A) U = beta (V - (exp(G) K) S_in)``
+    (the WY / UT form with a diagonal decay; ``A``, ``B`` as
+    :func:`_intra_chunk` gives them), so ``U = W_v - W_k S_in`` with
+    ``[W_v, W_k] = (I + A)^-1 [beta V, beta exp(G) K]`` solved once for
+    all chunks, before the scan; then ``O = (exp(G) Q) S_in + B U`` and
+    ``S_out = Diag(exp(G_last)) S_in + (exp(G_last - G) K)^T U``, a
+    ``jax.lax.scan`` carrying ``S`` over the sequence's chunks. Every
+    exponent is a difference that is never positive. A sequence is padded
+    to whole chunks with tokens that write nothing and decay nothing."""
+    n, L, H, K = q.shape
+    N = -(-L // CHUNK)
+    pad = N * CHUNK - L
+
+    def chunks(x):          # [n, L, H, ...] -> [N, n, H, CHUNK, ...]
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape((n, N, CHUNK) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 3)
+
+    q, k, v, g, beta = (chunks(x.astype(jnp.float32))
+                        for x in (q, k, v, g, beta))
+    G = jnp.cumsum(g, axis=-2)
+    A, B = jax.lax.map(lambda xs: _intra_chunk(*xs), (q, k, G, beta))
+    decay_in = jnp.exp(G)
+    w = jax.lax.linalg.triangular_solve(
+        A, beta[..., None] * jnp.concatenate([v, decay_in * k], -1),
+        left_side=True, lower=True, unit_diagonal=True)
+    w_v, w_k = w[..., :v.shape[-1]], w[..., v.shape[-1]:]
+    G_last = G[..., -1:, :]
+    # A zero typed like the inputs (inside ``shard_map``, device-varying
+    # where they are: the carry that comes back is).
+    _, out = jax.lax.scan(
+        _chunk_step, jax.lax.full_like(v, 0, shape=(n, H, K, v.shape[-1])),
+        (w_v, w_k, q * decay_in, B, k * jnp.exp(G_last - G),
+         jnp.exp(G_last[..., 0, :])))
+    out = jnp.moveaxis(jnp.moveaxis(out, 2, 3), 0, 1)        # [n, N, C, H, V]
+    return out.reshape((n, N * CHUNK) + out.shape[3:])[:, :L]
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """log of uniform(1, 16): the family's modelling code."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """Inverse softplus of a step drawn log-uniformly from [0.001, 0.1]."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, dtype, np.log(0.001), np.log(0.1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def _causal_taps(x, taps):
+    """Causal depthwise convolution of ``x`` [n, L, D] (float32) with
+    ``taps`` [T, D]; tap j multiplies the input T-1-j positions back."""
+    T, L = taps.shape[0], x.shape[1]
+    x = jnp.pad(x, ((0, 0), (T - 1, 0), (0, 0)))
+    return sum(taps[j] * x[:, j:j + L] for j in range(T))
+
+
+def _l2norm(u):
+    return u * jax.lax.rsqrt((u * u).sum(-1, keepdims=True) + L2_EPS)
+
+
+def _scan_inputs(x, p, heads, dtype):
+    """Steps 1-4 of the KDA mixer: ``x`` [n, L, W] and the layer's leaves
+    -> ``q, k, v, g`` [n, L, H, D] and ``beta`` [n, L, H], float32."""
+    n, L, _ = x.shape
+    f32 = jnp.float32
+    q, k, v = (
+        jax.nn.silu(_causal_taps(
+            _mm(x, p[f"{r}_proj"], dtype).astype(f32), p[f"{r}_conv"])
+        ).reshape(n, L, heads, -1) for r in "qkv")
+    q, k = _l2norm(q) / np.sqrt(q.shape[-1]), _l2norm(k)
+    gate_in = _mm(_mm(x, p["f_a"], dtype), p["f_b"], dtype)
+    g = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(
+        gate_in.astype(f32) + p["dt_bias"]).reshape(n, L, heads, -1)
+    beta = jax.nn.sigmoid(_mm(x, p["b_proj"], dtype).astype(f32))
+    return q, k, v, g, beta
+
+
+def _gated_out(o, x, p, eps, dtype):
+    """Step 6: the scan's ``o`` [n, L, H, D] through the per-head RMSNorm,
+    the output gate and the output projection -> [n, L, W]."""
+    n, L, _ = x.shape
+    o = o * jax.lax.rsqrt(
+        jnp.mean(o * o, axis=-1, keepdims=True) + eps) * p["o_norm"]
+    gate = jax.nn.sigmoid(_mm(
+        _mm(x, p["g_a"], dtype), p["g_b"], dtype).astype(jnp.float32))
+    return _mm(o.reshape(n, L, -1) * gate, p["out_proj"], dtype)
+
+
+class KDA(nn.Module):
+    """The gated delta-rule mixer. Its three parts (what feeds the scan,
+    the scan, what follows it) are each computed again in the backward
+    pass from what entered them: a layer's float32 intermediates of all
+    three together are what a 16 GB chip has no room for beside a
+    client's training state."""
+
+    heads: int
+    head_dim: int = 128
+    conv_kernel: int = 4
+    eps: float = 1e-5
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        n, L, W = x.shape
+        H, D, T = self.heads, self.head_dim, self.conv_kernel
+        f32 = jnp.float32
+        p = {f"{r}_proj": self.param(f"{r}_proj", _dense_init, (W, H * D),
+                                     f32) for r in "qkv"}
+        p.update({f"{r}_conv": self.param(
+            f"{r}_conv", nn.initializers.lecun_normal(), (T, H * D), f32)
+            for r in "qkv"})
+        for name, shape in (("f_a", (W, D)), ("f_b", (D, H * D)),
+                            ("b_proj", (W, H)), ("g_a", (W, D)),
+                            ("g_b", (D, H * D)), ("out_proj", (H * D, W))):
+            p[name] = self.param(name, _dense_init, shape, f32)
+        p["A_log"] = self.param("A_log", _a_log_init, (H,), f32)
+        p["dt_bias"] = self.param("dt_bias", _dt_bias_init, (H * D,), f32)
+        p["o_norm"] = self.param("o_norm", nn.initializers.ones, (D,), f32)
+
+        with jax.named_scope("kda.projections"):
+            q, k, v, g, beta = jax.checkpoint(
+                _scan_inputs, static_argnums=(2, 3))(x, p, H, self.dtype)
+        with jax.named_scope("kda.chunk_scan"):
+            o = jax.checkpoint(chunk_scan)(q, k, v, g, beta)
+        with jax.named_scope("kda.projections"):
+            y = jax.checkpoint(_gated_out, static_argnums=(3, 4))(
+                o, x, p, self.eps, self.dtype)
+        self.sow("intermediates", "kda_stats",
+                 jnp.asarray([n * L, n * -(-L // CHUNK)], jnp.int32))
+        return y
+
+
+class MLA(nn.Module):
+    """Latent attention: keys and values expanded from one normed
+    ``kv_rank``-wide latent, no rotary embedding."""
+
+    heads: int
+    kv_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_dim: int = 128
+    eps: float = 1e-5
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        n, L, W = x.shape
+        H, R = self.heads, self.kv_rank
+        Dn, Dr, Dv = self.qk_nope_dim, self.qk_rope_dim, self.v_dim
+        f32 = jnp.float32
+        q_proj = self.param("q_proj", _dense_init, (W, H * (Dn + Dr)), f32)
+        kv_a = self.param("kv_a", _dense_init, (W, R + Dr), f32)
+        kv_b = self.param("kv_b", _dense_init, (R, H * (Dn + Dv)), f32)
+        out_proj = self.param("out_proj", _dense_init, (H * Dv, W), f32)
+        kv_norm = RMSNorm(self.eps, name="kv_norm")
+        with jax.named_scope("mla.attention"):
+            q = _mm(x, q_proj, self.dtype).reshape(n, L, H, 1, Dn + Dr)
+            c, k_r = jnp.split(_mm(x, kv_a, self.dtype), [R], axis=-1)
+            k_n, v = jnp.split(
+                _mm(kv_norm(c), kv_b, self.dtype).reshape(n, L, H, Dn + Dv),
+                [Dn], axis=-1)
+            k = jnp.concatenate(
+                [k_n, jnp.broadcast_to(k_r[:, :, None], (n, L, H, Dr))], -1)
+            # One query head a key/value head; the scores are recomputed
+            # in the backward pass.
+            ctx = _attend(q, k, v)
+            return _mm(ctx.reshape(n, L, H * Dv), out_proj, self.dtype)
+
+
+class Block(nn.Module):
+    """One decoder layer: the mixer of ``kind`` (``"kda"`` or ``"mla"``),
+    then the dense MLP (``num_experts`` 0) or the routed experts plus the
+    shared ones, each on the RMS-normed residual stream."""
+
+    kind: str
+    heads: int
+    kda_head_dim: int
+    conv_kernel: int
+    kv_rank: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_dim: int
+    mlp_dim: int
+    num_experts: int
+    experts_per_token: int
+    held_experts: Tuple[int, ...]
+    num_shared_experts: int
+    norm_eps: float
+    norm_topk_prob: bool
+    routed_scaling_factor: float
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, h):
+        x = RMSNorm(self.norm_eps, name="operator_norm")(h)
+        if self.kind == "kda":
+            y = KDA(self.heads, self.kda_head_dim, self.conv_kernel,
+                    self.norm_eps, self.dtype, name="kda")(x)
+        elif self.kind == "mla":
+            y = MLA(self.heads, self.kv_rank, self.qk_nope_dim,
+                    self.qk_rope_dim, self.v_dim, self.norm_eps, self.dtype,
+                    name="mla")(x)
+        else:
+            raise ValueError(f"unknown layer type {self.kind!r}")
+        h = h + y.astype(jnp.float32)
+        x = RMSNorm(self.norm_eps, name="ffn_norm")(h)
+        if self.num_experts == 0:
+            return h + SwiGLU(self.mlp_dim, self.dtype, name="mlp")(
+                x).astype(jnp.float32)
+        y = moe.DroplessMoE(
+            self.num_experts, self.experts_per_token, self.held_experts,
+            self.mlp_dim, self.norm_topk_prob, self.routed_scaling_factor,
+            dtype=self.dtype, name="moe")(x).astype(jnp.float32)
+        if self.num_shared_experts:
+            # Every token, weight 1: what every chip computes alike.
+            with jax.named_scope("moe.shared_expert"):
+                y = y + SwiGLU(self.num_shared_experts * self.mlp_dim,
+                               self.dtype, name="shared")(
+                                   x).astype(jnp.float32)
+        return h + y
+
+
+class KimiLinear(nn.Module):
+    vocab_size: int = 163840
+    max_len: int = 1048576          # positions served; no position table
+    width: int = 2304
+    layer_types: Sequence[str] = ("kda", "kda", "kda", "mla")
+    num_dense_layers: int = 1       # leading layers with the dense MLP
+    heads: int = 32
+    kda_head_dim: int = 128
+    conv_kernel: int = 4
+    kv_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_dim: int = 128
+    mlp_dim: int = 9216
+    moe_mlp_dim: int = 1024
+    num_experts: int = 256          # the router's width
+    experts_per_token: int = 8
+    held_experts: Sequence[int] = tuple(range(256))
+    num_shared_experts: int = 1
+    norm_eps: float = 1e-5
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.446
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, tokens):
+        if tokens.shape[-1] > self.max_len:
+            raise ValueError(
+                f"sequence of {tokens.shape[-1]} tokens, max_len is "
+                f"{self.max_len}")
+        h = LookupOnlyEmbed(
+            self.vocab_size, self.width, name="embed",
+            embedding_init=nn.initializers.normal(stddev=0.02),
+            param_dtype=jnp.float32)(tokens)
+        # The backward pass keeps the residual stream between the blocks
+        # and computes a block again for everything inside it.
+        block = nn.remat(Block)
+        for i, kind in enumerate(self.layer_types):
+            dense = i < self.num_dense_layers
+            h = block(
+                kind=kind, heads=self.heads, kda_head_dim=self.kda_head_dim,
+                conv_kernel=self.conv_kernel, kv_rank=self.kv_rank,
+                qk_nope_dim=self.qk_nope_dim, qk_rope_dim=self.qk_rope_dim,
+                v_dim=self.v_dim,
+                mlp_dim=self.mlp_dim if dense else self.moe_mlp_dim,
+                # No experts: the dense MLP.
+                num_experts=0 if dense else self.num_experts,
+                experts_per_token=self.experts_per_token,
+                held_experts=tuple(self.held_experts),
+                num_shared_experts=self.num_shared_experts,
+                norm_eps=self.norm_eps, norm_topk_prob=self.norm_topk_prob,
+                routed_scaling_factor=self.routed_scaling_factor,
+                dtype=self.dtype, name=f"layers_{i}")(h)
+        h = RMSNorm(self.norm_eps, name="final_norm")(h)
+        head = self.param("head", _dense_init,
+                          (self.width, self.vocab_size), jnp.float32)
+        return jnp.dot(h.astype(self.dtype), head.astype(self.dtype),
+                       preferred_element_type=jnp.float32)
+
+
+def describe_stats(stats: np.ndarray) -> dict:
+    """Work counts from the ``kda_stats`` of a model's KDA layers summed
+    over some stretch of work (``[layers, 2]``): the tokens that went
+    through the scan and the chunk bodies it executed forward."""
+    tokens, chunks = np.asarray(stats, np.int64).reshape(
+        -1, len(STATS)).sum(0).tolist()
+    return {"kda_scan_tokens": tokens, "kda_scan_chunks": chunks}
+
+
+def _gather_counts(intermediates):
+    """One array of a forward pass's counts: the expert layers' ``moe_stats``
+    a row each, then one row that starts with the KDA layers' ``kda_stats``
+    summed (the names need no more) and is zero after them."""
+    scans = sown(intermediates, "kda_stats")
+    if not scans:
+        return None
+    scan = sum(scans)
+    experts = moe.gather_stats(intermediates)
+    if experts is None:
+        return scan[None]
+    return jnp.concatenate([experts, jnp.pad(
+        scan, (0, experts.shape[1] - len(STATS)))[None]])
+
+
+def _describe_counts(counts: np.ndarray) -> dict:
+    named = describe_stats(counts[-1, :len(STATS)])
+    if len(counts) > 1:
+        named.update(moe.describe_stats(counts[:-1]))
+    return named
+
+
+register_model(
+    ModelSpec(
+        name="kimi_linear",
+        builder=KimiLinear,
+        example_input_shape=(64,),
+        # A language model: its "classes" are its vocabulary.
+        num_classes=163840,
+        input_dtype=np.int32,
+        # DroplessMoE's jax.lax.ragged_dot has no batching rule for
+        # per-client expert weights.
+        vmap_clients=False,
+        work_counts=WorkCounts(_gather_counts, _describe_counts),
+        defaults={
+            "vocab_size": 163840, "max_len": 1048576, "width": 2304,
+            "layer_types": ["kda", "kda", "kda", "mla"],
+            "num_dense_layers": 1, "heads": 32, "kda_head_dim": 128,
+            "conv_kernel": 4, "kv_rank": 512, "qk_nope_dim": 128,
+            "qk_rope_dim": 64, "v_dim": 128, "mlp_dim": 9216,
+            "moe_mlp_dim": 1024, "num_experts": 256,
+            "experts_per_token": 8, "held_experts": list(range(256)),
+            "num_shared_experts": 1, "norm_eps": 1e-5,
+            "routed_scaling_factor": 2.446,
+        },
+    )
+)

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from olearning_sim_tpu.models.moe import DroplessMoE
+from olearning_sim_tpu.models.moe import WORK_COUNTS, DroplessMoE
 from olearning_sim_tpu.models.registry import ModelSpec, register_model
 
 _dense_init = nn.initializers.lecun_normal()
@@ -260,6 +260,7 @@ register_model(
         # DroplessMoE's jax.lax.ragged_dot has no batching rule for
         # per-client expert weights.
         vmap_clients=False,
+        work_counts=WORK_COUNTS,
         defaults={
             "vocab_size": 65536, "max_len": 128000, "width": 2048,
             "layer_types": ["conv", "conv", "full_attention", "conv"],

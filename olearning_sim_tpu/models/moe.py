@@ -2,8 +2,9 @@
 
 Two expert layers live here, and they share nothing:
 
-- :class:`DroplessMoE` — the routed expert layer of the ``lfm2`` family
-  (``models/lfm2.py``): a sigmoid router over the model's PUBLISHED number
+- :class:`DroplessMoE` — the routed expert layer of the ``lfm2`` and
+  ``kimi_linear`` families (``models/lfm2.py``, ``models/kimi_linear.py``,
+  which adds a shared expert beside it): a sigmoid router over the model's PUBLISHED number
   of experts, top-k selection steered by a bias that never enters the
   weights, SwiGLU experts, and no capacity: every (token, slot) assignment
   to an expert this layer holds is computed, whatever the imbalance. The
@@ -33,7 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from olearning_sim_tpu.models.registry import ModelSpec, register_model
+from olearning_sim_tpu.models.registry import (
+    ModelSpec, WorkCounts, register_model, sown)
 
 
 # What a DroplessMoE layer sows as ``moe_stats`` on every call, one int32
@@ -103,11 +105,18 @@ class DroplessMoE(nn.Module):
     that takes no gradient (so local training, aggregation and the server
     step leave it as it is); the published rule that updates it from the
     experts' load is not in the model's config and is not implemented. It
-    is seeded normal(``BIAS_INIT_SCALE``): 0.01 changes some selections of
-    a 64-wide router (the most loaded of 8 held experts gets 1.4 times the
-    mean load for 1.25 with no bias) and keeps the share of assignments
-    that land on them within 2% from seed to seed; at 0.05 that share
-    swung 8% and the round time with it (PERF.md section 6, PR 28).
+    is seeded normal(``BIAS_INIT_SCALE``). What that scale does is a
+    configuration's measurement, not a fact of the layer (PERF.md section
+    6). ``lfm2_moe_ep8`` (a 64-wide router, 4 slots, 8 held; PR 28): 0.01
+    changes some selections, the most loaded held expert gets 1.4 times
+    the mean load for 1.25 with no bias, and the share of assignments that
+    land on the held experts stays within 2% from seed to seed; at 0.05
+    that share swung 8% and the round time with it. ``kimi_linear_ep32``
+    (a 256-wide router, 8 slots, 8 held; PR 34): at 0.01 the 8 held
+    experts get 2.8-3.4% of a round's assignments (1/32 is 3.1%) and the
+    most loaded of them 3.8-6.4 times the mean load (seeded weights: a few
+    of 256 experts draw most tokens); the round time follows neither (the
+    layer's arrays are sized by slots, not by load: 0.12% over six seeds).
     """
 
     num_experts: int
@@ -208,6 +217,16 @@ def describe_stats(stats: np.ndarray) -> dict:
         "moe_expert_load_max": int(loads.max()),
         "moe_expert_load_mean": float(loads.mean()),
     }
+
+
+def gather_stats(intermediates):
+    """The ``moe_stats`` a forward pass sowed, one row an expert layer."""
+    found = sown(intermediates, "moe_stats")
+    return jnp.stack(found) if found else None
+
+
+# For the ``ModelSpec`` of a model whose only counted layers are these.
+WORK_COUNTS = WorkCounts(gather_stats, describe_stats)
 
 
 class SwitchFFN(nn.Module):

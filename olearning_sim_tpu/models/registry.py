@@ -12,10 +12,31 @@ construct it without shipping code archives.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import flax.linen as nn
+import jax
 import numpy as np
+
+
+def sown(intermediates, name: str) -> list:
+    """The leaves a model's layers sowed under ``name`` in a forward pass's
+    ``intermediates`` collection, in layer order."""
+    flat = jax.tree_util.tree_flatten_with_path(intermediates)[0]
+    return [leaf for path, leaf in flat
+            if name in jax.tree_util.keystr(path)]
+
+
+@dataclasses.dataclass(frozen=True)
+class WorkCounts:
+    """How a model counts its own work: ``gather(intermediates)`` makes one
+    int32 array of what its layers sowed in a forward pass (None where they
+    sowed nothing), and ``describe(summed)`` names such an array summed over
+    some stretch of work, ``{name: number}``. The form of the array is the
+    model's; a trainer only sums it."""
+
+    gather: Callable[[Any], Any]
+    describe: Callable[[np.ndarray], Dict[str, Any]]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,6 +53,8 @@ class ModelSpec:
     # weights (a grouped matmul batches over a leading axis only): the
     # round engine then takes clients one at a time (``block_clients`` 1).
     vmap_clients: bool = True
+    # Set where the model's layers sow counts of their own work.
+    work_counts: Optional[WorkCounts] = None
 
     def build(self, **overrides) -> nn.Module:
         kwargs = dict(self.defaults)
@@ -54,7 +77,8 @@ def get_model(name: str) -> ModelSpec:
     import importlib
     import importlib.util
 
-    for mod in ("mlp", "cnn", "resnet", "transformer", "vit", "moe", "lfm2"):
+    for mod in ("mlp", "cnn", "resnet", "transformer", "vit", "moe", "lfm2",
+                "kimi_linear"):
         qual = f"olearning_sim_tpu.models.{mod}"
         # Only true absence is optional; a present-but-broken module raises.
         if importlib.util.find_spec(qual) is not None:

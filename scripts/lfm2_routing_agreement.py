@@ -2,14 +2,17 @@
 same experts, at the benchmark cell's sizes, on the chip.
 
     python scripts/lfm2_routing_agreement.py [--seed N] [--sequences 8]
+        [--workload lfm2_moe_ep8.8_silo_1k]
 
-Builds ``lfm2_moe_ep8``'s model from its configuration file with seeded
+Builds the model of ``--workload``'s configuration (``lfm2_moe_ep8``'s, or
+another whose expert layers are ``DroplessMoE`` and whose reference has
+``chosen_experts``) from its configuration file with seeded
 weights, runs one forward pass of the program's model (bfloat16 matmul
 inputs, the router in float32) over ``--sequences`` sequences of the
 configuration's generator, and the reference's forward pass
-(``benchmark/reference/lfm2_moe.py``, float32 at ``highest``) over the same
+(``benchmark/reference/<model>.py``, float32 at ``highest``) over the same
 sequences one at a time, and prints the share of (token, slot) choices on
-which the two agree, a layer and overall: a top-4 choice can flip where two
+which the two agree, a layer and overall: a top-k choice can flip where two
 scores nearly tie, because the layers before the router feed it activations
 that differ in the last bfloat16 bits. Not part of a benchmark run; PERF.md
 section 2 quotes its reading beside the check's limits. Refuses the CPU.
@@ -36,12 +39,13 @@ def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--seed", type=int, default=2**31 + 28)
     parser.add_argument("--sequences", type=int, default=8)
+    parser.add_argument("--workload", default="lfm2_moe_ep8.8_silo_1k")
     args = parser.parse_args(argv)
     if jax.devices()[0].platform != "tpu":
         print("no TPU: a routing agreement share is a chip reading",
               file=sys.stderr)
         return 1
-    cell = manifest.load_cell("lfm2_moe_ep8.8_silo_1k")
+    cell = manifest.load_cell(args.workload)
     params = manifest.engine_params(manifest.compose_task(cell, args.seed))
     model_cfg, syn = params["model"], params["data"]["synthetic"]
     model = get_model(model_cfg["name"]).build(**model_cfg["overrides"])
@@ -67,7 +71,8 @@ def main(argv=None) -> int:
         same = (program[:, i, :, :, None] == want[:, :, None, :]).any(-1)
         agree[:, i] = same.mean((-1, -2))
     print(json.dumps({
-        "seed": args.seed, "tokens": int(tokens.size),
+        "workload": args.workload, "seed": args.seed,
+        "tokens": int(tokens.size),
         "agreement_by_layer": agree.mean(1).tolist(),
         "agreement": float(agree.mean())}))
     return 0
