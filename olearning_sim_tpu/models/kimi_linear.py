@@ -23,7 +23,10 @@ sigmoid(x W_b)``; the recurrence over a ``d x d`` state (keys x values)
     o_t = S_t^T q_t
 
 and ``out = (rms_d(o) * sigmoid((x W_ga) W_gb)) W_o``. The recurrence is
-computed chunked (:func:`chunk_scan`).
+computed chunked (:func:`chunk_scan`): a chunk of :data:`CHUNK` tokens is
+one unit lower-triangular system, built and inverted by sub-blocks of
+:data:`SUB` tokens, pairwise decays and substitution inside a sub-block and
+matrix products between them.
 
 **MLA**: ``q = x W_q`` (``heads`` x (``qk_nope_dim`` + ``qk_rope_dim``));
 ``[c, k_r] = x W_kva`` (``kv_rank`` + ``qk_rope_dim``; ``k_r`` is shared by
@@ -40,7 +43,8 @@ Precision: float32 parameters; matmul inputs and outputs in ``dtype``
 (bfloat16); the residual stream, the norms, the router, the softmax and the
 logits in float32, and float32 for everything inside the recurrence that
 carries a decay: ``g``, its running sums, every exponential, the state, the
-triangular solve and the products with any of them (``Precision.HIGHEST``).
+inverse of a chunk's triangular system and the products with any of them
+(``Precision.HIGHEST``).
 
 The embedding is only looked up (:class:`~olearning_sim_tpu.models.lookup.
 LookupOnlyEmbed`), so a trainer may train it by the rows a step reads. Every
@@ -50,6 +54,7 @@ scan took.
 
 from __future__ import annotations
 
+import functools
 from typing import Sequence, Tuple
 
 import flax.linen as nn
@@ -67,6 +72,9 @@ from olearning_sim_tpu.models.registry import (
 # Tokens a chunk of the delta-rule scan holds: the intra-chunk system is
 # CHUNK x CHUNK, the scan over a sequence has L / CHUNK steps.
 CHUNK = 64
+# Tokens a sub-block of a chunk holds (CHUNK is a multiple): pairwise decays
+# and row-by-row substitution inside one, matrix products between them.
+SUB = 16
 L2_EPS = 1e-6
 # What a KDA layer sows as ``kda_stats`` on every call, one int32 vector.
 STATS = ("scan_tokens", "scan_chunks")
@@ -81,22 +89,97 @@ def _mm32(a, b):
 def _intra_chunk(q, k, G, beta):
     """The decayed interactions inside one chunk, ``[..., C, K]`` each and
     ``beta`` ``[..., C]``; ``G`` is the running sum of the log decay from
-    the chunk's start. Returns ``A[t, j] = beta_t sum_c k_t k_j exp(G_t -
-    G_j)`` for j < t and ``B[t, j] = sum_c q_t k_j exp(G_t - G_j)`` for j
-    <= t, zero elsewhere. The decay of a pair is one exponential of a
-    difference that is never positive: ``exp(G_t) * exp(-G_j)`` would
-    underflow one factor and overflow the other. The ``C x C x K`` array
-    lives here only (recomputed in the backward pass)."""
+    the chunk's start. ``A[t, j] = beta_t sum_c k_t k_j exp(G_t - G_j)``
+    for j < t and ``B[t, j] = sum_c q_t k_j exp(G_t - G_j)`` for j <= t,
+    zero elsewhere, by sub-blocks of :data:`SUB` tokens. Returns two
+    tuples, ``A``'s blocks and ``B``'s: first the ``C / SUB`` blocks on the
+    diagonal, then, for each size ``SUB, 2 SUB, .. C / 2``, the block of
+    every later half against its earlier half (``[..., C / 2 size, size,
+    size]``): what :func:`_merged` puts together.
+
+    Inside a sub-block the decay of a pair is one exponential of a
+    difference that is never positive (``exp(G_t) * exp(-G_j)`` would
+    underflow one factor and overflow the other), and the sum over the
+    channels a masked reduction of a ``SUB x SUB x K`` array. Between a
+    later block, whose first token is ``r``, and the block before it,
+    ``exp(G_t - G_j) = exp(G_t - G_r) * exp(G_r - G_j)``: ``j < r <= t``
+    and ``G`` never rises, so neither exponent is positive, nothing
+    overflows, and a factor underflows only where the product lies below
+    float32's smallest number. Those blocks are therefore matrix products,
+    ``(beta k * exp(G - G_r)) (k * exp(G_r - G))^T`` and the same with
+    ``q`` on the left. The largest array is the pairwise decays', ``C x SUB
+    x K``, and lives here only (recomputed in the backward pass)."""
     C = q.shape[-2]
-    t = np.arange(C)
-    lower = (t[:, None] >= t[None, :])[..., None]
+
+    def blocks(x, size):            # [.., C, K] -> [.., C / size, size, K]
+        return x.reshape(x.shape[:-2] + (C // size, size, x.shape[-1]))
+
+    bk = beta[..., None] * k
+    kb, Gb = blocks(k, SUB), blocks(G, SUB)
+    s = np.arange(SUB)
+    lower = (s[:, None] >= s[None, :])[..., None]
     decay = jnp.exp(jnp.where(
-        lower, G[..., :, None, :] - G[..., None, :, :], -jnp.inf))
-    kd = k[..., None, :, :] * decay                         # [.., t, j, K]
-    kk = (k[..., :, None, :] * kd).sum(-1)
-    strict = t[:, None] > t[None, :]
-    return (beta[..., None] * jnp.where(strict, kk, 0.0),
-            (q[..., :, None, :] * kd).sum(-1))
+        lower, Gb[..., :, None, :] - Gb[..., None, :, :], -jnp.inf))
+    kd = kb[..., None, :, :] * decay                    # [.., t, j, K]
+    A = [jnp.where(s[:, None] > s[None, :],
+                   (blocks(bk, SUB)[..., :, None, :] * kd).sum(-1), 0.0)]
+    B = [(blocks(q, SUB)[..., :, None, :] * kd).sum(-1)]
+    size = SUB
+    while size < C:
+
+        def halves(x):              # each pair's earlier and later block
+            x = blocks(x, size)
+            return x[..., 0::2, :, :], x[..., 1::2, :, :]
+
+        (_, q2), (k1, _), (G1, G2), (_, bk2) = map(halves, (q, k, G, bk))
+        G_first = G2[..., :1, :]
+        since = jnp.exp(G2 - G_first)
+        until = jnp.swapaxes(k1 * jnp.exp(G_first - G1), -1, -2)
+        A.append(_mm32(bk2 * since, until))
+        B.append(_mm32(q2 * since, until))
+        size *= 2
+    return tuple(A), tuple(B)
+
+
+def _merged(diagonal, lower):
+    """``[..., 2 P, s, s]`` blocks on a diagonal and the ``P`` blocks under
+    it, each pair's ``[..., P, s, s]`` -> the ``P`` blocks ``[[D1, 0], [L,
+    D2]]`` of twice the size."""
+    first, second = diagonal[..., 0::2, :, :], diagonal[..., 1::2, :, :]
+    return jnp.concatenate([
+        jnp.concatenate([first, jnp.zeros_like(first)], -1),
+        jnp.concatenate([lower, second], -1)], -2)
+
+
+def _substitute(a):
+    """``(I + a)^-1`` of strictly lower-triangular ``a`` ``[..., SUB,
+    SUB]`` by forward substitution, a row a step; the blocks lie in the
+    minor dimension meanwhile, so that a step is elementwise over them."""
+    lead = a.shape[:-2]
+    a = jnp.moveaxis(a.reshape((-1, SUB, SUB)), 0, -1)       # [t, j, blocks]
+    eye = jnp.eye(SUB, dtype=a.dtype)[..., None]
+
+    def row(t, X):
+        # Rows of X from t on are still the identity's, and a[t, j] = 0 there.
+        x_t = eye[t] - (a[t][:, None] * X).sum(0)
+        return jax.lax.dynamic_update_index_in_dim(X, x_t, t, 0)
+
+    # A constant typed like ``a`` (see chunk_scan's zero state).
+    X = jax.lax.fori_loop(1, SUB, row, eye + jax.lax.full_like(a, 0))
+    return jnp.moveaxis(X, -1, 0).reshape(lead + (SUB, SUB))
+
+
+def _unit_lower_inverse(A):
+    """``(I + A)^-1`` from ``A``'s blocks as :func:`_intra_chunk` gives
+    them: the :data:`SUB`-row blocks on the diagonal by substitution, every
+    block of every chunk and head at once, then merged two and two, ``[[T1,
+    0], [-T2 A21 T1, T2]]``, until one block is left: between sub-blocks
+    there are matrix products only, forward and backward."""
+    T = _substitute(A[0])
+    for below in A[1:]:
+        T = _merged(T, -_mm32(_mm32(T[..., 1::2, :, :], below),
+                              T[..., 0::2, :, :]))
+    return T[..., 0, :, :]
 
 
 @jax.checkpoint
@@ -123,12 +206,15 @@ def chunk_scan(q, k, v, g, beta):
     lower-triangular system ``(I + A) U = beta (V - (exp(G) K) S_in)``
     (the WY / UT form with a diagonal decay; ``A``, ``B`` as
     :func:`_intra_chunk` gives them), so ``U = W_v - W_k S_in`` with
-    ``[W_v, W_k] = (I + A)^-1 [beta V, beta exp(G) K]`` solved once for
-    all chunks, before the scan; then ``O = (exp(G) Q) S_in + B U`` and
+    ``[W_v, W_k] = (I + A)^-1 [beta V, beta exp(G) K]``, one ``C x C x (V +
+    K)`` product with the inverse :func:`_unit_lower_inverse` makes, once
+    for all chunks, before the scan; then ``O = (exp(G) Q) S_in + B U`` and
     ``S_out = Diag(exp(G_last)) S_in + (exp(G_last - G) K)^T U``, a
     ``jax.lax.scan`` carrying ``S`` over the sequence's chunks. Every
-    exponent is a difference that is never positive. A sequence is padded
-    to whole chunks with tokens that write nothing and decay nothing."""
+    exponent is a difference that is never positive, those of a decay split
+    at a sub-block's first token too (:func:`_intra_chunk`). A sequence is
+    padded to whole chunks with tokens that write nothing and decay
+    nothing."""
     n, L, H, K = q.shape
     N = -(-L // CHUNK)
     pad = N * CHUNK - L
@@ -142,10 +228,10 @@ def chunk_scan(q, k, v, g, beta):
                         for x in (q, k, v, g, beta))
     G = jnp.cumsum(g, axis=-2)
     A, B = jax.lax.map(lambda xs: _intra_chunk(*xs), (q, k, G, beta))
+    B = functools.reduce(_merged, B)[..., 0, :, :]
     decay_in = jnp.exp(G)
-    w = jax.lax.linalg.triangular_solve(
-        A, beta[..., None] * jnp.concatenate([v, decay_in * k], -1),
-        left_side=True, lower=True, unit_diagonal=True)
+    w = _mm32(_unit_lower_inverse(A),
+              beta[..., None] * jnp.concatenate([v, decay_in * k], -1))
     w_v, w_k = w[..., :v.shape[-1]], w[..., v.shape[-1]:]
     G_last = G[..., -1:, :]
     # A zero typed like the inputs (inside ``shard_map``, device-varying

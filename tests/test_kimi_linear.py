@@ -55,19 +55,28 @@ def _x(seed, n=2, length=L):
         (n, length, W)), F32)
 
 
-@pytest.mark.parametrize("length,decay_scale", [
-    (km.CHUNK, 1.0),            # one chunk
-    (3 * km.CHUNK, 1.0),        # several chunks
-    (2 * km.CHUNK + 22, 1.0),   # a padded tail
-    (3 * km.CHUNK, 16.0),       # A_log at its largest: exp(sum g) underflows
+@pytest.mark.parametrize("length,decay_scale,alike", [
+    (km.CHUNK, 1.0, False),             # one chunk
+    (3 * km.CHUNK, 1.0, False),         # several chunks
+    (2 * km.CHUNK + 22, 1.0, False),    # a padded tail
+    (3 * km.CHUNK, 16.0, False),        # A_log at its largest: exp(sum g)
+                                        # underflows
+    (km.CHUNK + km.SUB + 5, 1.0, False),        # ends inside a sub-block
+    # exp(G_r - G_j) underflows from one sub-block to the next
+    (2 * km.CHUNK + km.SUB + 5, 16.0, False),
+    # near-identical keys, beta near 1, hardly a decay: I + A at its worst
+    # conditioning
+    (2 * km.CHUNK, 0.01, True),
 ])
 def test_the_chunked_scan_is_the_recurrence_in_value_and_gradient(
-        length, decay_scale):
+        length, decay_scale, alike):
     rng = np.random.default_rng(length + int(decay_scale))
     n, H, K, V = 2, 2, 16, 8
 
     def unit(shape):
         u = rng.standard_normal(shape)
+        if alike:
+            u = rng.standard_normal(shape[:1] + (1,) + shape[2:]) + 1e-2 * u
         return u / np.linalg.norm(u, axis=-1, keepdims=True)
 
     q = jnp.asarray(unit((n, length, H, K)) / np.sqrt(K), F32)
@@ -75,12 +84,21 @@ def test_the_chunked_scan_is_the_recurrence_in_value_and_gradient(
     v = jnp.asarray(rng.standard_normal((n, length, H, V)), F32)
     g = jnp.asarray(-decay_scale * np.logaddexp(
         0, rng.standard_normal((n, length, H, K))), F32)
-    beta = jnp.asarray(1 / (1 + np.exp(-rng.standard_normal(
-        (n, length, H)))), F32)
+    beta = jnp.asarray(1 / (1 + np.exp(-(
+        np.full((n, length, H), 7.0) if alike
+        else rng.standard_normal((n, length, H))))), F32)
     probe = jnp.asarray(rng.standard_normal((n, length, H, V)), F32)
     if decay_scale > 1:
-        # A chunk's product of decays is below float32's smallest number.
+        # A chunk's product of decays is below float32's smallest number,
+        # and so is every sub-block's.
         assert float(g[:, :km.CHUNK].sum(1).max()) < -200
+        assert max(float(g[:, i:i + km.SUB].sum(1).max())
+                   for i in range(0, length - km.SUB, km.SUB)) < -88
+    if alike:
+        # Every pair of a chunk's keys at a cosine over 0.99: A is close to
+        # the strictly lower triangle of ones.
+        cos = np.einsum("nthc,nshc->nhts", k[:, :km.CHUNK], k[:, :km.CHUNK])
+        assert float(cos.min()) > 0.99 and float(beta.min()) > 0.999
 
     def chunked(*a):
         o = km.chunk_scan(*a)
@@ -98,6 +116,39 @@ def test_the_chunked_scan_is_the_recurrence_in_value_and_gradient(
     _close(got, want)
     for a, b in zip(got_g, want_g):
         _close(a, b)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("what", ["triangular_solve", "pairwise_array"])
+def test_the_scans_program_holds_no_chunk_wide_solve_or_pairwise_array(what):
+    """One head, one sequence of two chunks at the published head width:
+    in the program of the scan and its gradient, everything between a
+    chunk's sub-blocks is a matrix product."""
+    K = 128
+    x = jax.ShapeDtypeStruct((1, 2 * km.CHUNK, 1, K), F32)
+    beta = jax.ShapeDtypeStruct((1, 2 * km.CHUNK, 1), F32)
+    program = jax.make_jaxpr(jax.value_and_grad(
+        lambda *a: jax.checkpoint(km.chunk_scan)(*a).sum(),
+        argnums=tuple(range(5))))(x, x, x, x, beta)
+    equations = list(_equations(program.jaxpr))
+    assert any(e.primitive.name == "dot_general" for e in equations)
+    if what == "triangular_solve":
+        rows = [e.invars[0].aval.shape[-1] for e in equations
+                if e.primitive.name == "triangular_solve"]
+        assert all(r <= km.SUB for r in rows), rows
+    else:
+        # A head-chunk's largest float32 array: the pairwise decays of its
+        # sub-blocks, CHUNK x SUB x K (CHUNK x CHUNK x K before PR 36).
+        largest = max(int(np.prod(v.aval.shape)) for e in equations
+                      for v in e.outvars if v.aval.dtype == F32)
+        assert largest == km.CHUNK * km.SUB * K, largest
 
 
 @pytest.mark.parametrize("kind", ["kda", "mla"])
