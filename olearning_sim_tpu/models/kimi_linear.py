@@ -66,8 +66,7 @@ from olearning_sim_tpu.models.lfm2 import (
     RMSNorm, SwiGLU, _attend, _dense_init, _mm)
 from olearning_sim_tpu.models.lookup import LookupOnlyEmbed
 from olearning_sim_tpu.models import moe
-from olearning_sim_tpu.models.registry import (
-    ModelSpec, WorkCounts, register_model, sown)
+from olearning_sim_tpu.models.registry import ModelSpec, register_model
 
 # Tokens a chunk of the delta-rule scan holds: the intra-chunk system is
 # CHUNK x CHUNK, the scan over a sequence has L / CHUNK steps.
@@ -490,37 +489,6 @@ class KimiLinear(nn.Module):
                        preferred_element_type=jnp.float32)
 
 
-def describe_stats(stats: np.ndarray) -> dict:
-    """Work counts from the ``kda_stats`` of a model's KDA layers summed
-    over some stretch of work (``[layers, 2]``): the tokens that went
-    through the scan and the chunk bodies it executed forward."""
-    tokens, chunks = np.asarray(stats, np.int64).reshape(
-        -1, len(STATS)).sum(0).tolist()
-    return {"kda_scan_tokens": tokens, "kda_scan_chunks": chunks}
-
-
-def _gather_counts(intermediates):
-    """One array of a forward pass's counts: the expert layers' ``moe_stats``
-    a row each, then one row that starts with the KDA layers' ``kda_stats``
-    summed (the names need no more) and is zero after them."""
-    scans = sown(intermediates, "kda_stats")
-    if not scans:
-        return None
-    scan = sum(scans)
-    experts = moe.gather_stats(intermediates)
-    if experts is None:
-        return scan[None]
-    return jnp.concatenate([experts, jnp.pad(
-        scan, (0, experts.shape[1] - len(STATS)))[None]])
-
-
-def _describe_counts(counts: np.ndarray) -> dict:
-    named = describe_stats(counts[-1, :len(STATS)])
-    if len(counts) > 1:
-        named.update(moe.describe_stats(counts[:-1]))
-    return named
-
-
 register_model(
     ModelSpec(
         name="kimi_linear",
@@ -532,7 +500,8 @@ register_model(
         # DroplessMoE's jax.lax.ragged_dot has no batching rule for
         # per-client expert weights.
         vmap_clients=False,
-        work_counts=WorkCounts(_gather_counts, _describe_counts),
+        work_counts=moe.work_counts_beside(
+            "kda_stats", tuple("kda_" + name for name in STATS)),
         defaults={
             "vocab_size": 163840, "max_len": 1048576, "width": 2304,
             "layer_types": ["kda", "kda", "kda", "mla"],

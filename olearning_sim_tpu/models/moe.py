@@ -2,11 +2,14 @@
 
 Two expert layers live here, and they share nothing:
 
-- :class:`DroplessMoE` — the routed expert layer of the ``lfm2`` and
-  ``kimi_linear`` families (``models/lfm2.py``, ``models/kimi_linear.py``,
-  which adds a shared expert beside it): a sigmoid router over the model's PUBLISHED number
+- :class:`DroplessMoE` — the routed expert layer of the ``lfm2``,
+  ``kimi_linear`` and ``nemotron_h`` families (``models/lfm2.py``;
+  ``models/kimi_linear.py`` and ``models/nemotron_h.py`` add a shared expert
+  beside it): a sigmoid router over the model's PUBLISHED number
   of experts, top-k selection steered by a bias that never enters the
-  weights, SwiGLU experts, and no capacity: every (token, slot) assignment
+  weights, experts of one of two forms (gated SwiGLU, three matrices, the
+  default; or squared ReLU without a gate, two), and no capacity: every
+  (token, slot) assignment
   to an expert this layer holds is computed, whatever the imbalance. The
   layer is TOLD which experts it holds (``held``): it routes over all of
   them and returns the partial sum its own experts give, which is what one
@@ -33,6 +36,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from olearning_sim_tpu.models.registry import (
     ModelSpec, WorkCounts, register_model, sown)
@@ -78,6 +82,56 @@ def _grouped_swiglu(xs, w1, w3, w2, sizes):
     return jax.lax.ragged_dot(jax.nn.silu(a) * b, w2, sizes)
 
 
+# The TPU compiler's grouped-matmul kernel tiles a width that is a multiple
+# of this by it, and any other width by 128.
+GROUPED_TILE = 512
+# Rows the squared-ReLU form's grouped products are given at a time: the
+# last held group is lengthened over the zero rows that follow the groups,
+# up to the next multiple of this (see :func:`_grouped_relu2`).
+GROUPED_ROW_BLOCK = 4096
+# The names the two grouped products' results carry for a caller's
+# ``jax.checkpoint`` policy (``save_only_these_names(*GROUPED_RESULTS)``
+# keeps them, so that a layer computed again in the backward pass does not
+# run the products again).
+GROUPED_RESULTS = ("grouped_hidden", "grouped_out")
+
+
+def _grouped_relu2(xs, w1, w2, sizes):
+    """``W2(relu(W1 x)^2)`` of rows ``xs`` grouped by expert, as
+    :func:`_grouped_swiglu` groups them: two grouped products a pass. The
+    hidden product is kept for the backward pass, and both products'
+    results are named (:data:`GROUPED_RESULTS`) for a caller that computes
+    its layer again there.
+
+    The hidden width is padded with zero columns of ``w1`` and zero rows of
+    ``w2`` up to a multiple of :data:`GROUPED_TILE` for the two calls:
+    ``relu(0)^2 = 0`` through zero rows adds exact zeros to the sums, and
+    a 1,856-wide expert's products take a fraction of the time a row
+    (128-wide tiles are 4 x the kernel's steps; PERF.md section 6, PR
+    38).
+
+    The products run over whole blocks of :data:`GROUPED_ROW_BLOCK` rows:
+    the rows that follow the groups are zeros (the caller's gather fills
+    them), the last group takes those up to the next block's end, and what
+    its expert makes of them is zero and is never gathered back, so values
+    and gradients are the same. What changes is the time: the kernel's
+    follows the rows it is given, the held experts' load follows the seeded
+    weights (0.15-0.27 of a step's assignments over three layers, seed to
+    seed), and a round's time followed it by 1.2% where 1% is the bound; in
+    blocks it does not, as long as a layer's load stays under one block,
+    for 2% of the round."""
+    pad = -w1.shape[-1] % GROUPED_TILE
+    w1 = jnp.pad(w1, ((0, 0), (0, 0), (0, pad)))
+    w2 = jnp.pad(w2, ((0, 0), (0, pad), (0, 0)))
+    rows = sizes.sum()
+    sizes = sizes.at[-1].add(jnp.minimum(
+        -rows % GROUPED_ROW_BLOCK, xs.shape[0] - rows))
+    a = jax.nn.relu(checkpoint_name(
+        jax.lax.ragged_dot(xs, w1, sizes), GROUPED_RESULTS[0]))
+    return checkpoint_name(
+        jax.lax.ragged_dot(a * a, w2, sizes), GROUPED_RESULTS[1])
+
+
 class DroplessMoE(nn.Module):
     """Dropless top-k routed expert layer that is told which experts it
     holds.
@@ -87,7 +141,10 @@ class DroplessMoE(nn.Module):
     are those of ``top_k(s + expert_bias)``; their weights are the selected
     ``s`` (never the biased score), divided by their sum + 1e-6 when
     ``norm_topk_prob``, times ``routed_scaling_factor``; expert
-    ``e(h) = W2(silu(W1 h) * W3 h)``. The layer returns the weighted sum
+    ``e(h) = W2(silu(W1 h) * W3 h)`` (``gated``, the default: leaves
+    ``expert_w1``, ``expert_w3``, ``expert_w2``) or, without a gate,
+    ``e(h) = W2(relu(W1 h)^2)`` (``expert_w1``, ``expert_w2``: no third
+    matrix, two grouped products). The layer returns the weighted sum
     over the selected experts that are in ``held`` and nothing for the
     others: with ``held`` = every id that is the whole layer, with a share
     of them it is that share's part of the sum, and the parts of disjoint
@@ -96,7 +153,7 @@ class DroplessMoE(nn.Module):
 
     No capacity and no drop: the (token, slot) assignments are sorted by
     the held expert they go to — assignments to experts not held form a
-    tail group — and the three expert products run as grouped matmuls
+    tail group — and the expert products run as grouped matmuls
     (``jax.lax.ragged_dot``) over the held groups only; the tail is never
     multiplied, read or summed. One expert may get every
     assignment, or none.
@@ -126,6 +183,7 @@ class DroplessMoE(nn.Module):
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
     dtype: jnp.dtype = jnp.bfloat16
+    gated: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -145,7 +203,8 @@ class DroplessMoE(nn.Module):
             (E,), jnp.float32)
         per_expert = nn.initializers.lecun_normal(batch_axis=(0,))
         w1 = self.param("expert_w1", per_expert, (H, W, M), jnp.float32)
-        w3 = self.param("expert_w3", per_expert, (H, W, M), jnp.float32)
+        if self.gated:
+            w3 = self.param("expert_w3", per_expert, (H, W, M), jnp.float32)
         w2 = self.param("expert_w2", per_expert, (H, M, W), jnp.float32)
 
         with jax.named_scope("moe.route"):
@@ -183,9 +242,13 @@ class DroplessMoE(nn.Module):
                 jnp.repeat(xf.astype(self.dtype), K, axis=0), perm, inv)
 
         with jax.named_scope("moe.experts"):
-            ye = _grouped_swiglu(xs, w1.astype(self.dtype),
-                                 w3.astype(self.dtype),
-                                 w2.astype(self.dtype), sizes)   # [A, W]
+            if self.gated:
+                ye = _grouped_swiglu(xs, w1.astype(self.dtype),
+                                     w3.astype(self.dtype),
+                                     w2.astype(self.dtype), sizes)  # [A, W]
+            else:
+                ye = _grouped_relu2(xs, w1.astype(self.dtype),
+                                    w2.astype(self.dtype), sizes)
 
         with jax.named_scope("moe.combine"):
             yk = _take_rows(ye, inv, perm).reshape(S, K, W)
@@ -227,6 +290,36 @@ def gather_stats(intermediates):
 
 # For the ``ModelSpec`` of a model whose only counted layers are these.
 WORK_COUNTS = WorkCounts(gather_stats, describe_stats)
+
+
+def work_counts_beside(sown_name: str, names: Tuple[str, ...]) -> WorkCounts:
+    """For the ``ModelSpec`` of a model whose mixers sow counts of their own
+    (``sown_name``: one int32 vector a layer, ``len(names)`` long) beside
+    these layers': one array of a forward pass's counts, the expert layers'
+    ``moe_stats`` a row each, then one row that starts with the mixers'
+    vectors summed (the names need no more) and is zero after them; summed
+    over some stretch of work it is named ``{names[i]: count}`` and, where
+    there are expert layers, what :func:`describe_stats` names."""
+
+    def gather(intermediates):
+        own = sown(intermediates, sown_name)
+        if not own:
+            return None
+        own = sum(own)
+        experts = gather_stats(intermediates)
+        if experts is None:
+            return own[None]
+        return jnp.concatenate([experts, jnp.pad(
+            own, (0, experts.shape[1] - len(names)))[None]])
+
+    def describe(counts: np.ndarray) -> dict:
+        named = dict(zip(names, np.asarray(
+            counts[-1, :len(names)], np.int64).tolist()))
+        if len(counts) > 1:
+            named.update(describe_stats(counts[:-1]))
+        return named
+
+    return WorkCounts(gather, describe)
 
 
 class SwitchFFN(nn.Module):

@@ -37,9 +37,12 @@ def leave_decay_out(reference):
     return reference
 
 
-def run(workload, seed, seconds, **run_cell_kwargs):
+def run(workload, seed, seconds, *, reference="kimi_linear",
+        plant=leave_decay_out, **run_cell_kwargs):
     """(the sound check, the check against the reference without its
-    decay), both ``benchmark.check.CheckResult``."""
+    decay), both ``benchmark.check.CheckResult``. ``reference`` names the
+    module the fault goes into and ``plant`` plants it (another model's
+    script of this kind gives its own two)."""
     from benchmark import check, harness, manifest
 
     find_module, run_check = manifest.find_module, check.run_check
@@ -47,8 +50,8 @@ def run(workload, seed, seconds, **run_cell_kwargs):
 
     def without_decay(kind, name, *where):
         module = find_module(kind, name, *where)    # a new copy every call
-        if (kind, name) == ("reference", "kimi_linear"):
-            leave_decay_out(module)
+        if (kind, name) == ("reference", reference):
+            plant(module)
         return module
 
     def both_checks(runner, cell, task, check_seed, plant=False):
@@ -69,10 +72,10 @@ def run(workload, seed, seconds, **run_cell_kwargs):
     return done.checks[0], planted[0]
 
 
-def main(argv=None) -> int:
+def main(argv=None, cell=CELL, doc=__doc__, **fault) -> int:
     import jax
 
-    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser = argparse.ArgumentParser(description=doc.split("\n\n")[0])
     parser.add_argument("--seed", type=int, default=2**31 + 341)
     parser.add_argument("--seconds", type=float, default=3.0)
     args = parser.parse_args(argv)
@@ -80,7 +83,7 @@ def main(argv=None) -> int:
         print("no TPU: the check's readings at the cell's size are chip "
               "readings", file=sys.stderr)
         return 1
-    sound, planted = run(CELL, args.seed, args.seconds)
+    sound, planted = run(cell, args.seed, args.seconds, **fault)
     for line in planted.lines():
         print("planted " + line, flush=True)
     print(json.dumps({

@@ -191,7 +191,11 @@ def test_a_kda_layer_counts_its_scans_tokens_and_chunks():
     _, inter = layer.apply({"params": params}, x, mutable=["intermediates"])
     (stats,) = inter["intermediates"]["kda_stats"]
     assert np.asarray(stats).tolist() == [3 * L, 3 * 2]
-    assert km.describe_stats(np.stack([stats, stats])) == {
+    # Two such layers' counts, gathered and named as the registry has it.
+    counts = get_model("kimi_linear").work_counts
+    row = counts.gather({"a": inter["intermediates"],
+                         "b": inter["intermediates"]})
+    assert counts.describe(np.asarray(row)) == {
         "kda_scan_tokens": 2 * 3 * L, "kda_scan_chunks": 2 * 3 * 2}
 
 
