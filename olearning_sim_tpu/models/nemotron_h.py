@@ -266,7 +266,17 @@ class ReLU2(nn.Module):
 class Layer(nn.Module):
     """One layer: ``h + f(rms(h))``, ``f`` by ``kind``: ``"M"`` the Mamba-2
     mixer, ``"E"`` the routed experts plus the shared one, ``"*"``
-    attention."""
+    attention.
+
+    What a layer leaves for the backward pass when nothing computes it again
+    (:class:`NemotronH` decides by ``kind``; bfloat16 unless said): ``"*"``
+    q, k, v and the context, 17 KB a token (the L x L scores never:
+    ``lfm2._attend`` computes them again); ``"E"`` the per-assignment arrays,
+    ``experts_per_token`` rows a token (the gathered inputs, both grouped
+    products' results, the float32 combine) and the shared expert's hidden
+    product, about 140 KB a token; ``"M"`` the fused projection's
+    10,304-wide result, the convolution's, the scan's float32 inputs,
+    running sums and pairwise decays and the gate, about 215 KB a token."""
 
     kind: str
     mamba_heads: int
@@ -314,6 +324,28 @@ class Layer(nn.Module):
 
 
 class NemotronH(nn.Module):
+    """The stack: embedding, one :class:`Layer` a letter of ``pattern``, the
+    final norm and the untied head.
+
+    **Which layers the backward pass computes again is chosen by the
+    layer's letter**, not by a knob: an ``"M"`` layer is wrapped in
+    ``nn.remat`` (its input alone is kept and the layer runs a second time
+    before its gradient), ``"E"`` and ``"*"`` layers are not (their
+    residuals are kept, :class:`Layer` has their sizes). Sized by compiling
+    the benchmark cell's round program (``MEMEM*E``, 4,096 tokens a step, 8
+    held experts, 21 B a parameter of state around it) for a v5e with
+    ``scripts/compile_cell.py``: temporaries + arguments + generated code
+    are 14.91 GiB of the chip's 15.75 (about 0.26 more are the runtime's),
+    13.34 with every layer computed again. An ``"M"`` layer's ``nn.remat``
+    buys 0.83 GiB there and costs the cheapest second pass; without it the
+    three do not fit (15.67 GiB before the runtime's share). An ``"E"``
+    layer's bought 0.48-0.58 GiB for the dearest second pass (the sort, the
+    gathers and the combine over ``experts_per_token`` rows a token); an
+    attention layer's bought nothing. What a caller gives up: every token of
+    a local step beyond the cell's 4,096 needs about 140 KB more for each
+    ``"E"`` layer than when every layer was computed again (PERF.md section
+    6, PR 40, has the compiler's table and the chip's readings)."""
+
     vocab_size: int = 131072
     max_len: int = 262144           # positions served; no position table
     width: int = 2688
@@ -347,13 +379,12 @@ class NemotronH(nn.Module):
             self.vocab_size, self.width, name="embed",
             embedding_init=nn.initializers.normal(stddev=0.02),
             param_dtype=jnp.float32)(tokens)
-        # The backward pass keeps the residual stream between the layers
-        # and computes a layer again for everything inside it but the
-        # grouped expert products, whose results it keeps.
-        layer = nn.remat(Layer, policy=(
-            jax.checkpoint_policies.save_only_these_names(
-                *moe.GROUPED_RESULTS)))
         for i, kind in enumerate(self.pattern):
+            # Only an M layer is computed again in the backward pass (215
+            # KB a token a layer not kept); an E layer's 140 KB and an
+            # attention layer's 17 KB a token are kept. Sized against the
+            # compiler's 14.91 of 15.75 GiB: the class docstring.
+            layer = nn.remat(Layer) if kind == "M" else Layer
             h = layer(
                 kind=kind, mamba_heads=self.mamba_heads,
                 mamba_head_dim=self.mamba_head_dim,

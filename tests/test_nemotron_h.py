@@ -4,13 +4,19 @@ recurrence token by token (values and all gradients, the largest and the
 smallest step size included), each mixer and the whole model (forward and
 the gradient of the next-token loss), the expert layer's two forms against
 hand-written sums, its sixteen shares with the shared expert counted once,
-and one federated round + evaluation through ``FedCore`` with the embedding
-trained by rows and the scan's counts on the round's metrics.
+one federated round + evaluation through ``FedCore`` with the embedding
+trained by rows and the scan's counts on the round's metrics, and which
+layers the backward pass computes again: the Mamba-2 layers alone, at no
+change to any value, and to no other family's round program.
 
 Counts and correctness facts only: never a speed."""
 
+import dataclasses
+import hashlib
+import json
 import os
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -349,6 +355,127 @@ def test_the_whole_model_matches_the_reference():
     plain = float(ref.sequence_loss(flat, tokens[0]))
     assert abs(float(planted.sequence_loss(flat, tokens[0]))
                - plain) > 1e-4 * plain
+
+
+def _loss_and_grads(pattern):
+    model = get_model("nemotron_h").build(
+        **dict(TINY, pattern=pattern), dtype=F32)
+    tokens = jnp.asarray(np.random.default_rng(8).integers(1, 128, (2, L)),
+                         jnp.int32)
+    params = model.init(jax.random.key(1), tokens)["params"]
+
+    def loss_fn(p):
+        logp = jax.nn.log_softmax(model.apply({"params": p}, tokens)[:, :-1])
+        return -jnp.take_along_axis(logp, tokens[:, 1:, None], -1).mean()
+
+    return loss_fn, params
+
+
+@pytest.mark.parametrize("pattern", ["M", "E", "*", "MEMEM*E"])
+def test_computing_a_layer_again_changes_no_value(pattern, monkeypatch):
+    """Loss and every leaf's gradient with the per-kind choice are those of
+    the same model with no ``nn.remat`` anywhere."""
+    loss_fn, params = _loss_and_grads(pattern)
+    got_loss, got = jax.value_and_grad(loss_fn)(params)
+    monkeypatch.setattr(nn, "remat", lambda module, **_kw: module)
+    want_loss, want = jax.value_and_grad(loss_fn)(params)
+    assert float(got_loss) == pytest.approx(float(want_loss), rel=1e-6)
+    got, want = check.flatten(got), check.flatten(want)
+    assert set(got) == set(want)
+    for name in want:
+        _close(got[name], want[name], 1e-5)
+
+
+def _checkpoints(jaxpr):
+    """The primitives inside every ``jax.checkpoint`` equation of a jaxpr,
+    at any depth, one set an equation."""
+    found = []
+
+    def inner(value):
+        value = getattr(value, "jaxpr", value)
+        return value if hasattr(value, "eqns") else None
+
+    def names(sub, into):
+        for eqn in sub.eqns:
+            into.add(eqn.primitive.name)
+            for value in eqn.params.values():
+                if inner(value) is not None:
+                    names(inner(value), into)
+        return into
+
+    def walk(sub):
+        for eqn in sub.eqns:
+            if eqn.primitive.name == "remat2":      # jax.checkpoint's
+                found.append(names(eqn.params["jaxpr"], set()))
+            for value in eqn.params.values():
+                if inner(value) is not None:
+                    walk(inner(value))
+
+    walk(jaxpr)
+    return found
+
+
+def test_the_backward_pass_computes_only_the_mamba_layers_again():
+    """The intent, pinned: an attention layer's backward pass holds one
+    ``jax.checkpoint`` equation, ``lfm2._attend``'s own around the scores
+    (no norm inside it); an expert layer's holds none (its grouped products
+    are residuals like everything else); a Mamba-2 layer's holds the
+    layer's, pre-norm and scan inside. Restoring the blanket ``nn.remat``
+    adds one to the first two; dropping the Mamba-2 layers' takes the third
+    away, and the round no longer fits the chip (PERF.md section 6, PR
+    40)."""
+    def backward(pattern):
+        loss_fn, params = _loss_and_grads(pattern)
+        return _checkpoints(jax.make_jaxpr(jax.grad(loss_fn))(params).jaxpr)
+
+    (scores,) = backward("*")
+    assert "exp" in scores and "rsqrt" not in scores
+    assert backward("E") == []
+    (mamba,) = backward("M")
+    assert {"scan", "rsqrt"} <= mamba
+    whole = backward("MEMEM*E")
+    assert len(whole) == 4
+    assert sum("scan" in names for names in whole) == 3
+
+
+@pytest.mark.parametrize("config,digest", [
+    ("lfm2_moe_ep8",
+     "e9f857dff430b6a4ab7bc2fba023017241ab76c943aac6b3e52f0f3fd554b3af"),
+    ("kimi_linear_ep32",
+     "f8917807f93aec3fbe0bda77387c68240212f8114f638dc3649d7a51906df07a"),
+])
+def test_the_other_sparse_decoders_round_programs_did_not_move(
+        config, digest):
+    """The two families that share ``models/moe.py`` and ``lfm2._attend``
+    with this one lower their small presets' ``round_step`` to the text
+    they lowered to before this family chose its layers to compute again
+    (PR 40: the digests are the parent commit's), so their compilation
+    cache keys stand. An intended edit to those families pins them anew
+    (the failure prints the new digest)."""
+    def read(*path):
+        with open(os.path.join(*path, config + ".json")) as f:
+            return json.load(f)
+
+    tiny = read(os.path.dirname(__file__), "benchmark", "data", "tiny")
+    params = read(manifest.HERE, "configs")["task"]["operatorflow"][
+        "operators"][0]["logical_simulation"]["operator_params"]
+    plan = make_mesh_plan(devices=jax.devices()[:1])
+    cfg = dataclasses.replace(
+        FedCoreConfig.from_dict(
+            dict(params["fedcore"], **tiny["traffic"]["fedcore"])),
+        task="next_token")
+    core = build_fedcore(
+        params["model"]["name"], from_config("fedavg", local_lr=0.1), plan,
+        cfg, model_overrides=tiny["overrides"],
+        input_shape=tuple(tiny["input_shape"]))
+    ds = make_synthetic_text_dataset(
+        7, tiny["traffic"]["clients"], tiny["traffic"]["n_local"],
+        tiny["input_shape"][0], num_classes=4,
+        vocab_size=tiny["overrides"]["vocab_size"],
+        dirichlet_alpha=0.3).pad_for(plan, 1).place(plan)
+    text = core.lower_round_step(
+        core.init_state(jax.random.key(0)), ds).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
 
 
 def test_the_references_program_is_not_left_in_the_compile_cache(monkeypatch):
