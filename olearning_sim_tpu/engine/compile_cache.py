@@ -104,6 +104,22 @@ BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
+def program_name(fun_name: str) -> str:
+    """jax's ``fun_name`` in one form for the four compile events, so that
+    a program's spans are found by one key: the trace event names the
+    Python function (``round_step``), the lowering, the backend compile
+    and the cache load its module (``jit(round_step)``; ``jit_round_step``
+    in older releases), and a qualified name ends in the function's
+    (``FedCore._build.<locals>.round_step``). All become ``round_step``."""
+    name = str(fun_name)
+    for wrapper in ("jit", "pmap"):
+        if name.startswith(wrapper + "(") and name.endswith(")"):
+            name = name[len(wrapper) + 1:-1]
+        elif name.startswith(wrapper + "_"):
+            name = name[len(wrapper) + 1:]
+    return name.rsplit(".", 1)[-1]
+
+
 def install_listener() -> None:
     """Mirror jax's compilation monitoring events into the metric catalog
     and the span tree (one set of listeners per process; jax offers no
@@ -117,7 +133,12 @@ def install_listener() -> None:
     thread, so their parent is the span that thread has open (round 0's
     ``round.<operator>.train``), whose ``task_id`` and ``round_idx`` they
     copy; a recompile in a later round names its round, phase and
-    ``fun_name``. Only the OUTERMOST interval of a thread is recorded: jax
+    ``fun_name``, and ``program`` is that name in one form for the four
+    events (:func:`program_name`): the engine's own jits give
+    ``round_step``, ``evaluate``, ``make`` (the initialiser) and
+    ``partial_body`` (the streamed partial step); anything else is an
+    eager operation's. Only the OUTERMOST interval of a thread is
+    recorded: jax
     reports one duration per traced jit and the functions of ``jax.numpy``
     are jits, so a round program's trace holds hundreds of nested ones, its
     lowering traces more, and a trace that runs an operation eagerly
@@ -171,6 +192,8 @@ def install_listener() -> None:
         local.depth = depth = getattr(local, "depth", 1) - 1
         if depth > 0:
             return
+        if "fun_name" in attrs:
+            attrs = dict(attrs, program=program_name(attrs["fun_name"]))
         tracer = default_tracer()
         parent = tracer.current()
         if parent is not None:

@@ -40,7 +40,7 @@ import os
 import threading
 import time
 import zlib
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -380,12 +380,16 @@ class SimulationRunner:
         # Telemetry->scheduler feedback: a CostOracle (taskmgr/pool.py)
         # fed the measured per-round wall time at every round close, so
         # the chip-pool scheduler packs from live numbers instead of only
-        # bench ingests (_feed_cost: steady rounds feed round_time_s;
-        # round 0 feeds compile_s only when it was compile-dominated).
+        # bench ingests (_feed_cost: steady rounds feed round_time_s and
+        # the device's measured peak; round 0 feeds compile_s only when
+        # the process truly compiled).
         self._cost_oracle = cost_oracle
         self._cost_family = cost_family
-        self._cost_round0_wall: Optional[float] = None
+        self._cost_round0: Optional[Tuple[int, float]] = None  # (idx, wall)
         self._cost_compile_fed = False
+        # device_peak_bytes of the last phase span that stamped it
+        # (_phase); None where the backend keeps no statistics.
+        self._device_peak_bytes: Optional[int] = None
         # run()-loop state for the cooperative stepping API (begin/step/
         # finish) the MultiTaskDispatcher drives; None outside a run.
         self._loop: Optional[Dict[str, Any]] = None
@@ -521,8 +525,12 @@ class SimulationRunner:
     def _phase(self, operator_name: str, phase: str, round_idx: int):
         """Span + per-phase latency histogram around one round phase; the
         histogram is fed the span's own duration. Yields the span (None
-        under a disabled tracer)."""
-        from olearning_sim_tpu.telemetry import instrument
+        under a disabled tracer). The two phases that end with a read from
+        the device, ``host_transfer`` and ``eval``, close with the
+        device's memory stamped on the span
+        (``telemetry.stamp_device_memory``)."""
+        from olearning_sim_tpu.telemetry import (
+            instrument, stamp_device_memory)
 
         with self._tracer().span(f"round.{operator_name}.{phase}",
                                  task_id=self.task_id,
@@ -530,6 +538,12 @@ class SimulationRunner:
             # A disabled tracer times nothing; the histogram still does.
             t0 = time.perf_counter() if span is None else None
             yield span
+            if phase in ("host_transfer", "eval"):
+                # The device has answered: what its allocator holds, and
+                # the most it has held, now count this round's programs.
+                peak = stamp_device_memory(span)
+                if peak is not None:
+                    self._device_peak_bytes = peak
         instrument(
             "ols_engine_round_phase_duration_seconds", self.registry
         ).labels(
@@ -1359,27 +1373,56 @@ class SimulationRunner:
                 extra=extra,
             ))
 
-    def _feed_cost(self, round_wall_s: float) -> None:
+    def _feed_cost(self, round_wall_s: float, round_idx: int = 0) -> None:
         """Telemetry->scheduler loop: feed this round's measured wall time
+        and the device's measured peak (``device_peak_bytes`` of the
+        round's last ``host_transfer`` or ``eval`` span, where the backend
+        reports it: the process's peak, so a process that runs several
+        tasks reads their sum and admission errs on the refusing side)
         into the pool's CostOracle the moment the round completes, so the
         NEXT admission/packing decision for this family runs on live
-        numbers. Round 0's wall is held back until round 1 can classify
-        it: cold builds are compile-dominated there and refine compile_s,
-        but with the persistent XLA compile cache warm round 0 is an
-        ordinary round — feeding it as compile_s would clobber the
+        numbers. The first round is never fed as a round time; it refines
+        compile_s once the second has run, and only where the process
+        truly compiled: with the persistent XLA compile cache warm it is
+        an ordinary round, and feeding it as compile_s would clobber the
         family's real compile estimate with a near-zero one."""
-        if self._cost_round0_wall is None:
-            self._cost_round0_wall = round_wall_s
+        if self._cost_round0 is None:
+            self._cost_round0 = (round_idx, round_wall_s)
             return
         self._cost_oracle.record_measurement(
-            self._cost_family, round_time_s=round_wall_s
+            self._cost_family, round_time_s=round_wall_s,
+            peak_hbm_bytes=self._device_peak_bytes,
         )
         if not self._cost_compile_fed:
             self._cost_compile_fed = True
-            if self._cost_round0_wall > 1.5 * round_wall_s:
+            compile_s = self._first_round_compile_s(round_wall_s)
+            if compile_s:
                 self._cost_oracle.record_measurement(
-                    self._cost_family, compile_s=self._cost_round0_wall
+                    self._cost_family, compile_s=compile_s
                 )
+
+    def _first_round_compile_s(self, steady_wall_s: float) -> float:
+        """What the first round spent making its programs ready: the sum of
+        its ``compile.*`` spans where one of them is a ``compile.backend``
+        (XLA compiled), 0.0 where every program came from the persistent
+        cache. A first round always traces its programs, so a tree that
+        holds no ``compile.*`` span of it saw nothing (the compile listener
+        writes to the process's default tracer, and names the task only
+        under a span of that tracer); the wall clock then decides, as it
+        did before the spans: the first round's wall where it was more
+        than 1.5 x a steady round's."""
+        from olearning_sim_tpu.telemetry import default_tracer
+
+        first_idx, first_wall_s = self._cost_round0
+        spans = [s for s in default_tracer().spans()
+                 if s.name.startswith("compile.")
+                 and s.attrs.get("task_id") == self.task_id
+                 and s.attrs.get("round_idx") == first_idx]
+        if not spans:
+            return first_wall_s if first_wall_s > 1.5 * steady_wall_s else 0.0
+        if not any(s.name == "compile.backend" for s in spans):
+            return 0.0
+        return sum(s.duration_s for s in spans)
 
     def convergence_record(self) -> Optional[Dict[str, Any]]:
         """The task's convergence record (engine/convergence.py), or None
@@ -2122,7 +2165,7 @@ class SimulationRunner:
         if self._convergence is not None:
             self._observe_convergence(round_idx, round_record, round_wall_s)
         if self._cost_oracle is not None and self._cost_family:
-            self._feed_cost(round_wall_s)
+            self._feed_cost(round_wall_s, round_idx)
         if self._pacer is not None and self.deadline.adaptive:
             # Controller state after this round's observations. History
             # records ride both the in-memory snapshot and the checkpoint
@@ -2194,9 +2237,11 @@ class SimulationRunner:
         # read from them) need jax's compile events listened to, also for a
         # runner that no task bridge built.
         from olearning_sim_tpu.engine.compile_cache import install_listener
+        from olearning_sim_tpu.telemetry import stamp_device_memory
 
         install_listener()
-        with self._tracer().span("bridge.init_state", task_id=self.task_id):
+        with self._tracer().span("bridge.init_state",
+                                 task_id=self.task_id) as init_span:
             for p in self.populations:
                 if p.name not in self.states:
                     # crc32, not hash(): str hashes are PYTHONHASHSEED-
@@ -2209,6 +2254,7 @@ class SimulationRunner:
                             zlib.crc32(self.task_id.encode()) & 0x7FFFFFFF
                         )
                     )
+            stamp_device_memory(init_span)
         start_round = self._try_resume()
         if start_round == 0 and self.model_io is not None:
             start_round = self._resume_from_exports()

@@ -169,14 +169,18 @@ def build_runner_from_taskconfig(
     TaskManager retires finished tasks' series from."""
     if not isinstance(tc, pb.TaskConfig):
         tc = json2taskconfig(tc)
-    from olearning_sim_tpu.telemetry import default_tracer
+    from olearning_sim_tpu.telemetry import (
+        default_tracer, stamp_device_memory)
 
     # The task's span tree (docs/observability.md): bridge.build with its
     # children bridge.build_fedcore, bridge.generate and bridge.place, all
     # carrying the task id; the runner adds bridge.init_state and round.*.
     tracer = default_tracer()
     span = functools.partial(tracer.span, task_id=tc.taskID.taskID)
-    with span("bridge.build"):
+    with span("bridge.build") as build:
+        # What the device held before this task put anything there (a
+        # long-lived server has other tasks' buffers).
+        stamp_device_memory(build, "_before")
         return _build_runner(tc, span, plan, task_repo, deviceflow,
                              stop_event, perf, checkpointer, cost_oracle,
                              registry)
@@ -193,6 +197,7 @@ def _build_runner(tc: pb.TaskConfig, span, plan, task_repo, deviceflow,
     # variant deserializes its round programs instead of recompiling.
     # Disable with OLS_COMPILE_CACHE=0 (docs/performance.md).
     from olearning_sim_tpu.engine.compile_cache import enable_compile_cache
+    from olearning_sim_tpu.telemetry import stamp_device_memory
 
     enable_compile_cache()
     params = _engine_params(tc)
@@ -380,8 +385,9 @@ def _build_runner(tc: pb.TaskConfig, span, plan, task_repo, deviceflow,
 
             store = HostClientStore.from_dataset(ds)
         else:
-            with span("bridge.place"):
+            with span("bridge.place") as placed:
                 ds = ds.pad_for(plan, cfg.block_clients).place(plan)
+                stamp_device_memory(placed)
         cls = np.zeros(ds.num_clients, int)
         start = 0
         for ci, n in enumerate(nums):

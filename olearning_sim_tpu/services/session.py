@@ -125,6 +125,21 @@ class SimulatorSession:
 
     # ------------------------------------------------------------------ boot
     def start(self) -> Tuple[grpc.Server, int]:
+        """Bind the gRPC server and bring the services' threads up, under
+        the ``session.start`` span (docs/observability.md): it belongs to
+        no task, and its ``process_age_s`` is how long the process had
+        lived when the span opened, so that age plus duration is the
+        process's boot time, imports and backend start included."""
+        from olearning_sim_tpu.telemetry import default_tracer, process_age_s
+
+        attrs = {"services": list(self.services)}
+        age_s = process_age_s()
+        if age_s is not None:
+            attrs["process_age_s"] = age_s
+        with default_tracer().span("session.start", **attrs):
+            return self._start()
+
+    def _start(self) -> Tuple[grpc.Server, int]:
         server = grpc.server(futures.ThreadPoolExecutor(self._max_workers))
         if "taskmgr" in self.services and self.task_manager is not None:
             from olearning_sim_tpu.taskmgr.grpc_service import (

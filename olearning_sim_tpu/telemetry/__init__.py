@@ -47,7 +47,9 @@ from olearning_sim_tpu.telemetry.tracing import (
     Span,
     SpanTracer,
     default_tracer,
+    process_age_s,
     set_default_tracer,
+    stamp_device_memory,
 )
 from olearning_sim_tpu.telemetry.exporters import (
     MetricsHTTPServer,
@@ -386,8 +388,10 @@ __all__ = [
     "default_tracer",
     "dump_json",
     "instrument",
+    "process_age_s",
     "render_prometheus",
     "set_default_registry",
     "set_default_tracer",
     "snapshot",
+    "stamp_device_memory",
 ]

@@ -31,7 +31,7 @@ import os
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterable, List, Optional
 
 
 def _trace_annotation():
@@ -40,6 +40,64 @@ def _trace_annotation():
     not be what imports JAX."""
     profiler = sys.modules.get("jax.profiler")
     return getattr(profiler, "TraceAnnotation", None)
+
+
+def process_age_s() -> Optional[float]:
+    """How long this process has lived, in seconds, by the kernel's own
+    record: the start time in ``/proc/self/stat`` (field 22, clock ticks
+    after boot) against ``/proc/uptime``. It counts what ran before the
+    program's first line: the interpreter's start and, read later, the
+    imports and the backend's initialisation. None where there is no
+    ``/proc``."""
+    try:
+        with open("/proc/self/stat") as f:
+            # The command name (field 2) may hold spaces and brackets:
+            # count fields from its closing bracket, where field 3 starts.
+            fields = f.read().rsplit(")", 1)[1].split()
+        with open("/proc/uptime") as f:
+            uptime_s = float(f.read().split()[0])
+        return uptime_s - int(fields[19]) / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def _peak_bytes(stats: Dict[str, Any]) -> int:
+    """The allocator's peak of live buffers plus its peak reservation,
+    where a running program's scratch memory is held."""
+    return (int(stats.get("peak_bytes_in_use", 0))
+            + int(stats.get("peak_bytes_reserved", 0)))
+
+
+def stamp_device_memory(span: Optional["Span"], suffix: str = "",
+                        devices: Optional[Iterable[Any]] = None
+                        ) -> Optional[int]:
+    """Set on ``span`` what the allocator of the fullest local device
+    holds now and the most it has held: ``device_bytes_in_use`` and
+    ``device_peak_bytes`` (``peak_bytes_in_use + peak_bytes_reserved``: a
+    running program's scratch memory is in the reservation), each with
+    ``suffix`` appended. Called at the edges where the owner of device
+    memory changes (data placed, state made, a round answered), so that
+    the differences between two stamps say who holds what. Returns the
+    peak. Sets nothing and returns None on a disabled tracer's span, in a
+    process that has not imported JAX (a span must not be what imports it)
+    and where the backend keeps no statistics (CPU). ``devices`` is for
+    the tests; the default is ``jax.local_devices()``."""
+    if span is None:
+        return None
+    if devices is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        devices = jax.local_devices()
+    kept = [stats for stats in (d.memory_stats() for d in devices) if stats]
+    if not kept:
+        return None
+    fullest = max(kept, key=_peak_bytes)
+    peak = _peak_bytes(fullest)
+    span.attrs["device_bytes_in_use" + suffix] = int(
+        fullest.get("bytes_in_use", 0))
+    span.attrs["device_peak_bytes" + suffix] = peak
+    return peak
 
 
 @dataclasses.dataclass
