@@ -297,10 +297,19 @@ def _gated_out(o, x, p, eps, dtype):
 
 class KDA(nn.Module):
     """The gated delta-rule mixer. Its three parts (what feeds the scan,
-    the scan, what follows it) are each computed again in the backward
-    pass from what entered them: a layer's float32 intermediates of all
-    three together are what a 16 GB chip has no room for beside a
-    client's training state."""
+    the scan, what follows it) are each a ``jax.checkpoint``: the backward
+    pass keeps what enters and leaves a part (``x``; ``q, k, v, g, beta``;
+    ``o``) and computes the part's float32 internals again, once, when it
+    gets there. Sized by compiling the benchmark cell's round program (five
+    blocks, 4,096 tokens a step, 21 B a parameter of state around it) for a
+    v5e with ``scripts/compile_cell.py`` (PERF.md section 6, PR 42): the
+    checkpoint around the scan buys 1.6 GiB a step where the blocks keep
+    their residuals (its internals, ``A``, ``B``, the inverse and ``w`` of
+    every chunk, would live from the forward pass to the backward in all
+    four KDA layers: without it the compiler refuses the program, 16.06 of
+    15.75 GiB); the two around it 0.5 GiB together. Inside the scan the
+    chunk bodies are checkpoints again (:func:`_intra_chunk`,
+    :func:`_chunk_step`)."""
 
     heads: int
     head_dim: int = 128
@@ -430,6 +439,32 @@ class Block(nn.Module):
 
 
 class KimiLinear(nn.Module):
+    """The stack: embedding, one :class:`Block` a layer, the final norm and
+    the untied head.
+
+    **No block is computed again in the backward pass**: a block keeps what
+    its forward pass produced outside the parts that carry a checkpoint of
+    their own (the residual stream, the norms' outputs, an MLA layer's q, k,
+    v and context, the dense MLP's and the shared expert's hidden products,
+    the routed layer's dispatch and combine arrays), and the backward pass
+    computes again only what those checkpoints cover: each of a KDA
+    mixer's three parts once (:class:`KDA`), the chunk bodies inside the
+    scan, the MLA scores (``lfm2._attend``) and the experts' hidden
+    products (``models/moe.py``). ``nn.remat`` around every block would run
+    each block's whole forward a second time (a quarter of the benchmark
+    cell's round, until PR 42) to save 2.98 GiB of the cell's 4,096-token
+    step. Around one block alone it gives back, by the compiler's
+    temporaries, 0.59 GiB at the dense KDA block, 0.60 at a KDA block with
+    experts and 0.94 at the MLA block: a block keeps about 150 KB a token
+    (MLA with experts: 245 KB), and every token of a local step beyond the
+    cell's 4,096 pays that in each block. By the compiler's own check
+    (reserved 0.258 + arguments + HLO temporaries <= 15.75 GiB) the cell's
+    round program has about 0.2 GiB to spare, and the chip's peak with the
+    evaluation beside it is 14.71 GB; a longer step needs ``nn.remat`` back
+    around some blocks, the MLA one first (0.94 GiB for 4% of the round;
+    PERF.md section 6, PR 42, has every policy compiled and the chip's
+    readings)."""
+
     vocab_size: int = 163840
     max_len: int = 1048576          # positions served; no position table
     width: int = 2304
@@ -463,12 +498,12 @@ class KimiLinear(nn.Module):
             self.vocab_size, self.width, name="embed",
             embedding_init=nn.initializers.normal(stddev=0.02),
             param_dtype=jnp.float32)(tokens)
-        # The backward pass keeps the residual stream between the blocks
-        # and computes a block again for everything inside it.
-        block = nn.remat(Block)
         for i, kind in enumerate(self.layer_types):
             dense = i < self.num_dense_layers
-            h = block(
+            # A plain ``Block``: its residuals are kept (150-245 KB a token)
+            # and its forward runs once; the class docstring has what that
+            # costs and when ``nn.remat`` has to come back.
+            h = Block(
                 kind=kind, heads=self.heads, kda_head_dim=self.kda_head_dim,
                 conv_kernel=self.conv_kernel, kv_rank=self.kv_rank,
                 qk_nope_dim=self.qk_nope_dim, qk_rope_dim=self.qk_rope_dim,

@@ -10,16 +10,29 @@ model, algorithm and ``fedcore`` block, one chip, the population padded as
 (``jax.experimental.topologies``: the TPU's compiler is installed where no
 chip is), lowers ``round_step`` from shapes alone, compiles it, and prints
 one JSON line: the compiler's temporaries, arguments, generated code and
-their sum (GiB; the runtime reserves about 0.26 GiB more of the chip's
-15.75), its ``peak_memory``, the generated code in GB, and the size of the
-serialized executable under zstd, which is what the program's entry in the
-compilation cache takes (PERF.md section 7 item 9k adds the cells' entries
-up against the chip machine's cap).
+their sum (GiB), its ``peak_memory``, the generated code in GB, and the
+size of the serialized executable under zstd, which is what the program's
+entry in the compilation cache takes (PERF.md section 7 item 9k adds the
+cells' entries up against the chip machine's cap).
+
+**Which of these decides the fit: none of the sums printed, only whether the
+compile raises.** The compiler's own check is ``reserved 0.258 GiB +
+arguments + HLO temporaries <= 15.75 GiB``, and a program that fails it
+raises here what it would raise on the chip (``RESOURCE_EXHAUSTED ... Used
+16.06G of 15.75G hbm``, with the three terms). ``temporaries_gib``
+(``temp_size_in_bytes``) counts the round program's outputs too, which share
+the donated arguments' memory, so ``total_gib`` overstates what the chip must
+hold by about the arguments: ``kimi_linear_ep32.8_silo_2k`` compiles at a
+``total_gib`` of 17.85, and ``temporaries_gib - arguments_gib`` has stood
+within 0.1 GiB of the check's HLO temporaries (PERF.md section 6, PR 42).
+Programs far past the limit by that arithmetic have compiled too, with a third
+of the generated code (0.11 GB for 0.34): the compiler then fits them some
+other way, and what that costs only a chip run says. The chip's measured
+peak (``device.hbm_peak_gb``) has stood 0.4-0.6 GiB over ``peak_memory_gib``.
 
 Nothing runs and nothing is timed: these are the compiler's own sizes, not
-a chip run, and a program the compiler refuses (one that does not fit)
-raises here what it would raise on the chip. Not part of a benchmark run;
-PERF.md quotes its output wherever a change is sized before a chip call.
+a chip run. Not part of a benchmark run; PERF.md quotes its output wherever
+a change is sized before a chip call.
 """
 
 import argparse

@@ -151,6 +151,59 @@ def test_the_scans_program_holds_no_chunk_wide_solve_or_pairwise_array(what):
         assert largest == km.CHUNK * km.SUB * K, largest
 
 
+def _checkpoints(jaxpr, inside=False):
+    """Every ``jax.checkpoint`` equation of a jaxpr, at any depth: whether
+    it lies inside another one, and the primitives it holds."""
+    for eqn in jaxpr.eqns:
+        held = eqn.primitive.name == "remat2"
+        if held:
+            yield inside, {e.primitive.name
+                           for e in _equations(eqn.params["jaxpr"])}
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _checkpoints(sub, inside or held)
+
+
+@pytest.mark.parametrize("layer_types,dense", [
+    (["kda"], 1), (["kda"], 0), (["mla"], 0),
+    (["kda", "kda", "kda", "mla", "kda"], 1),       # the benchmark cell's
+], ids=["kda_dense", "kda_experts", "mla_experts", "stack"])
+def test_the_backward_pass_computes_no_block_again(layer_types, dense):
+    """The intent, pinned: in the gradient's program no ``jax.checkpoint``
+    holds a block (a pre-norm's ``rsqrt`` beside a scan, attention scores
+    or grouped products). What it holds, a layer: a KDA mixer's three parts
+    (the scan's with the two chunk bodies inside it, again checkpoints; the
+    two around it with the L2 and per-head norms and no scan), the chunk
+    bodies of the forward scans, ``lfm2._attend``'s around an MLA mixer's
+    scores (no norm inside it) and ``moe``'s around the experts' hidden
+    products; a dense MLP none. ``nn.remat`` around ``Block`` again adds
+    one that holds everything; a part-level checkpoint dropped takes its
+    own away (PERF.md section 6, PR 42, has what each buys)."""
+    model = get_model("kimi_linear").build(**dict(
+        TINY, layer_types=layer_types, num_dense_layers=dense), dtype=F32)
+    tokens = jnp.asarray(np.random.default_rng(8).integers(1, 128, (2, L)))
+    params = model.init(jax.random.key(1), tokens)["params"]
+    held = list(_checkpoints(jax.make_jaxpr(jax.grad(
+        lambda p: (model.apply({"params": p}, tokens) ** 2).mean()))(
+            params).jaxpr))
+    outer = [names for inside, names in held if not inside]
+    kda, mla = layer_types.count("kda"), layer_types.count("mla")
+    experts = len(layer_types) - dense
+    assert not any("rsqrt" in names and names & {
+        "scan", "reduce_max", "ragged_dot_general"} for names in outer)
+    scans = [names for names in outer if "scan" in names]
+    assert len(scans) == kda and all("remat2" in names for names in scans)
+    # ``_intra_chunk`` and ``_chunk_step`` inside each scan's checkpoint.
+    assert len(held) - len(outer) == 2 * kda
+    around = [names for names in outer if "rsqrt" in names]
+    assert len(around) == 2 * kda
+    assert all("logistic" in names for names in around)
+    scores = [names for names in outer if "reduce_max" in names]
+    assert len(scores) == mla and all("exp" in names for names in scores)
+    assert sum("ragged_dot_general" in names for names in outer) == experts
+    # The rest: the two chunk bodies of each forward scan.
+    assert len(outer) == 5 * kda + mla + experts
+
+
 @pytest.mark.parametrize("kind", ["kda", "mla"])
 def test_each_mixer_matches_the_reference(kind):
     module, reference = {
