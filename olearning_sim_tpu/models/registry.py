@@ -78,7 +78,7 @@ def get_model(name: str) -> ModelSpec:
     import importlib.util
 
     for mod in ("mlp", "cnn", "resnet", "transformer", "vit", "moe", "lfm2",
-                "kimi_linear", "nemotron_h"):
+                "kimi_linear", "nemotron_h", "phi4flash"):
         qual = f"olearning_sim_tpu.models.{mod}"
         # Only true absence is optional; a present-but-broken module raises.
         if importlib.util.find_spec(qual) is not None:
