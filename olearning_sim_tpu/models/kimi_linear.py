@@ -49,7 +49,8 @@ inverse of a chunk's triangular system and the products with any of them
 The embedding is only looked up (:class:`~olearning_sim_tpu.models.lookup.
 LookupOnlyEmbed`), so a trainer may train it by the rows a step reads. Every
 KDA layer sows ``kda_stats`` (:data:`STATS`): the tokens and the chunks its
-scan took.
+scan took; every MLA layer, the pairs its mask lets through and the scores it
+formed.
 """
 
 from __future__ import annotations
@@ -63,7 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from olearning_sim_tpu.models.lfm2 import (
-    RMSNorm, SwiGLU, _attend, _dense_init, _mm)
+    RMSNorm, SwiGLU, _attend, _dense_init, _mm, sown_attend_pairs)
 from olearning_sim_tpu.models.lookup import LookupOnlyEmbed
 from olearning_sim_tpu.models import moe
 from olearning_sim_tpu.models.registry import ModelSpec, register_model
@@ -75,8 +76,12 @@ CHUNK = 64
 # and row-by-row substitution inside one, matrix products between them.
 SUB = 16
 L2_EPS = 1e-6
-# What a KDA layer sows as ``kda_stats`` on every call, one int32 vector.
-STATS = ("scan_tokens", "scan_chunks")
+# What a KDA or MLA layer sows as ``kda_stats`` on every call, one int32
+# vector: the tokens and chunks of a KDA layer's scan, the (query, key)
+# pairs an MLA layer's mask lets through, a head, and the scores a head
+# formed for them (``lfm2.attend_pairs``).
+STATS = ("kda_scan_tokens", "kda_scan_chunks", "attend_pairs_needed",
+         "attend_pairs_computed")
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -344,7 +349,7 @@ class KDA(nn.Module):
             y = jax.checkpoint(_gated_out, static_argnums=(3, 4))(
                 o, x, p, self.eps, self.dtype)
         self.sow("intermediates", "kda_stats",
-                 jnp.asarray([n * L, n * -(-L // CHUNK)], jnp.int32))
+                 jnp.asarray([n * L, n * -(-L // CHUNK), 0, 0], jnp.int32))
         return y
 
 
@@ -379,10 +384,12 @@ class MLA(nn.Module):
                 [Dn], axis=-1)
             k = jnp.concatenate(
                 [k_n, jnp.broadcast_to(k_r[:, :, None], (n, L, H, Dr))], -1)
-            # One query head a key/value head; the scores are recomputed
-            # in the backward pass.
+            # One query head a key/value head; by query blocks, the scores
+            # recomputed in the backward pass.
             ctx = _attend(q, k, v)
-            return _mm(ctx.reshape(n, L, H * Dv), out_proj, self.dtype)
+            out = _mm(ctx.reshape(n, L, H * Dv), out_proj, self.dtype)
+        self.sow("intermediates", "kda_stats", sown_attend_pairs(n, L, 2))
+        return out
 
 
 class Block(nn.Module):
@@ -535,8 +542,7 @@ register_model(
         # DroplessMoE's jax.lax.ragged_dot has no batching rule for
         # per-client expert weights.
         vmap_clients=False,
-        work_counts=moe.work_counts_beside(
-            "kda_stats", tuple("kda_" + name for name in STATS)),
+        work_counts=moe.work_counts_beside("kda_stats", STATS),
         defaults={
             "vocab_size": 163840, "max_len": 1048576, "width": 2304,
             "layer_types": ["kda", "kda", "kda", "mla"],

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from olearning_sim_tpu.models.moe import WORK_COUNTS, DroplessMoE
+from olearning_sim_tpu.models.moe import DroplessMoE, work_counts_beside
 from olearning_sim_tpu.models.registry import ModelSpec, register_model
 
 _dense_init = nn.initializers.lecun_normal()
@@ -97,18 +97,71 @@ def _rotary(x, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@jax.checkpoint
-def _attend(q, k, v):
-    """Causal softmax attention of q [n, L, G, R, D] over k, v [n, L, G, D]
-    (R query heads a key/value head), scores and softmax in float32. The
-    backward pass recomputes the L x L scores rather than keep them."""
-    L, D = q.shape[1], q.shape[-1]
+# What an attention layer sows as ``lfm2_stats`` on every call, one int32
+# vector: the (query, key) pairs its causal mask lets through, a head, and
+# the scores a head formed for them (:func:`attend_pairs`, by the sequences).
+STATS = ("attend_pairs_needed", "attend_pairs_computed")
+# Queries a block of :func:`_attend`: phi4flash's window block, and a whole
+# number of MXU tiles. 256 takes 0.42 to 0.82 of the time on the chip and
+# doubles the blocks' code, which set-up pays (PERF.md section 6, PR 45).
+BLOCK = 512
+
+
+def _query_blocks(L: int, block: int):
+    """(first query, end) of each block of ``block`` queries of ``L``; the
+    last may be shorter."""
+    return [(start, min(L, start + block)) for start in range(0, L, block)]
+
+
+def attend_pairs(L: int, block: int = BLOCK) -> Tuple[int, int]:
+    """(the (query, key) pairs causal attention over ``L`` tokens needs a
+    head, the scores :func:`_attend` forms for them): every query block
+    against the keys up to its own end."""
+    return (L * (L + 1) // 2,
+            sum((end - start) * end for start, end in _query_blocks(L, block)))
+
+
+def sown_attend_pairs(n: int, L: int, before: int = 0):
+    """What a layer that called :func:`_attend` on ``n`` sequences of ``L``
+    tokens sows: :func:`attend_pairs` times ``n``, after ``before`` zeros
+    (the counts of the model's other layers)."""
+    return jnp.asarray(
+        [0] * before + [n * pairs for pairs in attend_pairs(L)], jnp.int32)
+
+
+def _attend_prefix(q, k, v):
+    """Softmax attention of the LAST ``Q`` queries of a prefix, q [n, Q, G,
+    R, D], over all of its keys k [n, P, G, D] and v [n, P, G, Dv]: query
+    ``i`` sees keys ``0 .. P - Q + i``."""
+    Q, P, D = q.shape[1], k.shape[1], q.shape[-1]
     scores = jnp.einsum("nqgrd,nkgd->ngrqk", q, k,
                         preferred_element_type=jnp.float32) / np.sqrt(D)
-    causal = np.tril(np.ones((L, L), bool))
+    causal = jnp.arange(P) <= jnp.arange(P - Q, P)[:, None]
     probs = jax.nn.softmax(
         jnp.where(causal, scores, jnp.finfo(jnp.float32).min), -1)
     return jnp.einsum("ngrqk,nkgd->nqgrd", probs.astype(q.dtype), v)
+
+
+def _attend(q, k, v, block: int = BLOCK):
+    """Causal softmax attention of q [n, L, G, R, D] over k [n, L, G, D] and
+    v [n, L, G, Dv] (R query heads a key/value head), scores and softmax in
+    float32. By blocks of ``block`` queries: a block scores the keys up to
+    its own end and no others, so a row's softmax is over exactly the keys
+    it sees, the largest score array is ``block x L`` a head, and of the
+    ``L x L`` pairs :func:`attend_pairs` are formed (5/8 at four blocks).
+    A last block shorter than ``block`` is a shorter block. One
+    ``jax.checkpoint`` around all of it: the backward pass recomputes the
+    scores rather than keep them."""
+    L = q.shape[1]
+    if L <= block:
+        return jax.checkpoint(_attend_prefix)(q, k, v)
+
+    def blocks(q, k, v):
+        return jnp.concatenate([
+            _attend_prefix(q[:, start:end], k[:, :end], v[:, :end])
+            for start, end in _query_blocks(L, block)], 1)
+
+    return jax.checkpoint(blocks)(q, k, v)
 
 
 class CausalGQA(nn.Module):
@@ -139,7 +192,9 @@ class CausalGQA(nn.Module):
             k = _rotary(k_norm(k), self.rope_theta)
             ctx = _attend(q.reshape(n, L, G, H // G, D).astype(self.dtype),
                           k.astype(self.dtype), v)
-            return _mm(ctx.reshape(n, L, H * D), wo, self.dtype)
+            out = _mm(ctx.reshape(n, L, H * D), wo, self.dtype)
+        self.sow("intermediates", "lfm2_stats", sown_attend_pairs(n, L))
+        return out
 
 
 class SwiGLU(nn.Module):
@@ -260,7 +315,7 @@ register_model(
         # DroplessMoE's jax.lax.ragged_dot has no batching rule for
         # per-client expert weights.
         vmap_clients=False,
-        work_counts=WORK_COUNTS,
+        work_counts=work_counts_beside("lfm2_stats", STATS),
         defaults={
             "vocab_size": 65536, "max_len": 128000, "width": 2048,
             "layer_types": ["conv", "conv", "full_attention", "conv"],

@@ -288,10 +288,6 @@ def gather_stats(intermediates):
     return jnp.stack(found) if found else None
 
 
-# For the ``ModelSpec`` of a model whose only counted layers are these.
-WORK_COUNTS = WorkCounts(gather_stats, describe_stats)
-
-
 def work_counts_beside(sown_name: str, names: Tuple[str, ...]) -> WorkCounts:
     """For the ``ModelSpec`` of a model whose mixers sow counts of their own
     (``sown_name``: one int32 vector a layer, ``len(names)`` long) beside

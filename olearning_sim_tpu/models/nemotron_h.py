@@ -50,7 +50,8 @@ them (``Precision.HIGHEST``).
 The embedding is only looked up (:class:`~olearning_sim_tpu.models.lookup.
 LookupOnlyEmbed`), so a trainer may train it by the rows a step reads. Every
 Mamba-2 layer sows ``ssd_stats`` (:data:`STATS`): the tokens and the chunks
-its scan took.
+its scan took; every attention layer, the pairs its mask lets through and the
+scores it formed.
 """
 
 from __future__ import annotations
@@ -64,13 +65,18 @@ import numpy as np
 
 from olearning_sim_tpu.models.kimi_linear import (
     _a_log_init, _causal_taps, _dt_bias_init)
-from olearning_sim_tpu.models.lfm2 import RMSNorm, _attend, _dense_init, _mm
+from olearning_sim_tpu.models.lfm2 import (
+    RMSNorm, _attend, _dense_init, _mm, sown_attend_pairs)
 from olearning_sim_tpu.models.lookup import LookupOnlyEmbed
 from olearning_sim_tpu.models import moe
 from olearning_sim_tpu.models.registry import ModelSpec, register_model
 
-# What a Mamba-2 layer sows as ``ssd_stats`` on every call, one int32 vector.
-STATS = ("scan_tokens", "scan_chunks")
+# What a Mamba-2 or attention layer sows as ``ssd_stats`` on every call, one
+# int32 vector: the tokens and chunks of a Mamba-2 layer's scan, the (query,
+# key) pairs an attention layer's mask lets through, a head, and the scores
+# a head formed for them (``lfm2.attend_pairs``).
+STATS = ("ssd_scan_tokens", "ssd_scan_chunks", "attend_pairs_needed",
+         "attend_pairs_computed")
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -219,7 +225,8 @@ class Mamba2(nn.Module):
         with jax.named_scope("ssd.projections"):
             out = _gated_out(y, x, z, p, g, self.eps, self.dtype)
         self.sow("intermediates", "ssd_stats",
-                 jnp.asarray([n * L, n * (L // self.chunk_size)], jnp.int32))
+                 jnp.asarray([n * L, n * (L // self.chunk_size), 0, 0],
+                             jnp.int32))
         return out
 
 
@@ -243,9 +250,12 @@ class Attention(nn.Module):
             q = _mm(x, wq, self.dtype).reshape(n, L, G, H // G, D)
             k = _mm(x, wk, self.dtype).reshape(n, L, G, D)
             v = _mm(x, wv, self.dtype).reshape(n, L, G, D)
-            # The scores are recomputed in the backward pass.
+            # By query blocks; the scores are recomputed in the backward
+            # pass.
             ctx = _attend(q, k, v)
-            return _mm(ctx.reshape(n, L, H * D), wo, self.dtype)
+            out = _mm(ctx.reshape(n, L, H * D), wo, self.dtype)
+        self.sow("intermediates", "ssd_stats", sown_attend_pairs(n, L, 2))
+        return out
 
 
 class ReLU2(nn.Module):
@@ -270,8 +280,9 @@ class Layer(nn.Module):
 
     What a layer leaves for the backward pass when nothing computes it again
     (:class:`NemotronH` decides by ``kind``; bfloat16 unless said): ``"*"``
-    q, k, v and the context, 17 KB a token (the L x L scores never:
-    ``lfm2._attend`` computes them again); ``"E"`` the per-assignment arrays,
+    q, k, v and the context, 17 KB a token (the scores never:
+    ``lfm2._attend`` computes them again, a block of queries against the
+    keys up to its end); ``"E"`` the per-assignment arrays,
     ``experts_per_token`` rows a token (the gathered inputs, both grouped
     products' results, the float32 combine) and the shared expert's hidden
     product, about 140 KB a token; ``"M"`` the fused projection's
@@ -418,8 +429,7 @@ register_model(
         # DroplessMoE's jax.lax.ragged_dot has no batching rule for
         # per-client expert weights.
         vmap_clients=False,
-        work_counts=moe.work_counts_beside(
-            "ssd_stats", tuple("ssd_" + name for name in STATS)),
+        work_counts=moe.work_counts_beside("ssd_stats", STATS),
         defaults={
             "vocab_size": 131072, "max_len": 262144, "width": 2688,
             "pattern": "MEMEM*E", "mamba_heads": 64, "mamba_head_dim": 64,

@@ -41,8 +41,9 @@ six things by the layer's PUBLISHED index ``i`` (:func:`layer_kind`):
   pair), then ``a W_o + b_o``; the mask lets query ``t`` see keys ``t -
   window + 1 .. t`` and the scores are computed only for the key blocks the
   window reaches (:func:`window_attend`);
-- **F**: the same with the whole causal prefix, and it hands on its ``k``
-  and ``v``;
+- **F**: the same with the whole causal prefix (``lfm2._attend``: a block of
+  queries against the keys up to its end), and it hands on its ``k`` and
+  ``v``;
 - **C**, cross-attention: ``q = u W_q + b`` only; keys and values are the F
   layer's; the same differential attention, causal, its own lambda vectors,
   sub-norm and ``W_o``.
@@ -61,7 +62,8 @@ the residual stream, the norms, the convolution, softplus, softmax, lambda,
 the logits, and everything inside the recurrence (what feeds it leaves its
 projection in float32; every exponent is ``D_t A <= 0``).
 
-Every layer with a scan or a window sows ``phi4flash_stats`` (:data:`STATS`).
+Every layer with a scan or attention sows ``phi4flash_stats``
+(:data:`STATS`).
 """
 
 from __future__ import annotations
@@ -77,15 +79,18 @@ import numpy as np
 from olearning_sim_tpu.models import moe
 from olearning_sim_tpu.models.kimi_linear import (
     _a_log_init, _causal_taps, _dt_bias_init)
-from olearning_sim_tpu.models.lfm2 import SwiGLU, _attend, _dense_init, _mm
+from olearning_sim_tpu.models.lfm2 import (
+    SwiGLU, _attend, _dense_init, _mm, attend_pairs, sown_attend_pairs)
 from olearning_sim_tpu.models.registry import ModelSpec, register_model
 
-# What a layer with a scan or a window sows as ``phi4flash_stats`` on every
+# What a layer with a scan or attention sows as ``phi4flash_stats`` on every
 # call, one int32 vector: the tokens and chunks its scan took, the
 # (query, key) pairs of a sequence's window a head needs and the scores a
-# head formed for them (masked ones among them).
+# head formed for them (masked ones among them), and the same two of an F or
+# C layer's whole causal prefix (``lfm2.attend_pairs``).
 STATS = ("sscan_tokens", "sscan_chunks", "window_attn_pairs_needed",
-         "window_attn_pairs_computed")
+         "window_attn_pairs_computed", "attend_pairs_needed",
+         "attend_pairs_computed")
 # Tokens between two states the scan's backward pass keeps, and tokens a
 # step of the loop inside a chunk (measured on the chip at the benchmark
 # cell's shapes, PERF.md section 6, PR 44: 64 and 8 were the fastest pair).
@@ -155,11 +160,13 @@ def selective_scan(x, dt, A, B, C, chunk: int = CHUNK):
 def window_pairs(L: int, window: int) -> Tuple[int, int]:
     """(the (query, key) pairs a head's window needs over a sequence of
     ``L`` tokens, the scores :func:`window_attend` forms for them): every
-    query block against itself and, but for the first, the one before."""
+    query block against itself and, but for the first, the one before;
+    inside one window, what ``lfm2._attend`` forms."""
     reach = min(L, window)
     blocks = -(-L // window)
     return (L * reach - reach * (reach - 1) // 2,
-            (2 * blocks - 1) * window * window if L > window else L * L)
+            (2 * blocks - 1) * window * window if L > window
+            else attend_pairs(L)[1])
 
 
 @functools.partial(jax.checkpoint, static_argnums=(3,))
@@ -245,7 +252,7 @@ class Mamba(nn.Module):
             y = y + D * x
             out = _mm(jax.nn.silu(z.astype(f32)) * y, out_proj, self.dtype)
         self.sow("intermediates", "phi4flash_stats", jnp.asarray(
-            [n * L, n * -(-L // CHUNK), 0, 0], jnp.int32))
+            [n * L, n * -(-L // CHUNK), 0, 0, 0, 0], jnp.int32))
         return out, y
 
 
@@ -333,7 +340,10 @@ class DiffAttention(nn.Module):
         if self.window:
             needed, computed = window_pairs(L, self.window)
             self.sow("intermediates", "phi4flash_stats", jnp.asarray(
-                [0, 0, n * needed, n * computed], jnp.int32))
+                [0, 0, n * needed, n * computed, 0, 0], jnp.int32))
+        else:
+            self.sow("intermediates", "phi4flash_stats",
+                     sown_attend_pairs(n, L, 4))
         return out, k, v
 
 

@@ -243,13 +243,14 @@ def test_a_kda_layer_counts_its_scans_tokens_and_chunks():
     params = layer.init(jax.random.key(0), x)["params"]
     _, inter = layer.apply({"params": params}, x, mutable=["intermediates"])
     (stats,) = inter["intermediates"]["kda_stats"]
-    assert np.asarray(stats).tolist() == [3 * L, 3 * 2]
+    assert np.asarray(stats).tolist() == [3 * L, 3 * 2, 0, 0]
     # Two such layers' counts, gathered and named as the registry has it.
     counts = get_model("kimi_linear").work_counts
     row = counts.gather({"a": inter["intermediates"],
                          "b": inter["intermediates"]})
     assert counts.describe(np.asarray(row)) == {
-        "kda_scan_tokens": 2 * 3 * L, "kda_scan_chunks": 2 * 3 * 2}
+        "kda_scan_tokens": 2 * 3 * L, "kda_scan_chunks": 2 * 3 * 2,
+        "attend_pairs_needed": 0, "attend_pairs_computed": 0}
 
 
 def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():

@@ -80,6 +80,128 @@ def test_each_dense_block_kind_matches_the_reference(kind):
         _close(g, want[0][name])
 
 
+def _plain_attend(q, k, v):
+    """The form ``lfm2._attend`` replaced (PR 45): all L x L scores, the
+    upper triangle masked, one float32 softmax a row."""
+    n_keys, D = k.shape[1], q.shape[-1]
+    scores = jnp.einsum("nqgrd,nkgd->ngrqk", q, k,
+                        preferred_element_type=F32) / np.sqrt(D)
+    causal = np.tril(np.ones((n_keys, n_keys), bool))
+    probs = jax.nn.softmax(
+        jnp.where(causal, scores, jnp.finfo(F32).min), -1)
+    return jnp.einsum("ngrqk,nkgd->nqgrd", probs.astype(q.dtype), v)
+
+
+# A block of 16 queries, so that four blocks run in seconds here.
+B = 16
+# (key/value heads, query heads a key/value head, D, Dv): GQA; MLA's one
+# query head a key head and 192-wide keys for 128-wide values; phi4flash's
+# 64-wide keys for a pair's 128-wide values.
+HEADS = {"gqa": (2, 3, 8, 8), "mla": (4, 1, 12, 8), "diff": (2, 2, 8, 16)}
+
+
+def _qkv(length, heads, seed=0, lead=(2,)):
+    G, R, D, Dv = HEADS[heads]
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal(lead + shape), F32)
+                 for shape in ((length, G, R, D), (length, G, D),
+                               (length, G, Dv)))
+
+
+def _loss_and_grads(attend, over_clients=False):
+    """``sum(attend(q, k, v) ** 2)`` and its gradients, compiled."""
+    program = jax.value_and_grad(
+        lambda q, k, v: (attend(q, k, v) ** 2).sum(), (0, 1, 2))
+    return jax.jit(jax.vmap(program) if over_clients else program)
+
+
+def _blocked(q, k, v):
+    return lfm2_model._attend(q, k, v, B)
+
+
+@pytest.mark.parametrize("heads", sorted(HEADS))
+@pytest.mark.parametrize("length", [B // 2, B, 2 * B, 3 * B + 37])
+def test_attention_by_blocks_is_the_masked_l_by_l_attention(length, heads):
+    """Values and the gradients of q, k and v: below a block, one block,
+    whole blocks, and a last block shorter than the others."""
+    q, k, v = _qkv(length, heads, seed=length)
+    _close(jax.jit(_blocked)(q, k, v), _plain_attend(q, k, v), 1e-5)
+    (got, got_grads), (want, want_grads) = (
+        _loss_and_grads(f)(q, k, v) for f in (_blocked, _plain_attend))
+    _close(got, want, 1e-5)
+    for g, w in zip(got_grads, want_grads):
+        _close(g, w, 1e-5)
+
+
+@pytest.mark.parametrize("heads", sorted(HEADS))
+def test_attention_by_blocks_over_clients(heads):
+    """Three clients, each with its own q, k and v, under ``jax.vmap``."""
+    args = _qkv(3 * B + 37, heads, seed=4, lead=(3, 2))
+    (got, got_grads), (want, want_grads) = (
+        _loss_and_grads(f, over_clients=True)(*args)
+        for f in (_blocked, _plain_attend))
+    _close(got, want, 1e-5)
+    for g, w in zip(got_grads, want_grads):
+        _close(g, w, 1e-5)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr, at any depth."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from _equations(inner)
+
+
+def test_the_blocked_program_holds_no_l_by_l_array_and_one_checkpoint():
+    """At four blocks: the float32 scores a head are the blocks'
+    (``attend_pairs``), never L x L, forward and backward; one
+    ``jax.checkpoint`` equation around all of them."""
+    length = 4 * B
+    q, k, v = _qkv(length, "gqa")
+    heads = q.shape[0] * q.shape[2] * q.shape[3]
+    forward = list(_equations(jax.make_jaxpr(_blocked)(q, k, v).jaxpr))
+    scores = [e.outvars[0].aval.shape for e in forward
+              if e.primitive.name == "exp"]
+    assert len(scores) == 4 and {np.prod(s[:-2]) for s in scores} == {heads}
+    assert sum(s[-2] * s[-1] for s in scores) == lfm2_model.attend_pairs(
+        length, B)[1] == B * B * (1 + 2 + 3 + 4)
+    backward = list(_equations(jax.make_jaxpr(jax.grad(
+        lambda *a: (_blocked(*a) ** 2).sum(), (0, 1, 2)))(q, k, v).jaxpr))
+    assert sum(e.primitive.name == "remat2" for e in backward) == 1
+    assert any(e.primitive.name == "exp" for e in backward)
+    for eqn in forward + backward:
+        for var in eqn.outvars:
+            assert np.prod(var.aval.shape) < heads * length * length, (
+                eqn.primitive.name, var.aval.shape)
+
+
+@pytest.mark.parametrize("length,block", [
+    (1, 16), (8, 16), (16, 16), (17, 16), (64, 16), (85, 16),
+    (1024, 256), (2048, 256), (2048, 512), (2085, 512)])
+def test_attend_pairs_against_a_brute_count(length, block):
+    rows = np.arange(length)
+    # Row i is in the block that ends at key ``end[i]`` (exclusive).
+    end = np.minimum((rows // block + 1) * block, length)
+    assert lfm2_model.attend_pairs(length, block) == (
+        int((rows + 1).sum()), int(end.sum()))
+    if length <= block:
+        assert lfm2_model.attend_pairs(length, block)[1] == length * length
+
+
+def test_an_attention_layer_sows_the_pairs_of_its_sequences():
+    layer = lfm2_model.CausalGQA(4, 2, dtype=F32)
+    x = _x(2, n=3)
+    params = layer.init(jax.random.key(0), x)["params"]
+    _, inter = layer.apply({"params": params}, x, mutable=["intermediates"])
+    (stats,) = inter["intermediates"]["lfm2_stats"]
+    assert np.asarray(stats).tolist() == [3 * L * (L + 1) // 2, 3 * L * L]
+    assert lfm2_model.STATS == ("attend_pairs_needed",
+                                "attend_pairs_computed")
+
+
 def _moe(held, top_k=2, experts=8, **kw):
     return DroplessMoE(experts, top_k, tuple(held), 48, dtype=F32, **kw)
 
@@ -261,6 +383,9 @@ def test_a_next_token_round_and_evaluation_match_the_reference_round():
     assert named["moe_assignments_local"] == named[
         "moe_assignments_computed"] > 0
     assert named["moe_expert_load_max"] >= named["moe_expert_load_mean"] > 0
+    # One attention layer: a sequence's causal half, and its one block.
+    assert named["attend_pairs_needed"] == 4 * 2 * 4 * (L * (L + 1) // 2)
+    assert named["attend_pairs_computed"] == 4 * 2 * 4 * L * L
 
     x, y = make_central_text_eval_set(2**31 + 9, 8, L, 4, vocab_size=128)
     loss, acc = core.evaluate(state.params, x, y)

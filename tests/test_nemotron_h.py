@@ -174,13 +174,14 @@ def test_a_mamba_layer_counts_its_scans_tokens_and_chunks():
     params = layer.init(jax.random.key(0), x)["params"]
     _, inter = layer.apply({"params": params}, x, mutable=["intermediates"])
     (stats,) = inter["intermediates"]["ssd_stats"]
-    assert np.asarray(stats).tolist() == [3 * L, 3 * 5]
+    assert np.asarray(stats).tolist() == [3 * L, 3 * 5, 0, 0]
     # Two such layers' counts, gathered and named as the registry has it.
     counts = get_model("nemotron_h").work_counts
     row = counts.gather({"a": inter["intermediates"],
                          "b": inter["intermediates"]})
     assert counts.describe(np.asarray(row)) == {
-        "ssd_scan_tokens": 2 * 3 * L, "ssd_scan_chunks": 2 * 3 * 5}
+        "ssd_scan_tokens": 2 * 3 * L, "ssd_scan_chunks": 2 * 3 * 5,
+        "attend_pairs_needed": 0, "attend_pairs_computed": 0}
 
 
 @pytest.mark.parametrize("gated", [True, False])
@@ -440,9 +441,9 @@ def test_the_backward_pass_computes_only_the_mamba_layers_again():
 
 @pytest.mark.parametrize("config,digest", [
     ("lfm2_moe_ep8",
-     "e9f857dff430b6a4ab7bc2fba023017241ab76c943aac6b3e52f0f3fd554b3af"),
+     "5acc49ca9bde1302f860401ea341ea72f9c892879a47e1d274daa0abd0e5f5d1"),
     ("kimi_linear_ep32",
-     "d70e98044ba44a5932396a1baca6a176a1ed38dd19b1e77c47f84d1d97566171"),
+     "d4a2409ed05fc5bf9e79da5f9b348d514362fa5c571c77ae8f65819888c86645"),
 ])
 def test_the_other_sparse_decoders_round_programs_did_not_move(
         config, digest):
@@ -451,9 +452,12 @@ def test_the_other_sparse_decoders_round_programs_did_not_move(
     they lowered to before this family chose its layers to compute again
     (PR 40: the digests are the parent commit's), so their compilation
     cache keys stand. An intended edit to those families pins them anew
-    (the failure prints the new digest): ``kimi_linear_ep32``'s is PR 42's,
-    whose program changed on purpose (no ``nn.remat`` around its blocks);
-    ``lfm2_moe_ep8``'s is still PR 40's parent's."""
+    (the failure prints the new digest): both are PR 45's, whose attention
+    layers sow ``attend_pairs_needed`` and ``attend_pairs_computed`` (one
+    more row of ``model_stats`` in ``lfm2_moe_ep8``, two more counts a
+    vector in ``kimi_linear_ep32``) and whose ``lfm2._attend`` builds its
+    mask from two ``iota``s where it held an L x L constant; at these
+    presets' 16 and 80 tokens it is one block, as it was."""
     def read(*path):
         with open(os.path.join(*path, config + ".json")) as f:
             return json.load(f)

@@ -391,13 +391,16 @@ def test_a_round_and_evaluation_match_the_reference_round_and_count_their_work()
     # The tied table takes a dense gradient through the head: every row moves.
     assert (np.abs(delta["embed/embedding"]).sum(-1) > 0).all()
     # The round's work counts: 3 clients x 2 steps x 2 sequences through one
-    # scan layer (2 chunks of 64 a sequence) and one window layer (5 blocks).
+    # scan layer (2 chunks of 64 a sequence), one window layer (5 blocks) and
+    # the F and C layers' causal prefix (one block: L x L).
     named = core.describe_stats(np.asarray(metrics.model_stats))
     sequences = 3 * 2 * 2
     assert named == {
         "sscan_tokens": sequences * L, "sscan_chunks": sequences * 2,
         "window_attn_pairs_needed": sequences * pf.window_pairs(L, WINDOW)[0],
-        "window_attn_pairs_computed": sequences * 9 * WINDOW * WINDOW}
+        "window_attn_pairs_computed": sequences * 9 * WINDOW * WINDOW,
+        "attend_pairs_needed": sequences * 2 * (L * (L + 1) // 2),
+        "attend_pairs_computed": sequences * 2 * L * L}
 
     x, y = make_central_text_eval_set(2**31 + 9, 4, L, 4, vocab_size=128)
     loss, acc = core.evaluate(state.params, x, y)
