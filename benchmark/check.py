@@ -33,9 +33,8 @@ After the window has closed and the task is STOPPED, on the finished runner:
      so it carries the tight limit;
    - ``pseudo_grad_rel_l2``: the same by the worst leaf, over the
      reference's norm of that leaf or of the median leaf, whichever is
-     larger (some leaves' deltas are all but zero). A widest gap: a small
-     bias leaf's gradient is a sum with heavy cancellation and swings from
-     seed to seed, so its limit is loose;
+     larger (some leaves' deltas are all but zero). A widest gap: it swings
+     from seed to seed, so its limit is loose;
    - ``param_delta_global_rel_l2`` / ``param_delta_rel_l2``: the same two on
      the change of the global parameters (new - old), which is what the
      server step makes of it (an unchanged state reads exactly 1);
@@ -44,17 +43,26 @@ After the window has closed and the task is STOPPED, on the finished runner:
      than the difference's norm where bfloat16 inputs make the elementwise
      difference noisy).
 
-   **Which leaves count in the two worst-leaf numbers of the parameters'
-   change:** those of more than ``FEW_ELEMENTS`` elements. A leaf of a few
-   elements has nothing to average over, and under a server optimizer with
-   memory its change is the small remainder of the carried first moment and
-   the check round's gradient, which can all but cancel: DistilBERT's
-   2-class head bias moves as (u, -u), one number, and read 0.072 where the
-   worst of the 101 other leaves read 0.0016 (PERF.md section 2 has the
-   look). Such a leaf stays in the whole-vector number, in every
-   pseudo-gradient number, and is printed every run in the check's detail
-   as ``param_delta_few_elements_gap``, which no limit judges. The rule is on
-   the reference's shapes, not on a name.
+   **Which leaves count in the four worst-leaf numbers:** those of more
+   than ``FEW_ELEMENTS`` elements. A leaf of a few elements has nothing to
+   average over. DistilBERT's 2-class head bias moves as (u, -u), one
+   number. Its gradient is a sum of per-sample ``p - y`` with heavy
+   cancellation: where a sampled client's local steps amplify a rounding
+   (the float32 reference itself, fed bfloat16 matmul inputs, drifts as far
+   as the program does) and the four clients' deltas all but cancel, that
+   one number read 0.635 of the median leaf's norm where the worst of the
+   101 other leaves read 0.264 (the driver's seed 1955436291, PR 46). And
+   under a server optimizer with memory its change is the small remainder
+   of the carried first moment and the check round's gradient (0.072 where
+   the worst of the others read 0.0016, PR 32). Under the lower-precision
+   control the leaf reads 0.29-1.88 (its change 0.020-1.5), inside the
+   sound runs' range: it separates nothing.
+   Such a leaf stays in both whole-vector numbers and is printed every run
+   in the check's detail as ``pseudo_grad_few_elements_gap`` and
+   ``param_delta_few_elements_gap``, which no limit judges. The rule is on
+   the reference's shapes, not on a name; of the benchmark's models only
+   DistilBERT has such a leaf (the decoders' smallest have 32 and 64
+   elements). PERF.md section 2 has both looks.
 
    The deltas, not the parameters, are compared: parameters barely move in
    one round, so any comparison of them passes whatever the round did.
@@ -294,7 +302,7 @@ def run_check(runner, cell, task: Dict[str, Any], seed: int,
     loss_gap = float(np.max(
         np.abs(program_loss.astype(np.float64) - ref_loss)
         / np.maximum(np.abs(ref_loss), 1e-6)))
-    grad = worst_leaf(program_grad, ref["mean_delta"])
+    grad = worst_leaf(program_grad, ref["mean_delta"], FEW_ELEMENTS)
     delta = worst_leaf(program_delta, ref["param_delta"], FEW_ELEMENTS)
     numbers = {
         "clients_trained_gap": float(abs(trained - len(sample))),
@@ -311,6 +319,7 @@ def run_check(runner, cell, task: Dict[str, Any], seed: int,
               "server_count": None if opt0 is None else opt0["count"],
               "worst_leaf": {"pseudo_grad": grad["leaf"],
                              "param_delta": delta["leaf"]},
+              "pseudo_grad_few_elements_gap": grad["few_elements_gap"],
               "param_delta_few_elements_gap": delta["few_elements_gap"],
               "client_loss": [[round(float(p), 5), round(float(r), 5)]
                               for p, r in zip(program_loss, ref_loss)]}
@@ -321,7 +330,7 @@ def run_check(runner, cell, task: Dict[str, Any], seed: int,
             model, server, config["algorithm"], params0, opt0, clients,
             base_key, round_idx, steps=steps - 1,
             batch_size=int(fed["batch_size"]))
-        w = worst_leaf(program_grad, short["mean_delta"])
+        w = worst_leaf(program_grad, short["mean_delta"], FEW_ELEMENTS)
         detail["planted"]["last_step_dropped"] = {
             "pseudo_grad_global_rel_l2": w["global_rel_l2"],
             "pseudo_grad_rel_l2": w["rel_l2"],

@@ -6,8 +6,11 @@ chunks the program counted, ``kda_scan_tokens`` and ``kda_scan_chunks`` on
 a token a head, three passes; q, k, v and o in bfloat16, the log decay in
 float32 and the write strength once a pass, the states that enter the
 chunks written and read once) over the training rounds' time under
-``kda.chunk_scan``. The triangular solve, the intra-chunk 64 x 64 x 128
-decays, the chunk bodies computed again in the backward pass, the float32
+``kda.chunk_scan``. How the lowering gets there (since PR 36 by 16-token
+sub-blocks: 16 x 16 x 128 pairwise decays and substitution inside one,
+matrix products between them, the blocked inverse of the unit
+lower-triangular system merged 16 -> 32 -> 64 and applied as one product a
+chunk), the chunk bodies computed again in the backward pass, the float32
 products at ``Precision.HIGHEST`` and every layout copy are in the time and
 not in the work.
 

@@ -33,6 +33,19 @@ class Work:
         return Work(self.flops + other.flops, self.bytes + other.bytes)
 
 
+def _grouped_products(products: int, rows: float, calls: int, experts: int,
+                      hidden: int, intermediate: int, matmul_bytes: int,
+                      grad_bytes: int) -> Work:
+    """Training work of an expert of ``products`` matrices, ``hidden`` x
+    ``intermediate`` each, over ``rows`` rows grouped by expert."""
+    weights = products * experts * hidden * intermediate
+    return Work(
+        flops=2.0 * PASSES * products * rows * hidden * intermediate,
+        bytes=(calls * weights * (PASSES * matmul_bytes + grad_bytes)
+               + PASSES * products * rows * (hidden + intermediate)
+               * matmul_bytes))
+
+
 def grouped_swiglu(rows: float, calls: int, experts: int, hidden: int,
                    intermediate: int, *, matmul_bytes: int = 2,
                    grad_bytes: int = 4) -> Work:
@@ -47,11 +60,20 @@ def grouped_swiglu(rows: float, calls: int, experts: int, hidden: int,
     all calls together: each product's input rows read and output rows
     written once a pass (two products take ``hidden`` in and give
     ``intermediate`` out, the third the reverse)."""
-    weights = 3 * experts * hidden * intermediate
-    return Work(
-        flops=2.0 * PASSES * 3 * rows * hidden * intermediate,
-        bytes=(calls * weights * (PASSES * matmul_bytes + grad_bytes)
-               + PASSES * 3 * rows * (hidden + intermediate) * matmul_bytes))
+    return _grouped_products(3, rows, calls, experts, hidden, intermediate,
+                             matmul_bytes, grad_bytes)
+
+
+def grouped_relu2(rows: float, calls: int, experts: int, hidden: int,
+                  intermediate: int, *, matmul_bytes: int = 2,
+                  grad_bytes: int = 4) -> Work:
+    """Training work of ``W2(relu(W1 x)^2)``, the expert without a gate:
+    as :func:`grouped_swiglu` with two products a row and two weights an
+    expert (one takes ``hidden`` in and gives ``intermediate`` out, the
+    other the reverse). ``intermediate`` is the width the mathematics has:
+    columns of zeros a lowering pads it with are in the time, not here."""
+    return _grouped_products(2, rows, calls, experts, hidden, intermediate,
+                             matmul_bytes, grad_bytes)
 
 
 def least_seconds(work: Work, peaks: Dict[str, Any], chips: int = 1

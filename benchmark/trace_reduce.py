@@ -32,6 +32,13 @@ Definitions:
   ``while`` op spans its whole loop, so the union — not the sum — is used);
 - idle share: 1 - busy / stretch;
 - a program's device time: the sum of its ``XLA Modules`` events;
+- an operation's program: the ``XLA Modules`` event of the same device
+  whose interval holds the operation's start, named without its
+  fingerprint (``jit_round_step``); ``""`` where none does. The device says
+  it, not the ``op_name``: a kernel the compiler makes inside a ``while``
+  (its grouped matmul with the local steps as a loop) arrives with no path
+  at all, as the evaluation's does, and only the program tells the two
+  apart (PR 46);
 - an operation's time: the sum of its events, control-flow containers
   (``while``, ``conditional``, ``call``) left out because their bodies'
   operations are counted themselves;
@@ -69,6 +76,7 @@ Definitions:
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import re
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -167,6 +175,10 @@ class DeviceTrace:
     # counted in ``ops``; the empty path where an op has no op_name.
     scopes: Dict[Tuple[Tuple[str, ...], str], float] = dataclasses.field(
         default_factory=dict)
+    # program -> the same seconds by the same keys, of the operations that
+    # ran inside that program (``""``: inside none the trace recorded).
+    programs: Dict[str, Dict[Tuple[Tuple[str, ...], str], float]] = (
+        dataclasses.field(default_factory=dict))
 
 
 @dataclasses.dataclass
@@ -223,23 +235,27 @@ class TraceSummary:
                 total[name] = total.get(name, 0.0) + seconds / len(self.devices)
         return _largest(total, limit)
 
-    def _scopes(self):
-        """(path, which, seconds as a mean over the devices) of every entry."""
+    def _scopes(self, program: Optional[str] = None):
+        """(path, which, seconds as a mean over the devices) of every entry,
+        or of those of the operations that ran inside ``program``."""
         for d in self.devices:
-            for (path, which), seconds in d.scopes.items():
+            entries = d.scopes if program is None else d.programs.get(
+                program, {})
+            for (path, which), seconds in entries.items():
                 yield path, which, seconds / len(self.devices)
 
-    def scope_seconds(self, *components: str,
-                      which: Optional[str] = None) -> float:
+    def scope_seconds(self, *components: str, which: Optional[str] = None,
+                      program: Optional[str] = None) -> float:
         """Seconds, mean over devices, of the operations whose scope path
         holds all of ``components`` in this order (``("client_train",
         "moe.experts")`` is the train part of that kernel), all of them or
-        only the FORWARD, BACKWARD or RECOMPUTED ones. ``UNSCOPED`` alone:
+        only the FORWARD, BACKWARD or RECOMPUTED ones, in every program or
+        only inside ``program`` (``"jit_round_step"``). ``UNSCOPED`` alone:
         those whose path holds no scope of the program's."""
         if components == (UNSCOPED,):
-            return sum(s for p, w, s in self._scopes()
+            return sum(s for p, w, s in self._scopes(program)
                        if not any(map(is_scope, p)))
-        return sum(s for p, w, s in self._scopes()
+        return sum(s for p, w, s in self._scopes(program)
                    if which in (None, w) and _holds_in_order(p, components))
 
     @property
@@ -312,48 +328,60 @@ def reduce_profile(profile, host_interval: Optional[Tuple[float, float]] = None,
         intervals: List[Tuple[float, float]] = []
         ops: Dict[str, float] = {}
         scopes: Dict[Tuple[Tuple[str, ...], str], float] = {}
+        programs: Dict[str, Dict[Tuple[Tuple[str, ...], str], float]] = {}
         modules: Dict[str, Tuple[int, float]] = {}
         collective = 0.0
         meta = metadata_of.get(plane.name)
-        for line in plane.lines:
-            if line.name == OPS_LINE:
-                events = list(line.events)
-                ids = (meta.line_event_ids.get(OPS_LINE, []) if meta
-                       else [None] * len(events))
-                if len(ids) != len(events):
-                    raise ValueError(
-                        f"{plane.name}: {len(events)} operations and "
-                        f"{len(ids)} metadata ids: not the same trace")
-                scope_of: Dict[Optional[int], Tuple] = {None: ((), FORWARD)}
-                for ev, metadata_id in zip(events, ids):
-                    s = ev.start_ns * 1e-9
-                    e = s + ev.duration_ns * 1e-9
-                    if clip is not None:
-                        s, e = max(s, clip[0]), min(e, clip[1])
-                        if e <= s:
-                            continue
-                    intervals.append((s, e))
-                    base = _base(ev.name)
-                    if base in CONTAINERS:
+        lines = {line.name: line for line in plane.lines}
+        # The programs' executions first: (start, end, name), by start.
+        executions = []
+        for ev in (lines[MODULES_LINE].events if MODULES_LINE in lines
+                   else ()):
+            name = ev.name.split("(")[0]
+            n, s = modules.get(name, (0, 0.0))
+            modules[name] = (n + 1, s + ev.duration_ns * 1e-9)
+            executions.append((ev.start_ns * 1e-9,
+                               (ev.start_ns + ev.duration_ns) * 1e-9, name))
+        executions.sort()
+        execution_starts = [x[0] for x in executions]
+        if OPS_LINE in lines:
+            events = list(lines[OPS_LINE].events)
+            ids = (meta.line_event_ids.get(OPS_LINE, []) if meta
+                   else [None] * len(events))
+            if len(ids) != len(events):
+                raise ValueError(
+                    f"{plane.name}: {len(events)} operations and "
+                    f"{len(ids)} metadata ids: not the same trace")
+            scope_of: Dict[Optional[int], Tuple] = {None: ((), FORWARD)}
+            for ev, metadata_id in zip(events, ids):
+                began = s = ev.start_ns * 1e-9
+                e = s + ev.duration_ns * 1e-9
+                if clip is not None:
+                    s, e = max(s, clip[0]), min(e, clip[1])
+                    if e <= s:
                         continue
-                    key = short_name(ev.name)
-                    ops[key] = ops.get(key, 0.0) + (e - s)
-                    scope = scope_of.get(metadata_id)
-                    if scope is None:
-                        if meta.event_names.get(metadata_id) != ev.name:
-                            raise ValueError(
-                                f"{plane.name}: metadata {metadata_id} is not "
-                                f"{ev.name[:60]!r}: not the same trace")
-                        scope = scope_of[metadata_id] = scope_path(
-                            meta.stat(metadata_id, OP_NAME_STAT) or "")
-                    scopes[scope] = scopes.get(scope, 0.0) + (e - s)
-                    if base.startswith(COLLECTIVES):
-                        collective += e - s
-            elif line.name == MODULES_LINE:
-                for ev in line.events:
-                    name = ev.name.split("(")[0]
-                    n, s = modules.get(name, (0, 0.0))
-                    modules[name] = (n + 1, s + ev.duration_ns * 1e-9)
+                intervals.append((s, e))
+                base = _base(ev.name)
+                if base in CONTAINERS:
+                    continue
+                key = short_name(ev.name)
+                ops[key] = ops.get(key, 0.0) + (e - s)
+                scope = scope_of.get(metadata_id)
+                if scope is None:
+                    if meta.event_names.get(metadata_id) != ev.name:
+                        raise ValueError(
+                            f"{plane.name}: metadata {metadata_id} is not "
+                            f"{ev.name[:60]!r}: not the same trace")
+                    scope = scope_of[metadata_id] = scope_path(
+                        meta.stat(metadata_id, OP_NAME_STAT) or "")
+                scopes[scope] = scopes.get(scope, 0.0) + (e - s)
+                i = bisect.bisect_right(execution_starts, began) - 1
+                inside = programs.setdefault(
+                    executions[i][2] if i >= 0 and began < executions[i][1]
+                    else "", {})
+                inside[scope] = inside.get(scope, 0.0) + (e - s)
+                if base.startswith(COLLECTIVES):
+                    collective += e - s
         if not intervals:
             continue
         busy, gaps = _union(intervals)
@@ -366,7 +394,7 @@ def reduce_profile(profile, host_interval: Optional[Tuple[float, float]] = None,
         devices.append(DeviceTrace(
             index=int(m.group(1)), busy_s=busy, start_s=start, end_s=end,
             modules=modules, ops=ops, collective_s=collective, gaps=gaps,
-            scopes=scopes))
+            scopes=scopes, programs=programs))
     if not devices:
         raise ValueError("the trace holds no device plane with XLA Ops events"
                          + (" inside the stretch" if clip else ""))

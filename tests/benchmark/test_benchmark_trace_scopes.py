@@ -288,24 +288,36 @@ def test_the_scoped_share_and_the_sum_against_the_union(summary):
 
 def test_the_manifest_lists_the_kernel_metrics_for_the_cell_that_has_them(
         listed_manifest):
+    """A list names the cells whose model opens the metric's scope, whose
+    task has a next-token loss, whose traffic evaluates — found from the
+    models and the traffic files (``model_facts``), in the manifest's
+    order, so a cell a later PR appends is asked for where it has something
+    to read and nowhere else."""
     import json
+
+    import model_facts
 
     with open(listed_manifest, encoding="utf-8") as f:
         doc = json.load(f)
     by_name = {m["name"]: m for m in doc["per_layer"]}
     everywhere = ("round_program.scoped_share", "client_train.device_ms",
                   "aggregate.device_ms", "server_update.device_ms")
-    sparse = ("moe.experts.device_ms", "moe.route_dispatch_combine.device_ms",
-              "lfm2.attention.device_ms", "lfm2.short_conv.device_ms",
-              "lm_loss.device_ms", "moe.experts_roofline")
+    sparse = {
+        "moe.experts.device_ms": lambda f: "moe.experts" in f.scopes,
+        "moe.route_dispatch_combine.device_ms": lambda f: {
+            "moe.route", "moe.dispatch", "moe.combine"} <= f.scopes,
+        "moe.experts_roofline": lambda f: "moe.experts" in f.scopes,
+        "lfm2.attention.device_ms": lambda f: "lfm2.attention" in f.scopes,
+        "lfm2.short_conv.device_ms": lambda f: "lfm2.short_conv" in f.scopes,
+        "lm_loss.device_ms": lambda f: f.next_token,
+    }
     for name in everywhere:
         assert "workloads" not in by_name[name]
-    for name in sparse:
-        assert by_name[name]["workloads"] == ["lfm2_moe_ep8.8_silo_1k"]
+    for name, emits in sparse.items():
+        assert by_name[name]["workloads"] == model_facts.cells_where(
+            listed_manifest, emits), name
         assert by_name[name]["moves"] == "device_rounds_per_s"
-    assert by_name["evaluate.device_ms"]["workloads"] == [
-        "distilbert_sent140.128_spike", "lfm2_moe_ep8.8_silo_1k"]
-    for cell in doc["workloads"]:
-        traffic = manifest.load_cell(cell["name"], listed_manifest).traffic
-        assert ("evaluate" in traffic["operators"]) == (
-            cell["name"] in by_name["evaluate.device_ms"]["workloads"])
+    assert by_name["evaluate.device_ms"]["workloads"] == (
+        model_facts.cells_where(listed_manifest, lambda f: f.evaluates))
+    assert by_name["lfm2.attention.device_ms"]["workloads"] == [
+        "lfm2_moe_ep8.8_silo_1k"]             # the derivation finds something
