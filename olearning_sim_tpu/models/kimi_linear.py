@@ -452,12 +452,15 @@ class KimiLinear(nn.Module):
     **No block is computed again in the backward pass**: a block keeps what
     its forward pass produced outside the parts that carry a checkpoint of
     their own (the residual stream, the norms' outputs, an MLA layer's q, k,
-    v and context, the dense MLP's and the shared expert's hidden products,
-    the routed layer's dispatch and combine arrays), and the backward pass
+    v and context, the dense MLP's and the shared expert's hidden products;
+    the routed layer keeps no array of its own, only its input and routing
+    vectors), and the backward pass
     computes again only what those checkpoints cover: each of a KDA
     mixer's three parts once (:class:`KDA`), the chunk bodies inside the
-    scan, the MLA scores (``lfm2._attend``) and the experts' hidden
-    products (``models/moe.py``). ``nn.remat`` around every block would run
+    scan, the MLA scores (``lfm2._attend``), and the routed experts' rows
+    and hidden products, which the backward loop of ``models/moe.py``
+    gathers and multiplies again a window at a time. ``nn.remat`` around
+    every block would run
     each block's whole forward a second time (a quarter of the benchmark
     cell's round, until PR 42) to save 2.98 GiB of the cell's 4,096-token
     step. Around one block alone it gives back, by the compiler's

@@ -14,6 +14,15 @@ Two expert layers live here, and they share nothing:
   layer is TOLD which experts it holds (``held``): it routes over all of
   them and returns the partial sum its own experts give, which is what one
   chip of an expert-parallel deployment computes before the exchange.
+  The assignments are sorted by held expert and worked **in windows of C
+  sorted rows** (:func:`window_rows`: twice what an even router would send
+  the held experts, in whole :data:`GROUPED_ROW_BLOCK`s), every window, the
+  first too, inside one dynamic-trip ``lax.while_loop``
+  (:func:`_expert_windows`) with a backward loop of its own: the arrays a
+  trip builds have C rows where the layer's used to have one an assignment
+  (an eighth to a thirty-second of those are to held experts in the
+  benchmark's cells), and a window is no capacity: a layer whose held
+  experts draw more than C rows takes another trip and stays exact.
 - :class:`SwitchFFN` — a top-1, softmax, GELU toy with a STATIC capacity
   that drops overflow tokens through the residual, kept because
   ``tests/test_expert_parallel.py`` and the ``moe_text`` family
@@ -30,13 +39,13 @@ with ``expert_``; ``ep_param_specs`` shards that axis over ``ep``.
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name
 
 from olearning_sim_tpu.models.registry import (
     ModelSpec, WorkCounts, register_model, sown)
@@ -45,91 +54,215 @@ from olearning_sim_tpu.models.registry import (
 # What a DroplessMoE layer sows as ``moe_stats`` on every call, one int32
 # vector: these three counts, then the assignments each held expert got.
 STATS_HEAD = ("assignments_total", "assignments_local", "assignments_computed")
+# ... and beside it, one int32: the trips its loop over row windows took.
+TRIPS = "moe_window_trips"
 BIAS_INIT_SCALE = 0.01
-
-
-@jax.custom_vjp
-def _take_rows(x, rows, back):
-    """``x[rows]``, zeros where ``rows`` is out of range; ``rows`` picks each
-    row of ``x`` at most once and ``back`` is its inverse (row i of ``x``
-    went to ``back[i]``, out of range where it went nowhere), so the
-    cotangent is the gather ``g[back]``, not the scatter-add XLA derives
-    for a gather whose indices it cannot see are distinct."""
-    return jnp.take(x, rows, axis=0, mode="fill", fill_value=0)
-
-
-def _take_rows_fwd(x, rows, back):
-    return _take_rows(x, rows, back), (rows, back)
-
-
-def _take_rows_bwd(res, g):
-    rows, back = res
-    return jnp.take(g, back, axis=0, mode="fill", fill_value=0), None, None
-
-
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
-
-
-@jax.checkpoint
-def _grouped_swiglu(xs, w1, w3, w2, sizes):
-    """``W2(silu(W1 x) * W3 x)`` of rows ``xs`` grouped by expert: group g
-    is the next ``sizes[g]`` rows and uses ``w*[g]``; rows past the groups
-    are not multiplied (what comes back in them is the kernel's to leave:
-    the caller masks them). The backward pass recomputes
-    the two hidden products rather than keep them for every row."""
-    a = jax.lax.ragged_dot(xs, w1, sizes)
-    b = jax.lax.ragged_dot(xs, w3, sizes)
-    return jax.lax.ragged_dot(jax.nn.silu(a) * b, w2, sizes)
 
 
 # The TPU compiler's grouped-matmul kernel tiles a width that is a multiple
 # of this by it, and any other width by 128.
 GROUPED_TILE = 512
-# Rows the squared-ReLU form's grouped products are given at a time: the
-# last held group is lengthened over the zero rows that follow the groups,
-# up to the next multiple of this (see :func:`_grouped_relu2`).
+# Rows the expert layer's windows are counted in (:func:`window_rows`), and
+# rows the squared-ReLU form's grouped products are given at a time
+# (:func:`_whole_blocks`).
 GROUPED_ROW_BLOCK = 4096
-# The names the two grouped products' results carry for a caller's
-# ``jax.checkpoint`` policy (``save_only_these_names(*GROUPED_RESULTS)``
-# keeps them, so that a layer computed again in the backward pass does not
-# run the products again).
-GROUPED_RESULTS = ("grouped_hidden", "grouped_out")
 
 
-def _grouped_relu2(xs, w1, w2, sizes):
-    """``W2(relu(W1 x)^2)`` of rows ``xs`` grouped by expert, as
-    :func:`_grouped_swiglu` groups them: two grouped products a pass. The
-    hidden product is kept for the backward pass, and both products'
-    results are named (:data:`GROUPED_RESULTS`) for a caller that computes
-    its layer again there.
+def _hidden_rows(gated, xs, ws, sizes):
+    """The hidden rows of rows ``xs`` grouped by expert, ``silu(W1 x) * W3
+    x`` (``gated``: ``ws`` is ``w1, w3``) or ``relu(W1 x)^2`` (``w1``):
+    group g is the next ``sizes[g]`` rows and uses ``w*[g]``. Rows past the
+    groups are not multiplied (what comes back in them is the kernel's to
+    leave: the caller masks them)."""
+    a = jax.lax.ragged_dot(xs, ws[0], sizes)
+    if gated:
+        return jax.nn.silu(a) * jax.lax.ragged_dot(xs, ws[1], sizes)
+    a = jax.nn.relu(a)
+    return a * a
 
-    The hidden width is padded with zero columns of ``w1`` and zero rows of
-    ``w2`` up to a multiple of :data:`GROUPED_TILE` for the two calls:
-    ``relu(0)^2 = 0`` through zero rows adds exact zeros to the sums, and
-    a 1,856-wide expert's products take a fraction of the time a row
-    (128-wide tiles are 4 x the kernel's steps; PERF.md section 6, PR
-    38).
 
-    The products run over whole blocks of :data:`GROUPED_ROW_BLOCK` rows:
-    the rows that follow the groups are zeros (the caller's gather fills
-    them), the last group takes those up to the next block's end, and what
-    its expert makes of them is zero and is never gathered back, so values
-    and gradients are the same. What changes is the time: the kernel's
-    follows the rows it is given, the held experts' load follows the seeded
-    weights (0.15-0.27 of a step's assignments over three layers, seed to
-    seed), and a round's time followed it by 1.2% where 1% is the bound; in
-    blocks it does not, as long as a layer's load stays under one block,
-    for 2% of the round."""
+def _padded_width(w1, w2):
+    """The two-matrix form's weights with their hidden width padded with
+    zero columns of ``w1`` and zero rows of ``w2`` up to a multiple of
+    :data:`GROUPED_TILE`: ``relu(0)^2 = 0`` through zero rows adds exact
+    zeros to the sums, and a 1,856-wide expert's products take a fraction
+    of the time a row (128-wide tiles are 4 x the kernel's steps; PERF.md
+    section 6, PR 38)."""
     pad = -w1.shape[-1] % GROUPED_TILE
-    w1 = jnp.pad(w1, ((0, 0), (0, 0), (0, pad)))
-    w2 = jnp.pad(w2, ((0, 0), (0, pad), (0, 0)))
-    rows = sizes.sum()
-    sizes = sizes.at[-1].add(jnp.minimum(
-        -rows % GROUPED_ROW_BLOCK, xs.shape[0] - rows))
-    a = jax.nn.relu(checkpoint_name(
-        jax.lax.ragged_dot(xs, w1, sizes), GROUPED_RESULTS[0]))
-    return checkpoint_name(
-        jax.lax.ragged_dot(a * a, w2, sizes), GROUPED_RESULTS[1])
+    return (jnp.pad(w1, ((0, 0), (0, 0), (0, pad))),
+            jnp.pad(w2, ((0, 0), (0, pad), (0, 0))))
+
+
+def _whole_blocks(sizes, rows):
+    """The groups' sizes with the last group lengthened to the next multiple
+    of :data:`GROUPED_ROW_BLOCK`, never past ``rows`` (one window's): what
+    the two-matrix form's grouped products run over.
+
+    The rows that follow the groups are zeros (the caller's gather fills
+    them), and what the last expert makes of them is zero and is never added
+    to a token, so values and gradients are the same. What changes is the
+    time: the kernel's follows the rows it is given, the held experts' load
+    follows the seeded weights (0.15-0.27 of a step's assignments over three
+    layers, seed to seed), and a round's time followed it by 1.2% where 1%
+    is the bound; in blocks it does not, as long as a layer's load stays
+    under one block, for 2% of the round."""
+    used = sizes.sum()
+    return sizes.at[-1].add(jnp.minimum(
+        -used % GROUPED_ROW_BLOCK, rows - used))
+
+
+def window_rows(assignments: int, held: int, experts: int) -> int:
+    """C, the sorted rows an expert layer takes a trip: twice the rows an
+    even router sends the held experts (``assignments * held / experts``),
+    in whole :data:`GROUPED_ROW_BLOCK`s, and never more than there are
+    assignments. Twice, because the seeded routers are not even (a 256-wide
+    one sends a held expert up to 6.4 times its mean, a layer's held share
+    moves 0.15-0.27 around 0.19 seed to seed: PERF.md section 6, PRs 34
+    and 38), and a layer-step that draws more only takes another trip."""
+    even = -(-assignments * held // experts)
+    return min(-(-2 * even // GROUPED_ROW_BLOCK) * GROUPED_ROW_BLOCK,
+               assignments)
+
+
+def _window(gated, trip, rows, slots, perm, sizes):
+    """Rows ``trip * rows`` onward of the sorted order, ``rows`` of them:
+    the token each row is (out of range past the held groups' end, so that a
+    gather fills zeros there and a scatter drops), the (token, slot)
+    assignment it is (out of range likewise; ``slots`` a token), which rows
+    are inside the held groups, the groups' sizes clipped to the window,
+    and the sizes the grouped products run over (those, in whole blocks
+    where the form is the two-matrix one)."""
+    with jax.named_scope("moe.dispatch"):
+        first = trip * rows
+        ends = jnp.cumsum(sizes)
+        row = first + jnp.arange(rows, dtype=jnp.int32)
+        held = row < ends[-1]
+        assigned = jnp.where(
+            held, jnp.take(perm, row, mode="clip"), perm.shape[0])
+        inside = jnp.diff(jnp.clip(ends, first, first + rows), prepend=first)
+        groups = inside if gated else _whole_blocks(inside, rows)
+    return assigned // slots, assigned, held, inside, groups
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _expert_windows(gated, hidden, dtype, rows, x, weights, perm, sizes, ws):
+    """The held experts' weighted sum by token, float32 ``[S, W]``, from the
+    tokens ``x [S, W]``, their routing weights ``[S, K]``, the sorted order
+    of the (token, slot) assignments (``perm``: sorted row r is assignment
+    ``perm[r]``, token ``perm[r] // K``; the held groups first, ``sizes``
+    rows each) and the float32 expert matrices ``ws``; and beside it two
+    counts, the trips taken and the rows they covered.
+
+    One ``lax.while_loop`` over windows of ``rows`` sorted rows, while a
+    window starts inside the held groups: a trip gathers its rows' tokens,
+    runs the grouped products over the groups' parts inside the window,
+    weights the results and adds them to their tokens. No array here has a
+    row an assignment: what a trip touches is ``rows`` long, and a layer
+    whose held experts drew nothing takes no trip. ``lax.while_loop`` has
+    no reverse-mode rule, so the backward pass is a loop of its own over the
+    same windows (:func:`_expert_windows_bwd`)."""
+    return _expert_windows_fwd(gated, hidden, dtype, rows, x, weights, perm,
+                               sizes, ws)[0]
+
+
+def _expert_windows_fwd(gated, hidden, dtype, rows, x, weights, perm, sizes,
+                        ws):
+    local = sizes.sum()
+    with jax.named_scope("moe.experts"):
+        ws = tuple(w.astype(dtype) for w in ws)
+        ws = ws if gated else _padded_width(*ws)
+
+    def trip(carry):
+        n, y, covered = carry
+        token, assigned, held, inside, groups = _window(
+            gated, n, rows, weights.shape[1], perm, sizes)
+        with jax.named_scope("moe.dispatch"):
+            xs = jnp.take(x, token, axis=0, mode="fill",
+                          fill_value=0).astype(dtype)
+        with jax.named_scope("moe.experts"):
+            ye = jax.lax.ragged_dot(
+                _hidden_rows(gated, xs, ws[:-1], groups), ws[-1], groups)
+        with jax.named_scope("moe.combine"):
+            weight = jnp.take(weights.reshape(-1), assigned, mode="fill",
+                              fill_value=0)
+            # Rows past the groups hold what the kernel left there.
+            y = y.at[token].add(jnp.where(
+                held[:, None], ye.astype(jnp.float32) * weight[:, None], 0),
+                mode="drop")
+        return n + 1, y, covered + inside.sum()
+
+    n, y, covered = jax.lax.while_loop(
+        lambda carry: carry[0] * rows < local, trip,
+        (jnp.int32(0), jax.lax.full_like(x, 0, jnp.float32),
+         jax.lax.full_like(local, 0)))
+    return (y, jnp.stack([n, covered])), (x, weights, perm, sizes, ws)
+
+
+def _expert_windows_bwd(gated, hidden, dtype, rows, res, cotangents):
+    """A second dynamic-trip loop over the same windows: a trip computes its
+    window's hidden rows again from the tokens and pulls the window's
+    cotangent back through them, adding into float32 sums for the tokens,
+    the routing weights and the expert matrices.
+
+    What a trip differentiates is the forward's sum with a row's weight
+    moved inside the last product, onto the hidden row (that product is
+    linear in it, so the two are one function): the weight's cotangent then
+    comes off the hidden width, sum of hidden x its cotangent, and the
+    window's results, which only the weight's cotangent in the forward's
+    own form would read, are not built again: eleven grouped products a
+    layer-step in the gated form, as before the windows, where twelve cost
+    2.2% of the round in nemotron's cell (PERF.md section 6, PR 47)."""
+    x, weights, perm, sizes, ws = res
+    # The sum's cotangent at the width the products take it (a caller that
+    # casts the sum to ``dtype`` sends one that is exact there).
+    g = cotangents[0].astype(dtype)
+    local = sizes.sum()
+    H, W = ws[0].shape[:2]
+    leaves = [(H, W, hidden)] * (len(ws) - 1) + [(H, hidden, W)]
+
+    def trip(carry):
+        n, dx, dweights, dws = carry
+        token, assigned, held, _, groups = _window(
+            gated, n, rows, weights.shape[1], perm, sizes)
+        with jax.named_scope("moe.dispatch"):
+            xs = jnp.take(x, token, axis=0, mode="fill",
+                          fill_value=0).astype(dtype)
+        with jax.named_scope("moe.combine"):
+            weight = jnp.take(weights.reshape(-1), assigned, mode="fill",
+                              fill_value=0)
+            gs = jnp.take(g, token, axis=0, mode="fill", fill_value=0)
+
+        def weighted(xs, weight, ws):
+            h = _hidden_rows(gated, xs, ws[:-1], groups)
+            return jax.lax.ragged_dot(
+                (weight[:, None] * h).astype(dtype), ws[-1], groups)
+
+        with jax.named_scope("moe.experts"):
+            dxs, dweight, dw = jax.vjp(weighted, xs, weight, ws)[1](gs)
+            # Out of the padded width as they are added: the sums are as
+            # wide as the leaves.
+            dws = tuple(
+                total + d[:, :total.shape[1], :total.shape[2]].astype(
+                    jnp.float32) for total, d in zip(dws, dw))
+        with jax.named_scope("moe.combine"):
+            dweights = dweights.at[assigned].add(
+                jnp.where(held, dweight, 0), mode="drop")
+        with jax.named_scope("moe.dispatch"):
+            dx = dx.at[token].add(jnp.where(
+                held[:, None], dxs.astype(jnp.float32), 0), mode="drop")
+        return n + 1, dx, dweights, dws
+
+    _, dx, dweights, dws = jax.lax.while_loop(
+        lambda carry: carry[0] * rows < local, trip,
+        (jnp.int32(0), jax.lax.full_like(x, 0, jnp.float32),
+         jax.lax.full_like(weights, 0).reshape(-1),
+         tuple(jax.lax.full_like(w, 0, jnp.float32, shape=shape)
+               for w, shape in zip(ws, leaves))))
+    return (dx.astype(x.dtype), dweights.reshape(weights.shape), None, None,
+            dws)
+
+
+_expert_windows.defvjp(_expert_windows_fwd, _expert_windows_bwd)
 
 
 class DroplessMoE(nn.Module):
@@ -158,6 +291,27 @@ class DroplessMoE(nn.Module):
     multiplied, read or summed. One expert may get every
     assignment, or none.
 
+    **By row windows, and why a window is no capacity.** Only the int32 and
+    float32 routing vectors have a row an assignment (A = tokens x
+    ``top_k``). Everything W or ``mlp_dim`` wide is built a window at a
+    time, C = :func:`window_rows` sorted rows (a static function of A, the
+    held share and :data:`GROUPED_ROW_BLOCK`: 8,192 / 4,096 / 4,096 rows in
+    the benchmark's three cells, where a layer-step's held experts draw
+    about 4,100 / 1,600 / 1,100 of 32,768 / 24,576 / 32,768), inside one
+    ``lax.while_loop`` that runs while a window still starts inside the
+    held groups (:func:`_expert_windows`): a trip gathers its rows straight
+    from the tokens, clips the groups to the window, runs the grouped
+    products, and adds the weighted results to their tokens in a float32
+    ``[S, W]`` sum. A capacity would stop after the first window and drop
+    the rest; here the rows past C take a second trip (a third, ...), at
+    the cost of that trip's time, and the result is the same sum. The
+    backward pass is a second loop over the same windows, written here
+    because ``lax.while_loop`` has no reverse-mode rule: its residuals are
+    the tokens, the routing vectors and the expert matrices as the products
+    take them, and a trip computes its window's products again. The trips
+    a call took are sown as ``moe_window_trips`` (1 in every call of the
+    benchmark's cells: the round's time does not follow the load).
+
     ``expert_bias`` steers the selection only. It is a float32 parameter
     that takes no gradient (so local training, aggregation and the server
     step leave it as it is); the published rule that updates it from the
@@ -173,7 +327,8 @@ class DroplessMoE(nn.Module):
     experts get 2.8-3.4% of a round's assignments (1/32 is 3.1%) and the
     most loaded of them 3.8-6.4 times the mean load (seeded weights: a few
     of 256 experts draw most tokens); the round time follows neither (the
-    layer's arrays are sized by slots, not by load: 0.12% over six seeds).
+    layer's arrays were sized by slots then and are by the window since PR
+    47, not by load: 0.12% over six seeds).
     """
 
     num_experts: int
@@ -226,42 +381,24 @@ class DroplessMoE(nn.Module):
             here = group < H
             sizes = (group[:, None] == jnp.arange(H, dtype=jnp.int32)
                      ).sum(0, dtype=jnp.int32)                   # [H]
-            # Sorted position r holds assignment perm[r]; assignment j sits
-            # at inv[j]. Out of range (A) marks the tail in both: a grouped
-            # matmul kernel need not write the rows it does not multiply,
-            # and what it leaves there must reach neither the sum nor,
-            # through the backward pass, the tokens' gradients.
-            perm = jnp.argsort(group, stable=True)
-            inv = jnp.zeros_like(perm).at[perm].set(
-                jnp.arange(A, dtype=perm.dtype))
-            local = sizes.sum()
-            perm = jnp.where(jnp.arange(A) < local, perm, A)
-            inv = jnp.where(inv < local, inv, A)
-            # Row i*K + j is token i in its slot j.
-            xs = _take_rows(
-                jnp.repeat(xf.astype(self.dtype), K, axis=0), perm, inv)
+            # Sorted position r holds assignment perm[r], token perm[r] //
+            # K in its slot perm[r] % K: the held groups first, the tail
+            # after them, which no window reaches.
+            perm = jnp.argsort(group, stable=True).astype(jnp.int32)
 
-        with jax.named_scope("moe.experts"):
-            if self.gated:
-                ye = _grouped_swiglu(xs, w1.astype(self.dtype),
-                                     w3.astype(self.dtype),
-                                     w2.astype(self.dtype), sizes)  # [A, W]
-            else:
-                ye = _grouped_relu2(xs, w1.astype(self.dtype),
-                                    w2.astype(self.dtype), sizes)
-
-        with jax.named_scope("moe.combine"):
-            yk = _take_rows(ye, inv, perm).reshape(S, K, W)
-            y = (yk.astype(jnp.float32) * weights[..., None]).sum(1)
+        y, (trips, computed) = _expert_windows(
+            self.gated, M, self.dtype, window_rows(A, H, E), xf, weights,
+            perm, sizes, (w1, w3, w2) if self.gated else (w1, w2))
 
         # The choices themselves, for whoever asks for the intermediates
         # (scripts/lfm2_routing_agreement.py); training does not.
         self.sow("intermediates", "moe_chosen", chosen)
         # Counted twice on purpose: what the router sent to held experts,
-        # and what the grouped products were told to cover.
+        # and the rows the windows' grouped products covered.
         self.sow("intermediates", "moe_stats", jnp.concatenate([
-            jnp.stack([jnp.int32(A), here.sum(dtype=jnp.int32), local]),
+            jnp.stack([jnp.int32(A), here.sum(dtype=jnp.int32), computed]),
             sizes]))
+        self.sow("intermediates", TRIPS, trips)
         return y.reshape(B, L, W).astype(x.dtype)
 
 
@@ -291,11 +428,14 @@ def gather_stats(intermediates):
 def work_counts_beside(sown_name: str, names: Tuple[str, ...]) -> WorkCounts:
     """For the ``ModelSpec`` of a model whose mixers sow counts of their own
     (``sown_name``: one int32 vector a layer, ``len(names)`` long) beside
-    these layers': one array of a forward pass's counts, the expert layers'
-    ``moe_stats`` a row each, then one row that starts with the mixers'
-    vectors summed (the names need no more) and is zero after them; summed
-    over some stretch of work it is named ``{names[i]: count}`` and, where
-    there are expert layers, what :func:`describe_stats` names."""
+    these layers': one array of a forward pass's counts, the mixers' vectors
+    summed as its one row where there are no expert layers, else the expert
+    layers' ``moe_stats`` a row each, then a row that starts with the
+    mixers' sum, then a row that starts with the expert layers' trips summed
+    (:data:`TRIPS`), both zero after that (the names need no more than a
+    layer with one held expert is wide); summed over some stretch of work it
+    is named ``{names[i]: count}`` and, where there are expert layers, what
+    :func:`describe_stats` names and ``{TRIPS: count}``."""
 
     def gather(intermediates):
         own = sown(intermediates, sown_name)
@@ -305,14 +445,18 @@ def work_counts_beside(sown_name: str, names: Tuple[str, ...]) -> WorkCounts:
         experts = gather_stats(intermediates)
         if experts is None:
             return own[None]
-        return jnp.concatenate([experts, jnp.pad(
-            own, (0, experts.shape[1] - len(names)))[None]])
+        trips = sum(sown(intermediates, TRIPS))[None]
+        return jnp.concatenate([experts] + [
+            jnp.pad(row, (0, experts.shape[1] - len(row)))[None]
+            for row in (own, trips)])
 
     def describe(counts: np.ndarray) -> dict:
+        layers = max(len(counts) - 2, 0)
         named = dict(zip(names, np.asarray(
-            counts[-1, :len(names)], np.int64).tolist()))
-        if len(counts) > 1:
-            named.update(describe_stats(counts[:-1]))
+            counts[layers, :len(names)], np.int64).tolist()))
+        if layers:
+            named.update(describe_stats(counts[:layers]))
+            named[TRIPS] = int(counts[-1, 0])
         return named
 
     return WorkCounts(gather, describe)

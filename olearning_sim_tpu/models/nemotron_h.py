@@ -355,7 +355,13 @@ class NemotronH(nn.Module):
     attention layer's bought nothing. What a caller gives up: every token of
     a local step beyond the cell's 4,096 needs about 140 KB more for each
     ``"E"`` layer than when every layer was computed again (PERF.md section
-    6, PR 40, has the compiler's table and the chip's readings)."""
+    6, PR 40, has the compiler's table and the chip's readings). Those are
+    PR 40's sizes: since PR 47 an ``"E"`` layer's routed part keeps only its
+    input and routing vectors (``models/moe.py`` works its rows in windows
+    and computes a window again in its own backward loop), the compiler's
+    ``peak_memory`` for the cell fell 10.80 -> 10.01 GiB, and about one
+    ``"M"`` layer's ``nn.remat`` would now fit (PERF.md section 7, item
+    5d(iii))."""
 
     vocab_size: int = 131072
     max_len: int = 262144           # positions served; no position table

@@ -311,6 +311,204 @@ def test_expert_bias_steers_the_selection_and_never_the_weights():
     assert np.asarray(g["gate"]).any()
 
 
+# The row windows (PR 47). Two of 16 experts held, top-2 of 16 tokens: an
+# even router would send the held ones A / 8 = 4 of the A = 32 assignments,
+# so with ``GROUPED_ROW_BLOCK`` at 4 a window is C = 8 sorted rows.
+WINDOW = 8
+
+
+def _steered(first, second, seed=0, gated=True):
+    """A layer, its parameters and 16 tokens of which exactly the first
+    ``first`` pick held expert 0 and the last ``second`` held expert 1:
+    feature 0 (1) of a token is +-6 and only expert 0 (1) reads it, so its
+    score is 0.998 or 0.002 beside the others' 0.2-0.8."""
+    layer = DroplessMoE(16, 2, (0, 1), 40, dtype=F32, gated=gated)
+    rng = np.random.default_rng(seed)
+    x = 0.5 * rng.standard_normal((1, L, W))
+    x[0, :, 0] = np.where(np.arange(L) < first, 6.0, -6.0)
+    x[0, :, 1] = np.where(np.arange(L) >= L - second, 6.0, -6.0)
+    x = jnp.asarray(x, F32)
+    params = dict(layer.init(jax.random.key(seed), x)["params"])
+    gate = 0.1 * rng.standard_normal((W, 16))
+    gate[:2] = 0
+    gate[0, 0] = gate[1, 1] = 1
+    params["gate"] = jnp.asarray(gate, F32)
+    return layer, params, x
+
+
+def _plain_experts(p, x, held, top_k, gated):
+    """The layer as a float32 loop over the held experts, every token
+    through every one of them."""
+    x = x[0]
+    scores = jax.nn.sigmoid(jnp.dot(x, p["gate"], precision="highest"))
+    _, chosen = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(p["expert_bias"]), top_k)
+    weights = jnp.take_along_axis(scores, chosen, -1)
+    weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
+    y = jnp.zeros_like(x)
+    for h, e in enumerate(held):
+        a = jnp.dot(x, p["expert_w1"][h], precision="highest")
+        hidden = (jax.nn.silu(a) * jnp.dot(
+            x, p["expert_w3"][h], precision="highest") if gated
+                  else jax.nn.relu(a) ** 2)
+        y = y + (weights * (chosen == e)).sum(-1)[:, None] * jnp.dot(
+            hidden, p["expert_w2"][h], precision="highest")
+    return y[None]
+
+
+def _windowed(layer, p, x):
+    y, inter = layer.apply({"params": p}, x, mutable=["intermediates"])
+    inter = inter["intermediates"]
+    return y, (inter["moe_stats"][0], inter["moe_window_trips"][0])
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["swiglu", "relu2"])
+@pytest.mark.parametrize("first,second", [
+    (0, 0),         # no row: no trip
+    (3, 2),         # under one window
+    (8, 0),         # exactly C
+    (8, 1),         # C + 1: a second trip for one row
+    (5, 6),         # a group cut by a window's edge
+    (16, 0),        # every token on one held expert
+    (16, 16),       # every assignment held: the most trips there can be
+])
+def test_the_windowed_layer_is_the_plain_loop_over_the_experts(
+        first, second, gated, monkeypatch):
+    """Value and every gradient (tokens, ``gate``, the expert matrices)
+    whatever the held experts' load, and the trips are those the load
+    needs: ceil(local / C)."""
+    from olearning_sim_tpu.models import moe
+
+    monkeypatch.setattr(moe, "GROUPED_ROW_BLOCK", 4)
+    assert moe.window_rows(2 * L, 2, 16) == WINDOW
+    layer, p, x = _steered(first, second, seed=first + second, gated=gated)
+    probe = jnp.asarray(np.random.default_rng(9).standard_normal(
+        (1, L, W)), F32)
+    (_, (got, (stats, trips))), got_g = jax.value_and_grad(
+        lambda p, x: ((_windowed(layer, p, x)[0] * probe).sum(),
+                      _windowed(layer, p, x)), argnums=(0, 1),
+        has_aux=True)(p, x)
+    (_, want), want_g = jax.value_and_grad(
+        lambda p, x: ((_plain_experts(p, x, (0, 1), 2, gated)
+                       * probe).sum(),
+                      _plain_experts(p, x, (0, 1), 2, gated)),
+        argnums=(0, 1), has_aux=True)(p, x)
+    local = first + second
+    assert np.asarray(stats).tolist() == [
+        2 * L, local, local, first, second]
+    assert int(trips) == -(-local // WINDOW)
+    _close(got, want)
+    _close(got_g[1], want_g[1])
+    for name in want_g[0]:
+        if local or name != "expert_bias":
+            _close(got_g[0][name], want_g[0][name])
+    assert not np.asarray(got_g[0]["expert_bias"]).any()
+    if not local:
+        assert not np.asarray(got).any()
+        assert not any(np.asarray(g).any() for g in got_g[0].values())
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["swiglu", "relu2"])
+def test_a_block_of_clients_whose_trip_counts_differ(gated, monkeypatch):
+    """``jax.vmap`` over two clients' weights and tokens, one trip for the
+    first and three for the second: each is what it is alone."""
+    from olearning_sim_tpu.models import moe
+
+    monkeypatch.setattr(moe, "GROUPED_ROW_BLOCK", 4)
+    (layer, p0, x0), (_, p1, x1) = (
+        _steered(3, 2, seed=1, gated=gated),
+        _steered(16, 5, seed=2, gated=gated))
+    probe = jnp.asarray(np.random.default_rng(9).standard_normal(
+        (1, L, W)), F32)
+
+    def one(p, x):
+        (_, (_, counts)), g = jax.value_and_grad(
+            lambda p, x: ((_windowed(layer, p, x)[0] * probe).sum(),
+                          _windowed(layer, p, x)), argnums=(0, 1),
+            has_aux=True)(p, x)
+        return counts, g
+
+    (stats, trips), both = jax.vmap(one)(
+        jax.tree.map(lambda a, b: jnp.stack([a, b]), p0, p1),
+        jnp.stack([x0, x1]))
+    assert np.asarray(trips).tolist() == [1, 3]
+    assert np.asarray(stats)[:, 1:3].tolist() == [[5, 5], [21, 21]]
+    for i, (p, x) in enumerate([(p0, x0), (p1, x1)]):
+        _, alone = one(p, x)
+        for got, want in zip(jax.tree.leaves(both), jax.tree.leaves(alone)):
+            _close(got[i], want, 1e-5)
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["swiglu", "relu2"])
+def test_the_windows_inside_a_checkpoint(gated, monkeypatch):
+    """Computed again in a caller's backward pass (``nn.remat`` around a
+    block, ``jax.checkpoint`` around a part): the same gradients."""
+    from olearning_sim_tpu.models import moe
+
+    monkeypatch.setattr(moe, "GROUPED_ROW_BLOCK", 4)
+    layer, p, x = _steered(16, 5, seed=3, gated=gated)
+
+    def loss(p, x):
+        return (layer.apply({"params": p}, x) ** 2).sum()
+
+    want = jax.grad(loss, argnums=(0, 1))(p, x)
+    got = jax.grad(jax.checkpoint(loss), argnums=(0, 1))(p, x)
+    assert np.asarray(want[1]).any()
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        _close(a, b, 1e-6)
+
+
+def _equations_in_loops(jaxpr, looped=False):
+    """Every equation of a jaxpr at any depth, with whether a ``while``
+    holds it."""
+    for eqn in jaxpr.eqns:
+        yield eqn, looped
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations_in_loops(
+                sub, looped or eqn.primitive.name == "while")
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["swiglu", "relu2"])
+def test_the_windowed_program_holds_no_array_a_row_an_assignment(
+        gated, monkeypatch):
+    """The forward-and-backward program of a tiny expert layer (S = 24
+    tokens, K = 2, A = 48, C = 12; every other size a number of its own):
+    no floating-point array has a row an assignment (A rows, or ``[S, K]``
+    before a wider axis) beyond the routing vectors' K columns, and every
+    grouped product is inside a ``while``: the forward loop's, or the
+    backward loop's, where the window's products run again."""
+    from olearning_sim_tpu.models import moe
+
+    monkeypatch.setattr(moe, "GROUPED_ROW_BLOCK", 4)
+    S, K = 24, 2
+    layer = DroplessMoE(16, K, (0, 1), 40, dtype=F32, gated=gated)
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((1, S, W)), F32)
+    p = layer.init(jax.random.key(0), x)["params"]
+    assert moe.window_rows(S * K, 2, 16) == 12
+    program = jax.make_jaxpr(jax.grad(
+        lambda p, x: (layer.apply({"params": p}, x) ** 2).sum(),
+        argnums=(0, 1)))(p, x)
+    products, rows = [], set()
+    for eqn, looped in _equations_in_loops(program.jaxpr):
+        if eqn.primitive.name.startswith("ragged_dot"):
+            products.append(looped)
+        for var in eqn.outvars:
+            shape = var.aval.shape
+            if not jnp.issubdtype(var.aval.dtype, jnp.floating):
+                continue
+            rows.add(shape[:1])
+            assert not (shape[:1] == (S * K,) and np.prod(shape[1:]) > K), (
+                eqn.primitive.name, shape)
+            assert not (shape[:2] == (S, K) and len(shape) > 2), (
+                eqn.primitive.name, shape)
+    # Forward 3 (2), the same again in the backward loop (the last of them
+    # unused there: the compiler drops it), and there the products of two
+    # cotangents a forward one.
+    assert len(products) == (12 if gated else 8)
+    assert all(products)
+    assert (12,) in rows and (S,) in rows
+
+
 def test_the_whole_model_matches_the_reference():
     model = get_model("lfm2").build(**TINY, dtype=F32)
     tokens = jnp.asarray(np.random.default_rng(8).integers(1, 128, (3, L)),

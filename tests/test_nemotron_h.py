@@ -184,6 +184,34 @@ def test_a_mamba_layer_counts_its_scans_tokens_and_chunks():
         "attend_pairs_needed": 0, "attend_pairs_computed": 0}
 
 
+@pytest.mark.parametrize("held", [1, 2, 8])
+def test_the_mixers_counts_ride_beside_the_expert_layers(held):
+    """One array of a forward pass's counts: the expert layers' rows of 3 +
+    ``held``, the mixers' four summed, the trips summed, with one held
+    expert too (a row as wide as the mixers' names, and no wider)."""
+    x = _x(2, n=1)
+    mixer = nm.Mamba2(4, 8, 16, 2, chunk_size=CHUNK, dtype=F32)
+    layer = DroplessMoE(8, 2, tuple(range(held)), 12, dtype=F32, gated=False)
+    sown = []
+    for module in (mixer, layer):
+        params = module.init(jax.random.key(0), x)["params"]
+        sown.append(module.apply(
+            {"params": params}, x, mutable=["intermediates"])[1][
+                "intermediates"])
+    (stats,), (trips,) = sown[1]["moe_stats"], sown[1]["moe_window_trips"]
+    counts = get_model("nemotron_h").work_counts
+    got = np.asarray(counts.gather(
+        {"mixer": sown[0], "first": sown[1], "second": sown[1]}))
+    assert got.shape == (2 + 2, 3 + held)
+    named = counts.describe(got)
+    assert named["ssd_scan_tokens"] == L and named["ssd_scan_chunks"] == 5
+    assert named["moe_window_trips"] == 2 * int(trips) > 0
+    assert named["moe_assignments_total"] == 2 * 2 * L
+    assert named["moe_assignments_local"] == 2 * int(stats[1])
+    assert named["moe_expert_load_mean"] == pytest.approx(
+        int(stats[1]) / held)
+
+
 @pytest.mark.parametrize("gated", [True, False])
 def test_both_expert_forms_keep_their_tree_and_their_arithmetic(gated):
     """The gated form (the default: three matrices, ``W2(silu(W1 h) * W3
@@ -244,7 +272,10 @@ def test_whole_row_blocks_and_a_padded_width_change_no_value_or_gradient(
         return jax.lax.ragged_dot(a * a, w2, sizes)
 
     def blocked(xs, w1, w2):
-        return moe._grouped_relu2(xs, w1, w2, sizes)
+        w1, w2 = moe._padded_width(w1, w2)
+        blocks = moe._whole_blocks(sizes, rows)
+        return jax.lax.ragged_dot(
+            moe._hidden_rows(False, xs, (w1,), blocks), w2, blocks)
 
     def read(f):        # what the layer gathers back: the groups' rows
         return jax.value_and_grad(
@@ -255,6 +286,53 @@ def test_whole_row_blocks_and_a_padded_width_change_no_value_or_gradient(
     assert float(got) == pytest.approx(float(want), rel=1e-6)
     for a, b in zip(got_g, want_g):
         _close(a, b, 1e-6)
+
+
+@pytest.mark.parametrize("block,trips", [(144, 1), (8, 4), (4, 5)])
+def test_the_two_matrix_forms_windows_against_the_reference(
+        block, trips, monkeypatch):
+    """The squared-ReLU layer by row windows, its width padded to the tile
+    (12 to 16) and its last group lengthened to whole blocks inside each
+    window, in one trip and in many, inside a caller's ``jax.checkpoint``
+    too: the reference's layer in value and in every gradient."""
+    from olearning_sim_tpu.models import moe
+
+    monkeypatch.setattr(moe, "GROUPED_ROW_BLOCK", block)
+    monkeypatch.setattr(moe, "GROUPED_TILE", 8)
+    experts, top_k, M = 64, ref.TOP_K, 12
+    x = _x(5, n=1, length=24)
+    layer = DroplessMoE(experts, top_k, (0, 1, 2, 3), M,
+                        routed_scaling_factor=ref.ROUTED_SCALING_FACTOR,
+                        dtype=F32, gated=False)
+    p = dict(layer.init(jax.random.key(2), x)["params"])
+    # The four held experts favoured: 96 of the 144 assignments are
+    # theirs, where an even router over 64 would send them 9.
+    p["expert_bias"] = jnp.zeros(experts).at[:4].set(1.0)
+    probe = _x(6, n=1, length=24)
+
+    def want_fn(p, x):
+        return (ref.experts(p, "", x[0], held=(0, 1, 2, 3))
+                * probe[0]).sum()
+
+    def got_fn(p, x):
+        y, inter = layer.apply({"params": p}, x, mutable=["intermediates"])
+        return (y * probe).sum(), inter["intermediates"]
+
+    want, want_g = jax.value_and_grad(want_fn, argnums=(0, 1))(p, x)
+    (got, inter), got_g = jax.value_and_grad(
+        got_fn, argnums=(0, 1), has_aux=True)(p, x)
+    local = int(inter["moe_stats"][0][1])
+    window = moe.window_rows(24 * top_k, 4, experts)
+    assert int(inter["moe_window_trips"][0]) == -(-local // window) == trips
+    assert int(inter["moe_stats"][0][2]) == local
+    again = jax.grad(jax.checkpoint(lambda p, x: got_fn(p, x)[0]),
+                     argnums=(0, 1))(p, x)
+    assert float(got) == pytest.approx(float(want), rel=2e-4)
+    for grads in (got_g, again):
+        _close(grads[1], want_g[1])
+        _close(grads[0]["gate"], want_g[0]["gate"])
+        for name in ("expert_w1", "expert_w2"):
+            _close(grads[0][name], want_g[0][name])
 
 
 def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
@@ -441,9 +519,9 @@ def test_the_backward_pass_computes_only_the_mamba_layers_again():
 
 @pytest.mark.parametrize("config,digest", [
     ("lfm2_moe_ep8",
-     "5acc49ca9bde1302f860401ea341ea72f9c892879a47e1d274daa0abd0e5f5d1"),
+     "59191acdb37055bf5817e492f20350e34047bde0d744cc9469aac53471de34e3"),
     ("kimi_linear_ep32",
-     "d4a2409ed05fc5bf9e79da5f9b348d514362fa5c571c77ae8f65819888c86645"),
+     "7cb35fc076f5d6705ebb72d632aa97a0de935ff2a497cb04e117621f38ff8f51"),
 ])
 def test_the_other_sparse_decoders_round_programs_did_not_move(
         config, digest):
@@ -452,12 +530,13 @@ def test_the_other_sparse_decoders_round_programs_did_not_move(
     they lowered to before this family chose its layers to compute again
     (PR 40: the digests are the parent commit's), so their compilation
     cache keys stand. An intended edit to those families pins them anew
-    (the failure prints the new digest): both are PR 45's, whose attention
-    layers sow ``attend_pairs_needed`` and ``attend_pairs_computed`` (one
-    more row of ``model_stats`` in ``lfm2_moe_ep8``, two more counts a
-    vector in ``kimi_linear_ep32``) and whose ``lfm2._attend`` builds its
-    mask from two ``iota``s where it held an L x L constant; at these
-    presets' 16 and 80 tokens it is one block, as it was."""
+    (the failure prints the new digest): both are PR 47's, an intended edit
+    to the module the three families share: ``models/moe.py``
+    ``DroplessMoE`` works its sorted rows in windows inside a
+    ``lax.while_loop`` with a backward loop of its own and sows
+    ``moe_window_trips`` (one more row at the end of ``model_stats``). PR 45's before them: the attention layers'
+    ``attend_pairs_*`` counts and ``lfm2._attend``'s mask from two
+    ``iota``s."""
     def read(*path):
         with open(os.path.join(*path, config + ".json")) as f:
             return json.load(f)
