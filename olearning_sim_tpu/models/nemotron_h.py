@@ -339,29 +339,36 @@ class NemotronH(nn.Module):
     final norm and the untied head.
 
     **Which layers the backward pass computes again is chosen by the
-    layer's letter**, not by a knob: an ``"M"`` layer is wrapped in
-    ``nn.remat`` (its input alone is kept and the layer runs a second time
-    before its gradient), ``"E"`` and ``"*"`` layers are not (their
-    residuals are kept, :class:`Layer` has their sizes). Sized by compiling
+    layer's letter**, not by a knob, and since PR 48 the choice is none: no
+    layer is wrapped in ``nn.remat``; every layer keeps its residuals
+    (:class:`Layer` has their sizes) and the backward pass computes again
+    only what the parts' own checkpoints cover, ``lfm2._attend``'s scores
+    and an ``"E"`` layer's windows (``models/moe.py``). Sized by compiling
     the benchmark cell's round program (``MEMEM*E``, 4,096 tokens a step, 8
     held experts, 21 B a parameter of state around it) for a v5e with
-    ``scripts/compile_cell.py``: temporaries + arguments + generated code
-    are 14.91 GiB of the chip's 15.75 (about 0.26 more are the runtime's),
-    13.34 with every layer computed again. An ``"M"`` layer's ``nn.remat``
-    buys 0.83 GiB there and costs the cheapest second pass; without it the
-    three do not fit (15.67 GiB before the runtime's share). An ``"E"``
-    layer's bought 0.48-0.58 GiB for the dearest second pass (the sort, the
-    gathers and the combine over ``experts_per_token`` rows a token); an
-    attention layer's bought nothing. What a caller gives up: every token of
-    a local step beyond the cell's 4,096 needs about 140 KB more for each
-    ``"E"`` layer than when every layer was computed again (PERF.md section
-    6, PR 40, has the compiler's table and the chip's readings). Those are
-    PR 40's sizes: since PR 47 an ``"E"`` layer's routed part keeps only its
-    input and routing vectors (``models/moe.py`` works its rows in windows
-    and computes a window again in its own backward loop), the compiler's
-    ``peak_memory`` for the cell fell 10.80 -> 10.01 GiB, and about one
-    ``"M"`` layer's ``nn.remat`` would now fit (PERF.md section 7, item
-    5d(iii))."""
+    ``scripts/compile_cell.py``: temporaries 13.982 GiB, ``peak_memory``
+    12.179 GiB, and the compiler's own check (0.258 reserved + 1.968 of
+    arguments + temporaries less the 1.968 of outputs that share the
+    donated arguments <= 15.75 GiB) leaves 1.51 GiB; on the chip the round
+    with its evaluation peaks at 13.47 GB of 16.9. ``nn.remat`` around the
+    three ``"M"`` layers bought 2.34 GiB there (205 KB a token a layer by
+    the compiler, temporaries 11.640) and cost their second forward, 0.25
+    s of a 2.82 s round on the chip. What a caller gives up: every token of
+    a local step beyond the cell's 4,096 needs about 1.1 MB (three ``"M"``
+    layers' residuals are 0.6 of it; 0.5 MB with them computed again), so
+    about 1,400 more tokens a step still fit beside that state, and a third
+    sequence of 2,048 does not (temporaries 16.064 GiB: the compiler then
+    fits the program some other way, with a third of the code, at a price
+    nobody has measured). A fuller stage puts ``nn.remat`` back around
+    ``Layer`` where ``kind == "M"`` first: the cheapest second pass, proven
+    to change no value (``tests/test_nemotron_h.py``), and with it three
+    sequences a step compile with 2.87 GiB to spare. An ``"E"`` layer's
+    ``nn.remat`` bought 0.5 GiB for the dearest second pass when PR 40
+    took it off (the sort, the gathers and the combine over
+    ``experts_per_token`` rows a token) and buys less since PR 47 (its
+    routed part keeps only its input and routing vectors); an attention
+    layer's bought nothing (PERF.md section 6, PR 48, has the compiler's
+    table of the policies and the chip's readings)."""
 
     vocab_size: int = 131072
     max_len: int = 262144           # positions served; no position table
@@ -397,12 +404,11 @@ class NemotronH(nn.Module):
             embedding_init=nn.initializers.normal(stddev=0.02),
             param_dtype=jnp.float32)(tokens)
         for i, kind in enumerate(self.pattern):
-            # Only an M layer is computed again in the backward pass (215
-            # KB a token a layer not kept); an E layer's 140 KB and an
-            # attention layer's 17 KB a token are kept. Sized against the
-            # compiler's 14.91 of 15.75 GiB: the class docstring.
-            layer = nn.remat(Layer) if kind == "M" else Layer
-            h = layer(
+            # No layer is wrapped in nn.remat: an M layer's 215 KB a token
+            # are kept like an attention layer's 17 (an E layer's routed
+            # part keeps its input). Sized against the compiler's check,
+            # 1.51 GiB to spare: the class docstring.
+            h = Layer(
                 kind=kind, mamba_heads=self.mamba_heads,
                 mamba_head_dim=self.mamba_head_dim,
                 state_size=self.state_size, groups=self.groups,
