@@ -26,7 +26,14 @@ and ``out = (rms_d(o) * sigmoid((x W_ga) W_gb)) W_o``. The recurrence is
 computed chunked (:func:`chunk_scan`): a chunk of :data:`CHUNK` tokens is
 one unit lower-triangular system, built and inverted by sub-blocks of
 :data:`SUB` tokens, pairwise decays and substitution inside a sub-block and
-matrix products between them.
+matrix products between them. What runs where: a program lowered for a TPU
+runs the scan forward as the Pallas kernel of ``ops/kda_scan.py`` (the same
+arithmetic with a chunk's system, its inverse and the state in VMEM), in
+training's forward pass, the evaluation and the initialiser's trace alike;
+every other platform runs :func:`chunk_scan` itself, and so does every
+backward pass (the forward computed again, then its backward pass), which
+makes this file's plain-JAX code the backward's code and the tests'
+reference.
 
 **MLA**: ``q = x W_q`` (``heads`` x (``qk_nope_dim`` + ``qk_rope_dim``));
 ``[c, k_r] = x W_kva`` (``kv_rank`` + ``qk_rope_dim``; ``k_r`` is shared by
@@ -49,8 +56,9 @@ inverse of a chunk's triangular system and the products with any of them
 The embedding is only looked up (:class:`~olearning_sim_tpu.models.lookup.
 LookupOnlyEmbed`), so a trainer may train it by the rows a step reads. Every
 KDA layer sows ``kda_stats`` (:data:`STATS`): the tokens and the chunks its
-scan took; every MLA layer, the pairs its mask lets through and the scores it
-formed.
+scan took and how many of those chunks the kernel took (all of them on a TPU,
+none elsewhere); every MLA layer, the pairs its mask lets through and the
+scores it formed.
 """
 
 from __future__ import annotations
@@ -79,9 +87,11 @@ L2_EPS = 1e-6
 # What a KDA or MLA layer sows as ``kda_stats`` on every call, one int32
 # vector: the tokens and chunks of a KDA layer's scan, the (query, key)
 # pairs an MLA layer's mask lets through, a head, and the scores a head
-# formed for them (``lfm2.attend_pairs``).
+# formed for them (``lfm2.attend_pairs``), and the chunks of a KDA layer's
+# scan that ``ops/kda_scan.py``'s kernel took forward (= the chunks where
+# the program was lowered for a TPU, 0 elsewhere).
 STATS = ("kda_scan_tokens", "kda_scan_chunks", "attend_pairs_needed",
-         "attend_pairs_computed")
+         "attend_pairs_computed", "kda_scan_kernel_chunks")
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -218,7 +228,13 @@ def chunk_scan(q, k, v, g, beta):
     exponent is a difference that is never positive, those of a decay split
     at a sub-block's first token too (:func:`_intra_chunk`). A sequence is
     padded to whole chunks with tokens that write nothing and decay
-    nothing."""
+    nothing.
+
+    :class:`KDA` calls it through ``ops.kda_scan.chunk_scan``: on a TPU the
+    forward pass is that module's kernel, which does the same arithmetic a
+    chunk at a time in VMEM, and this function is what the backward pass
+    differentiates (and computes forward once more to do so); elsewhere it
+    is the forward pass too."""
     n, L, H, K = q.shape
     N = -(-L // CHUNK)
     pad = N * CHUNK - L
@@ -302,18 +318,24 @@ def _gated_out(o, x, p, eps, dtype):
 
 class KDA(nn.Module):
     """The gated delta-rule mixer. Its three parts (what feeds the scan,
-    the scan, what follows it) are each a ``jax.checkpoint``: the backward
-    pass keeps what enters and leaves a part (``x``; ``q, k, v, g, beta``;
-    ``o``) and computes the part's float32 internals again, once, when it
-    gets there. Sized by compiling the benchmark cell's round program (five
-    blocks, 4,096 tokens a step, 21 B a parameter of state around it) for a
-    v5e with ``scripts/compile_cell.py`` (PERF.md section 6, PR 42): the
-    checkpoint around the scan buys 1.6 GiB a step where the blocks keep
-    their residuals (its internals, ``A``, ``B``, the inverse and ``w`` of
-    every chunk, would live from the forward pass to the backward in all
-    four KDA layers: without it the compiler refuses the program, 16.06 of
-    15.75 GiB); the two around it 0.5 GiB together. Inside the scan the
-    chunk bodies are checkpoints again (:func:`_intra_chunk`,
+    the scan, what follows it) each keep for the backward pass what enters
+    them (``x``; ``q, k, v, g, beta``; ``o`` and ``x``) and compute their
+    float32 internals again, once, when it gets there: the two around the
+    scan as a ``jax.checkpoint``, the scan as ``ops.kda_scan.chunk_scan``, a
+    custom VJP whose forward pass is the Pallas kernel where the program is
+    lowered for a TPU (the plain :func:`chunk_scan` elsewhere) and whose
+    backward pass is ``jax.vjp`` of the checkpointed :func:`chunk_scan` at
+    the five kept inputs, which is what the ``jax.checkpoint`` that stood
+    here until PR 49 did in the backward pass and what it kept. Sized by
+    compiling the benchmark cell's round program (five blocks, 4,096 tokens
+    a step, 21 B a parameter of state around it) for a v5e with
+    ``scripts/compile_cell.py`` (PERF.md section 6, PR 42): keeping the
+    scan's internals instead (``A``, ``B``, the inverse and ``w`` of every
+    chunk, from the forward pass to the backward in all four KDA layers)
+    would cost 1.6 GiB a step where the blocks keep their residuals, and the
+    compiler refuses that program, 16.06 of 15.75 GiB; the two checkpoints
+    around the scan buy 0.5 GiB together. Inside the plain scan the chunk
+    bodies are checkpoints again (:func:`_intra_chunk`,
     :func:`_chunk_step`)."""
 
     heads: int
@@ -344,12 +366,18 @@ class KDA(nn.Module):
             q, k, v, g, beta = jax.checkpoint(
                 _scan_inputs, static_argnums=(2, 3))(x, p, H, self.dtype)
         with jax.named_scope("kda.chunk_scan"):
-            o = jax.checkpoint(chunk_scan)(q, k, v, g, beta)
+            # Imported where a KDA layer is traced: a process that builds no
+            # such model (every other family's) does not load Pallas, a
+            # second of its start.
+            from olearning_sim_tpu.ops import kda_scan
+
+            o, kernel_chunks = kda_scan.chunk_scan(chunk_scan, q, k, v, g, beta)
         with jax.named_scope("kda.projections"):
             y = jax.checkpoint(_gated_out, static_argnums=(3, 4))(
                 o, x, p, self.eps, self.dtype)
-        self.sow("intermediates", "kda_stats",
-                 jnp.asarray([n * L, n * -(-L // CHUNK), 0, 0], jnp.int32))
+        self.sow("intermediates", "kda_stats", jnp.stack(
+            [jnp.int32(n * L), jnp.int32(n * -(-L // CHUNK)), jnp.int32(0),
+             jnp.int32(0), kernel_chunks]))
         return y
 
 
@@ -388,7 +416,8 @@ class MLA(nn.Module):
             # recomputed in the backward pass.
             ctx = _attend(q, k, v)
             out = _mm(ctx.reshape(n, L, H * Dv), out_proj, self.dtype)
-        self.sow("intermediates", "kda_stats", sown_attend_pairs(n, L, 2))
+        self.sow("intermediates", "kda_stats", jnp.pad(
+            sown_attend_pairs(n, L, 2), (0, 1)))
         return out
 
 

@@ -61,8 +61,11 @@ class TransformerBlock(nn.Module):
             # Fused Pallas kernel: no HBM score tensor. Slower than XLA's
             # fused dense path on current chips (see ops/flash_attention.py);
             # exists as the ring per-step primitive and for variants XLA
-            # can't fuse. Not for FedCore client models: the round program
-            # leaves mp to the auto partitioner, which Mosaic refuses.
+            # can't fuse. Not for FedCore client models as it stands: the
+            # round program leaves mp to the auto partitioner, which Mosaic
+            # refuses, and this call does not yet go through
+            # ops.lowering.manual_over_auto_axes, which lifts that (the
+            # kernel still loses to dense on every shape measured).
             from olearning_sim_tpu.ops import flash_attention
 
             B, L, W = x.shape

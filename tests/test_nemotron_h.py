@@ -529,7 +529,7 @@ def test_the_backward_pass_computes_no_layer_again():
     ("lfm2_moe_ep8",
      "59191acdb37055bf5817e492f20350e34047bde0d744cc9469aac53471de34e3"),
     ("kimi_linear_ep32",
-     "7cb35fc076f5d6705ebb72d632aa97a0de935ff2a497cb04e117621f38ff8f51"),
+     "c661049ccebb1badcc0db45a39a96df0195037c816d4f95ef6dab230b5c6a503"),
 ])
 def test_the_other_sparse_decoders_round_programs_did_not_move(
         config, digest):
@@ -538,7 +538,11 @@ def test_the_other_sparse_decoders_round_programs_did_not_move(
     they lowered to before this family chose its layers to compute again
     (PR 40: the digests are the parent commit's), so their compilation
     cache keys stand. An intended edit to those families pins them anew
-    (the failure prints the new digest): both are PR 47's, an intended edit
+    (the failure prints the new digest): ``kimi_linear_ep32``'s is PR 49's,
+    an intended edit to that family alone (``KDA`` calls its scan through
+    ``ops/kda_scan.py``'s custom VJP and sows one more count,
+    ``kda_scan_kernel_chunks``; ``lfm2_moe_ep8``'s stood). Before it both
+    were PR 47's, an intended edit
     to the module the three families share: ``models/moe.py``
     ``DroplessMoE`` works its sorted rows in windows inside a
     ``lax.while_loop`` with a backward loop of its own and sows
