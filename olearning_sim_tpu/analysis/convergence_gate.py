@@ -7,8 +7,7 @@ quality* — an aggressive staleness discount, a defense that stopped
 binding under attack, a drift path training on the wrong labels. This
 analyzer runs a small fixed-seed convergence grid (the
 :func:`~olearning_sim_tpu.engine.convergence.run_convergence_task`
-harness — the SAME code path ``bench.py --convergence`` banks, so the
-gate and the bench can never measure different things) and diffs each
+harness, end-to-end through a ``SimulationRunner``) and diffs each
 entry's deterministic record against the blessed envelopes in
 ``analysis/convergence.json``:
 
@@ -17,8 +16,8 @@ entry                 engine config
 ====================  ===================================================
 clean                 plain fedavg (the quality baseline)
 async_staleness       buffered async commits, polynomial staleness
-                      discount (PR 8) — prices the 2.19x throughput
-                      headline in accuracy terms
+                      discount (PR 8) — prices what the async
+                      path's throughput costs in accuracy
 attack_trimmed_mean   20% scale-factor-30 attackers + clip/trimmed-mean
                       defense (PR 5/6) — the defended entry must stay
                       near the clean baseline

@@ -17,8 +17,7 @@ arxiv 2504.13850 — both evaluate on exactly this axis):
   AND wall time. Tracker state rides per-round history records →
   checkpoint meta (like the deadline/quarantine/async clocks), so a
   supervisor-resumed run replays the identical record;
-- :func:`run_convergence_task` — the shared harness behind
-  ``bench.py --convergence`` (BENCH_convergence.json) and the
+- :func:`run_convergence_task` — the harness behind the
   ``analysis/convergence_gate`` regression gate: one (family ×
   engine-config) convergence run end-to-end through a SimulationRunner.
 

@@ -180,13 +180,9 @@ class FedCoreConfig:
     # either and the aux estimator follows the choice.
     sample_mode: str = "auto"
     # lax.scan unroll factor for the local-SGD step loop. Unrolling lets XLA
-    # fuse/pipeline across sequential steps (the per-step tensors are small,
-    # so scan's one-iteration window otherwise leaves the scalar units and
-    # DMA idle between convs). Measured on v5e at the headline config
-    # (10k clients, cnn4): block_clients/step_unroll 256/1 -> 0.42
-    # rounds/sec, 32/10 -> 0.69, 16/10 -> 0.72 — small blocks + full unroll
-    # let XLA pick a far better batched-kernel conv strategy than the big
-    # 256-group one. Sweep with scripts/profile_headline.py.
+    # fuse/pipeline across sequential steps, and multiplies the program's
+    # generated code by the factor: the benchmark's cells set 1 or 2
+    # (benchmark/configs/), and PERF.md section 6 has what each measured.
     step_unroll: int = 1
     # Unroll factor for the outer scan over client blocks. Successive blocks
     # are independent work (the carry is only an accumulator), so a small

@@ -2431,8 +2431,7 @@ class MultiTaskDispatcher:
       (tests/test_async.py asserts this).
     - ``interleave="thread"``: each task runs its full round loop on its
       own thread, so one task's host-side phases overlap another's
-      device compute and the device queue stays fed between programs —
-      the measured aggregate-throughput win banked in BENCH_async.json.
+      device compute and the device queue stays fed between programs.
 
     Leases (PR 4 supervision, reused): given a ``task_repo`` with lease
     columns, the dispatcher claims each task's lease at start, renews it

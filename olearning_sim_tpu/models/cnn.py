@@ -41,29 +41,6 @@ class CNN(nn.Module):
         return nn.Dense(self.num_classes, dtype=jnp.float32)(x)
 
 
-class CNNPool(nn.Module):
-    """Legacy conv/max-pool/dense variant (the round-1 ``cnn4``). Kept for
-    comparison; ~5x slower per round on TPU because of max-pool's
-    ``select_and_scatter`` backward and the flatten->Dense K=batch
-    contraction."""
-
-    features: Sequence[int] = (32, 64)
-    dense: int = 128
-    num_classes: int = 10
-
-    @nn.compact
-    def __call__(self, x):
-        x = x.astype(jnp.bfloat16)
-        for f in self.features:
-            x = nn.Conv(f, (3, 3), padding="SAME", dtype=jnp.bfloat16)(x)
-            x = nn.relu(x)
-            x = nn.max_pool(x, (2, 2), strides=(2, 2))
-        x = x.reshape((x.shape[0], -1))
-        x = nn.Dense(self.dense, dtype=jnp.bfloat16)(x)
-        x = nn.relu(x)
-        return nn.Dense(self.num_classes, dtype=jnp.float32)(x)
-
-
 register_model(
     ModelSpec(
         name="cnn4",
@@ -71,15 +48,5 @@ register_model(
         example_input_shape=(32, 32, 3),
         num_classes=10,
         defaults={"features": (32, 64, 128), "num_classes": 10},
-    )
-)
-
-register_model(
-    ModelSpec(
-        name="cnn4_pool",
-        builder=CNNPool,
-        example_input_shape=(32, 32, 3),
-        num_classes=10,
-        defaults={"features": (32, 64), "dense": 128, "num_classes": 10},
     )
 )

@@ -71,8 +71,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from olearning_sim_tpu.models.lfm2 import (
-    RMSNorm, SwiGLU, _attend, _dense_init, _mm, sown_attend_pairs)
+from olearning_sim_tpu.models.decoder_parts import (
+    RMSNorm, SwiGLU, a_log_init, attend, causal_taps, dense_init,
+    dt_bias_init, mm, sown_attend_pairs, work_counts_beside)
 from olearning_sim_tpu.models.lookup import LookupOnlyEmbed
 from olearning_sim_tpu.models import moe
 from olearning_sim_tpu.models.registry import ModelSpec, register_model
@@ -87,7 +88,7 @@ L2_EPS = 1e-6
 # What a KDA or MLA layer sows as ``kda_stats`` on every call, one int32
 # vector: the tokens and chunks of a KDA layer's scan, the (query, key)
 # pairs an MLA layer's mask lets through, a head, and the scores a head
-# formed for them (``lfm2.attend_pairs``), and the chunks of a KDA layer's
+# formed for them (``decoder_parts.attend_pairs``), and the chunks of a KDA layer's
 # scan that ``ops/kda_scan.py``'s kernel took forward (= the chunks where
 # the program was lowered for a TPU, 0 elsewhere).
 STATS = ("kda_scan_tokens", "kda_scan_chunks", "attend_pairs_needed",
@@ -264,26 +265,6 @@ def chunk_scan(q, k, v, g, beta):
     return out.reshape((n, N * CHUNK) + out.shape[3:])[:, :L]
 
 
-def _a_log_init(key, shape, dtype=jnp.float32):
-    """log of uniform(1, 16): the family's modelling code."""
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-
-def _dt_bias_init(key, shape, dtype=jnp.float32):
-    """Inverse softplus of a step drawn log-uniformly from [0.001, 0.1]."""
-    dt = jnp.exp(jax.random.uniform(
-        key, shape, dtype, np.log(0.001), np.log(0.1)))
-    return dt + jnp.log(-jnp.expm1(-dt))
-
-
-def _causal_taps(x, taps):
-    """Causal depthwise convolution of ``x`` [n, L, D] (float32) with
-    ``taps`` [T, D]; tap j multiplies the input T-1-j positions back."""
-    T, L = taps.shape[0], x.shape[1]
-    x = jnp.pad(x, ((0, 0), (T - 1, 0), (0, 0)))
-    return sum(taps[j] * x[:, j:j + L] for j in range(T))
-
-
 def _l2norm(u):
     return u * jax.lax.rsqrt((u * u).sum(-1, keepdims=True) + L2_EPS)
 
@@ -294,14 +275,14 @@ def _scan_inputs(x, p, heads, dtype):
     n, L, _ = x.shape
     f32 = jnp.float32
     q, k, v = (
-        jax.nn.silu(_causal_taps(
-            _mm(x, p[f"{r}_proj"], dtype).astype(f32), p[f"{r}_conv"])
+        jax.nn.silu(causal_taps(
+            mm(x, p[f"{r}_proj"], dtype).astype(f32), p[f"{r}_conv"])
         ).reshape(n, L, heads, -1) for r in "qkv")
     q, k = _l2norm(q) / np.sqrt(q.shape[-1]), _l2norm(k)
-    gate_in = _mm(_mm(x, p["f_a"], dtype), p["f_b"], dtype)
+    gate_in = mm(mm(x, p["f_a"], dtype), p["f_b"], dtype)
     g = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(
         gate_in.astype(f32) + p["dt_bias"]).reshape(n, L, heads, -1)
-    beta = jax.nn.sigmoid(_mm(x, p["b_proj"], dtype).astype(f32))
+    beta = jax.nn.sigmoid(mm(x, p["b_proj"], dtype).astype(f32))
     return q, k, v, g, beta
 
 
@@ -311,9 +292,9 @@ def _gated_out(o, x, p, eps, dtype):
     n, L, _ = x.shape
     o = o * jax.lax.rsqrt(
         jnp.mean(o * o, axis=-1, keepdims=True) + eps) * p["o_norm"]
-    gate = jax.nn.sigmoid(_mm(
-        _mm(x, p["g_a"], dtype), p["g_b"], dtype).astype(jnp.float32))
-    return _mm(o.reshape(n, L, -1) * gate, p["out_proj"], dtype)
+    gate = jax.nn.sigmoid(mm(
+        mm(x, p["g_a"], dtype), p["g_b"], dtype).astype(jnp.float32))
+    return mm(o.reshape(n, L, -1) * gate, p["out_proj"], dtype)
 
 
 class KDA(nn.Module):
@@ -349,7 +330,7 @@ class KDA(nn.Module):
         n, L, W = x.shape
         H, D, T = self.heads, self.head_dim, self.conv_kernel
         f32 = jnp.float32
-        p = {f"{r}_proj": self.param(f"{r}_proj", _dense_init, (W, H * D),
+        p = {f"{r}_proj": self.param(f"{r}_proj", dense_init, (W, H * D),
                                      f32) for r in "qkv"}
         p.update({f"{r}_conv": self.param(
             f"{r}_conv", nn.initializers.lecun_normal(), (T, H * D), f32)
@@ -357,9 +338,9 @@ class KDA(nn.Module):
         for name, shape in (("f_a", (W, D)), ("f_b", (D, H * D)),
                             ("b_proj", (W, H)), ("g_a", (W, D)),
                             ("g_b", (D, H * D)), ("out_proj", (H * D, W))):
-            p[name] = self.param(name, _dense_init, shape, f32)
-        p["A_log"] = self.param("A_log", _a_log_init, (H,), f32)
-        p["dt_bias"] = self.param("dt_bias", _dt_bias_init, (H * D,), f32)
+            p[name] = self.param(name, dense_init, shape, f32)
+        p["A_log"] = self.param("A_log", a_log_init, (H,), f32)
+        p["dt_bias"] = self.param("dt_bias", dt_bias_init, (H * D,), f32)
         p["o_norm"] = self.param("o_norm", nn.initializers.ones, (D,), f32)
 
         with jax.named_scope("kda.projections"):
@@ -399,23 +380,23 @@ class MLA(nn.Module):
         H, R = self.heads, self.kv_rank
         Dn, Dr, Dv = self.qk_nope_dim, self.qk_rope_dim, self.v_dim
         f32 = jnp.float32
-        q_proj = self.param("q_proj", _dense_init, (W, H * (Dn + Dr)), f32)
-        kv_a = self.param("kv_a", _dense_init, (W, R + Dr), f32)
-        kv_b = self.param("kv_b", _dense_init, (R, H * (Dn + Dv)), f32)
-        out_proj = self.param("out_proj", _dense_init, (H * Dv, W), f32)
+        q_proj = self.param("q_proj", dense_init, (W, H * (Dn + Dr)), f32)
+        kv_a = self.param("kv_a", dense_init, (W, R + Dr), f32)
+        kv_b = self.param("kv_b", dense_init, (R, H * (Dn + Dv)), f32)
+        out_proj = self.param("out_proj", dense_init, (H * Dv, W), f32)
         kv_norm = RMSNorm(self.eps, name="kv_norm")
         with jax.named_scope("mla.attention"):
-            q = _mm(x, q_proj, self.dtype).reshape(n, L, H, 1, Dn + Dr)
-            c, k_r = jnp.split(_mm(x, kv_a, self.dtype), [R], axis=-1)
+            q = mm(x, q_proj, self.dtype).reshape(n, L, H, 1, Dn + Dr)
+            c, k_r = jnp.split(mm(x, kv_a, self.dtype), [R], axis=-1)
             k_n, v = jnp.split(
-                _mm(kv_norm(c), kv_b, self.dtype).reshape(n, L, H, Dn + Dv),
+                mm(kv_norm(c), kv_b, self.dtype).reshape(n, L, H, Dn + Dv),
                 [Dn], axis=-1)
             k = jnp.concatenate(
                 [k_n, jnp.broadcast_to(k_r[:, :, None], (n, L, H, Dr))], -1)
             # One query head a key/value head; by query blocks, the scores
             # recomputed in the backward pass.
-            ctx = _attend(q, k, v)
-            out = _mm(ctx.reshape(n, L, H * Dv), out_proj, self.dtype)
+            ctx = attend(q, k, v)
+            out = mm(ctx.reshape(n, L, H * Dv), out_proj, self.dtype)
         self.sow("intermediates", "kda_stats", jnp.pad(
             sown_attend_pairs(n, L, 2), (0, 1)))
         return out
@@ -486,7 +467,7 @@ class KimiLinear(nn.Module):
     vectors), and the backward pass
     computes again only what those checkpoints cover: each of a KDA
     mixer's three parts once (:class:`KDA`), the chunk bodies inside the
-    scan, the MLA scores (``lfm2._attend``), and the routed experts' rows
+    scan, the MLA scores (``decoder_parts.attend``), and the routed experts' rows
     and hidden products, which the backward loop of ``models/moe.py``
     gathers and multiplies again a window at a time. ``nn.remat`` around
     every block would run
@@ -557,7 +538,7 @@ class KimiLinear(nn.Module):
                 routed_scaling_factor=self.routed_scaling_factor,
                 dtype=self.dtype, name=f"layers_{i}")(h)
         h = RMSNorm(self.norm_eps, name="final_norm")(h)
-        head = self.param("head", _dense_init,
+        head = self.param("head", dense_init,
                           (self.width, self.vocab_size), jnp.float32)
         return jnp.dot(h.astype(self.dtype), head.astype(self.dtype),
                        preferred_element_type=jnp.float32)
@@ -574,7 +555,7 @@ register_model(
         # DroplessMoE's jax.lax.ragged_dot has no batching rule for
         # per-client expert weights.
         vmap_clients=False,
-        work_counts=moe.work_counts_beside("kda_stats", STATS),
+        work_counts=work_counts_beside("kda_stats", STATS),
         defaults={
             "vocab_size": 163840, "max_len": 1048576, "width": 2304,
             "layer_types": ["kda", "kda", "kda", "mla"],

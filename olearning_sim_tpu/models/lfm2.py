@@ -40,26 +40,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from olearning_sim_tpu.models.moe import DroplessMoE, work_counts_beside
+# BLOCK and attend_pairs are not used below: the benchmark's own tests read
+# them as this module's (tests/benchmark/test_layer_attention_pairs.py).
+from olearning_sim_tpu.models.decoder_parts import (  # noqa: F401
+    BLOCK, RMSNorm, SwiGLU, attend, attend_pairs, dense_init, mm,
+    sown_attend_pairs, work_counts_beside)
+from olearning_sim_tpu.models.moe import DroplessMoE
 from olearning_sim_tpu.models.registry import ModelSpec, register_model
-
-_dense_init = nn.initializers.lecun_normal()
-
-
-def _mm(x, kernel, dtype):
-    return jnp.dot(x.astype(dtype), kernel.astype(dtype))
-
-
-class RMSNorm(nn.Module):
-    eps: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
-        x = x.astype(jnp.float32)
-        return x * jax.lax.rsqrt(
-            jnp.mean(x * x, axis=-1, keepdims=True) + self.eps) * scale
 
 
 class ShortConv(nn.Module):
@@ -71,18 +58,18 @@ class ShortConv(nn.Module):
     @nn.compact
     def __call__(self, x):
         W, K = x.shape[-1], self.kernel_size
-        in_proj = self.param("in_proj", _dense_init, (W, 3 * W), jnp.float32)
+        in_proj = self.param("in_proj", dense_init, (W, 3 * W), jnp.float32)
         # conv[j] multiplies the input K-1-j positions back.
         conv = self.param("conv", nn.initializers.lecun_normal(), (K, W),
                           jnp.float32)
-        out_proj = self.param("out_proj", _dense_init, (W, W), jnp.float32)
+        out_proj = self.param("out_proj", dense_init, (W, W), jnp.float32)
         with jax.named_scope("lfm2.short_conv"):
-            b, c, u = jnp.split(_mm(x, in_proj, self.dtype), 3, axis=-1)
+            b, c, u = jnp.split(mm(x, in_proj, self.dtype), 3, axis=-1)
             bu = jnp.pad((b * u).astype(jnp.float32),
                          ((0, 0), (K - 1, 0), (0, 0)))
             L = x.shape[1]
             y = sum(conv[j] * bu[:, j:j + L] for j in range(K))
-            return _mm(c.astype(jnp.float32) * y, out_proj, self.dtype)
+            return mm(c.astype(jnp.float32) * y, out_proj, self.dtype)
 
 
 def _rotary(x, theta: float):
@@ -99,69 +86,9 @@ def _rotary(x, theta: float):
 
 # What an attention layer sows as ``lfm2_stats`` on every call, one int32
 # vector: the (query, key) pairs its causal mask lets through, a head, and
-# the scores a head formed for them (:func:`attend_pairs`, by the sequences).
+# the scores a head formed for them (``decoder_parts.attend_pairs``, by the
+# sequences).
 STATS = ("attend_pairs_needed", "attend_pairs_computed")
-# Queries a block of :func:`_attend`: phi4flash's window block, and a whole
-# number of MXU tiles. 256 takes 0.42 to 0.82 of the time on the chip and
-# doubles the blocks' code, which set-up pays (PERF.md section 6, PR 45).
-BLOCK = 512
-
-
-def _query_blocks(L: int, block: int):
-    """(first query, end) of each block of ``block`` queries of ``L``; the
-    last may be shorter."""
-    return [(start, min(L, start + block)) for start in range(0, L, block)]
-
-
-def attend_pairs(L: int, block: int = BLOCK) -> Tuple[int, int]:
-    """(the (query, key) pairs causal attention over ``L`` tokens needs a
-    head, the scores :func:`_attend` forms for them): every query block
-    against the keys up to its own end."""
-    return (L * (L + 1) // 2,
-            sum((end - start) * end for start, end in _query_blocks(L, block)))
-
-
-def sown_attend_pairs(n: int, L: int, before: int = 0):
-    """What a layer that called :func:`_attend` on ``n`` sequences of ``L``
-    tokens sows: :func:`attend_pairs` times ``n``, after ``before`` zeros
-    (the counts of the model's other layers)."""
-    return jnp.asarray(
-        [0] * before + [n * pairs for pairs in attend_pairs(L)], jnp.int32)
-
-
-def _attend_prefix(q, k, v):
-    """Softmax attention of the LAST ``Q`` queries of a prefix, q [n, Q, G,
-    R, D], over all of its keys k [n, P, G, D] and v [n, P, G, Dv]: query
-    ``i`` sees keys ``0 .. P - Q + i``."""
-    Q, P, D = q.shape[1], k.shape[1], q.shape[-1]
-    scores = jnp.einsum("nqgrd,nkgd->ngrqk", q, k,
-                        preferred_element_type=jnp.float32) / np.sqrt(D)
-    causal = jnp.arange(P) <= jnp.arange(P - Q, P)[:, None]
-    probs = jax.nn.softmax(
-        jnp.where(causal, scores, jnp.finfo(jnp.float32).min), -1)
-    return jnp.einsum("ngrqk,nkgd->nqgrd", probs.astype(q.dtype), v)
-
-
-def _attend(q, k, v, block: int = BLOCK):
-    """Causal softmax attention of q [n, L, G, R, D] over k [n, L, G, D] and
-    v [n, L, G, Dv] (R query heads a key/value head), scores and softmax in
-    float32. By blocks of ``block`` queries: a block scores the keys up to
-    its own end and no others, so a row's softmax is over exactly the keys
-    it sees, the largest score array is ``block x L`` a head, and of the
-    ``L x L`` pairs :func:`attend_pairs` are formed (5/8 at four blocks).
-    A last block shorter than ``block`` is a shorter block. One
-    ``jax.checkpoint`` around all of it: the backward pass recomputes the
-    scores rather than keep them."""
-    L = q.shape[1]
-    if L <= block:
-        return jax.checkpoint(_attend_prefix)(q, k, v)
-
-    def blocks(q, k, v):
-        return jnp.concatenate([
-            _attend_prefix(q[:, start:end], k[:, :end], v[:, :end])
-            for start, end in _query_blocks(L, block)], 1)
-
-    return jax.checkpoint(blocks)(q, k, v)
 
 
 class CausalGQA(nn.Module):
@@ -178,37 +105,23 @@ class CausalGQA(nn.Module):
         n, L, W = x.shape
         H, G = self.heads, self.kv_heads
         D = W // H
-        wq = self.param("q_proj", _dense_init, (W, H * D), jnp.float32)
-        wk = self.param("k_proj", _dense_init, (W, G * D), jnp.float32)
-        wv = self.param("v_proj", _dense_init, (W, G * D), jnp.float32)
-        wo = self.param("out_proj", _dense_init, (H * D, W), jnp.float32)
+        wq = self.param("q_proj", dense_init, (W, H * D), jnp.float32)
+        wk = self.param("k_proj", dense_init, (W, G * D), jnp.float32)
+        wv = self.param("v_proj", dense_init, (W, G * D), jnp.float32)
+        wo = self.param("out_proj", dense_init, (H * D, W), jnp.float32)
         q_norm, k_norm = (RMSNorm(self.eps, name="q_norm"),
                           RMSNorm(self.eps, name="k_norm"))
         with jax.named_scope("lfm2.attention"):
-            q = _mm(x, wq, self.dtype).reshape(n, L, H, D)
-            k = _mm(x, wk, self.dtype).reshape(n, L, G, D)
-            v = _mm(x, wv, self.dtype).reshape(n, L, G, D)
+            q = mm(x, wq, self.dtype).reshape(n, L, H, D)
+            k = mm(x, wk, self.dtype).reshape(n, L, G, D)
+            v = mm(x, wv, self.dtype).reshape(n, L, G, D)
             q = _rotary(q_norm(q), self.rope_theta)
             k = _rotary(k_norm(k), self.rope_theta)
-            ctx = _attend(q.reshape(n, L, G, H // G, D).astype(self.dtype),
+            ctx = attend(q.reshape(n, L, G, H // G, D).astype(self.dtype),
                           k.astype(self.dtype), v)
-            out = _mm(ctx.reshape(n, L, H * D), wo, self.dtype)
+            out = mm(ctx.reshape(n, L, H * D), wo, self.dtype)
         self.sow("intermediates", "lfm2_stats", sown_attend_pairs(n, L))
         return out
-
-
-class SwiGLU(nn.Module):
-    mlp_dim: int
-    dtype: jnp.dtype = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        W = x.shape[-1]
-        w1 = self.param("w1", _dense_init, (W, self.mlp_dim), jnp.float32)
-        w3 = self.param("w3", _dense_init, (W, self.mlp_dim), jnp.float32)
-        w2 = self.param("w2", _dense_init, (self.mlp_dim, W), jnp.float32)
-        gated = jax.nn.silu(_mm(x, w1, self.dtype)) * _mm(x, w3, self.dtype)
-        return _mm(gated, w2, self.dtype)
 
 
 class LFM2(nn.Module):

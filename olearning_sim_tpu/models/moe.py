@@ -47,15 +47,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from olearning_sim_tpu.models.registry import (
-    ModelSpec, WorkCounts, register_model, sown)
+from olearning_sim_tpu.models.decoder_parts import TRIPS
+from olearning_sim_tpu.models.registry import ModelSpec, register_model
 
-
-# What a DroplessMoE layer sows as ``moe_stats`` on every call, one int32
-# vector: these three counts, then the assignments each held expert got.
-STATS_HEAD = ("assignments_total", "assignments_local", "assignments_computed")
-# ... and beside it, one int32: the trips its loop over row windows took.
-TRIPS = "moe_window_trips"
 BIAS_INIT_SCALE = 0.01
 
 
@@ -400,66 +394,6 @@ class DroplessMoE(nn.Module):
             sizes]))
         self.sow("intermediates", TRIPS, trips)
         return y.reshape(B, L, W).astype(x.dtype)
-
-
-def describe_stats(stats: np.ndarray) -> dict:
-    """Work counts from the ``moe_stats`` of a model's expert layers summed
-    over some stretch of work (``[layers, 3 + held]``): the assignments
-    made, routed to held experts and computed, and the largest and the mean
-    load of a held expert (one of one layer) over that stretch."""
-    stats = np.asarray(stats, np.int64).reshape(-1, stats.shape[-1])
-    head = dict(zip(STATS_HEAD, stats[:, :len(STATS_HEAD)].sum(0).tolist()))
-    loads = stats[:, len(STATS_HEAD):]
-    return {
-        "moe_assignments_total": head["assignments_total"],
-        "moe_assignments_local": head["assignments_local"],
-        "moe_assignments_computed": head["assignments_computed"],
-        "moe_expert_load_max": int(loads.max()),
-        "moe_expert_load_mean": float(loads.mean()),
-    }
-
-
-def gather_stats(intermediates):
-    """The ``moe_stats`` a forward pass sowed, one row an expert layer."""
-    found = sown(intermediates, "moe_stats")
-    return jnp.stack(found) if found else None
-
-
-def work_counts_beside(sown_name: str, names: Tuple[str, ...]) -> WorkCounts:
-    """For the ``ModelSpec`` of a model whose mixers sow counts of their own
-    (``sown_name``: one int32 vector a layer, ``len(names)`` long) beside
-    these layers': one array of a forward pass's counts, the mixers' vectors
-    summed as its one row where there are no expert layers, else the expert
-    layers' ``moe_stats`` a row each, then a row that starts with the
-    mixers' sum, then a row that starts with the expert layers' trips summed
-    (:data:`TRIPS`), both zero after that (the names need no more than a
-    layer with one held expert is wide); summed over some stretch of work it
-    is named ``{names[i]: count}`` and, where there are expert layers, what
-    :func:`describe_stats` names and ``{TRIPS: count}``."""
-
-    def gather(intermediates):
-        own = sown(intermediates, sown_name)
-        if not own:
-            return None
-        own = sum(own)
-        experts = gather_stats(intermediates)
-        if experts is None:
-            return own[None]
-        trips = sum(sown(intermediates, TRIPS))[None]
-        return jnp.concatenate([experts] + [
-            jnp.pad(row, (0, experts.shape[1] - len(row)))[None]
-            for row in (own, trips)])
-
-    def describe(counts: np.ndarray) -> dict:
-        layers = max(len(counts) - 2, 0)
-        named = dict(zip(names, np.asarray(
-            counts[layers, :len(names)], np.int64).tolist()))
-        if layers:
-            named.update(describe_stats(counts[:layers]))
-            named[TRIPS] = int(counts[-1, 0])
-        return named
-
-    return WorkCounts(gather, describe)
 
 
 class SwitchFFN(nn.Module):

@@ -63,10 +63,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from olearning_sim_tpu.models.kimi_linear import (
-    _a_log_init, _causal_taps, _dt_bias_init)
-from olearning_sim_tpu.models.lfm2 import (
-    RMSNorm, _attend, _dense_init, _mm, sown_attend_pairs)
+from olearning_sim_tpu.models.decoder_parts import (
+    RMSNorm, a_log_init, attend, causal_taps, dense_init, dt_bias_init, mm,
+    sown_attend_pairs, work_counts_beside)
 from olearning_sim_tpu.models.lookup import LookupOnlyEmbed
 from olearning_sim_tpu.models import moe
 from olearning_sim_tpu.models.registry import ModelSpec, register_model
@@ -74,7 +73,7 @@ from olearning_sim_tpu.models.registry import ModelSpec, register_model
 # What a Mamba-2 or attention layer sows as ``ssd_stats`` on every call, one
 # int32 vector: the tokens and chunks of a Mamba-2 layer's scan, the (query,
 # key) pairs an attention layer's mask lets through, a head, and the scores
-# a head formed for them (``lfm2.attend_pairs``).
+# a head formed for them (``decoder_parts.attend_pairs``).
 STATS = ("ssd_scan_tokens", "ssd_scan_chunks", "attend_pairs_needed",
          "attend_pairs_computed")
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -158,10 +157,10 @@ def _scan_inputs(u, p, heads, groups, state, dtype):
     n, L, _ = u.shape
     f32 = jnp.float32
     d_inner = p["out_proj"].shape[0]
-    z, xBC, dt = jnp.split(_mm(u, p["in_proj"], dtype),
+    z, xBC, dt = jnp.split(mm(u, p["in_proj"], dtype),
                            [d_inner, p["in_proj"].shape[1] - heads], axis=-1)
     xBC = jax.nn.silu(
-        _causal_taps(xBC.astype(f32), p["conv"]) + p["conv_bias"])
+        causal_taps(xBC.astype(f32), p["conv"]) + p["conv_bias"])
     x, B, C = jnp.split(xBC, [d_inner, d_inner + groups * state], axis=-1)
     dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"])
     return (z, x.reshape(n, L, heads, -1), dt,
@@ -177,7 +176,7 @@ def _gated_out(y, x, z, p, groups, eps, dtype):
     y = (y * jax.nn.silu(z.astype(jnp.float32))).reshape(n, L, groups, -1)
     y = y * jax.lax.rsqrt(
         jnp.mean(y * y, axis=-1, keepdims=True) + eps) * p["norm"]
-    return _mm(y.reshape(n, L, -1), p["out_proj"], dtype)
+    return mm(y.reshape(n, L, -1), p["out_proj"], dtype)
 
 
 class Mamba2(nn.Module):
@@ -200,7 +199,7 @@ class Mamba2(nn.Module):
         d_inner, conv_dim = H * self.head_dim, H * self.head_dim + 2 * g * N
         f32 = jnp.float32
         p = {
-            "in_proj": self.param("in_proj", _dense_init,
+            "in_proj": self.param("in_proj", dense_init,
                                   (W, d_inner + conv_dim + H), f32),
             "conv": self.param("conv", nn.initializers.lecun_normal(),
                                (T, conv_dim), f32),
@@ -208,13 +207,13 @@ class Mamba2(nn.Module):
                                     (conv_dim,), f32),
             # A step log-uniform in [time_step_min 0.001, time_step_max 0.1]:
             # the published time_step_floor 1e-4 lies under it.
-            "dt_bias": self.param("dt_bias", _dt_bias_init, (H,), f32),
-            "A_log": self.param("A_log", _a_log_init, (H,), f32),
+            "dt_bias": self.param("dt_bias", dt_bias_init, (H,), f32),
+            "A_log": self.param("A_log", a_log_init, (H,), f32),
             "D": self.param("D", nn.initializers.ones, (H,), f32),
             # The gated norm's scale, a row a group.
             "norm": self.param("norm", nn.initializers.ones,
                                (g, d_inner // g), f32),
-            "out_proj": self.param("out_proj", _dense_init, (d_inner, W),
+            "out_proj": self.param("out_proj", dense_init, (d_inner, W),
                                    f32),
         }
         with jax.named_scope("ssd.projections"):
@@ -242,18 +241,18 @@ class Attention(nn.Module):
     def __call__(self, x):
         n, L, W = x.shape
         H, G, D = self.heads, self.kv_heads, self.head_dim
-        wq = self.param("q_proj", _dense_init, (W, H * D), jnp.float32)
-        wk = self.param("k_proj", _dense_init, (W, G * D), jnp.float32)
-        wv = self.param("v_proj", _dense_init, (W, G * D), jnp.float32)
-        wo = self.param("out_proj", _dense_init, (H * D, W), jnp.float32)
+        wq = self.param("q_proj", dense_init, (W, H * D), jnp.float32)
+        wk = self.param("k_proj", dense_init, (W, G * D), jnp.float32)
+        wv = self.param("v_proj", dense_init, (W, G * D), jnp.float32)
+        wo = self.param("out_proj", dense_init, (H * D, W), jnp.float32)
         with jax.named_scope("nemotron_h.attention"):
-            q = _mm(x, wq, self.dtype).reshape(n, L, G, H // G, D)
-            k = _mm(x, wk, self.dtype).reshape(n, L, G, D)
-            v = _mm(x, wv, self.dtype).reshape(n, L, G, D)
+            q = mm(x, wq, self.dtype).reshape(n, L, G, H // G, D)
+            k = mm(x, wk, self.dtype).reshape(n, L, G, D)
+            v = mm(x, wv, self.dtype).reshape(n, L, G, D)
             # By query blocks; the scores are recomputed in the backward
             # pass.
-            ctx = _attend(q, k, v)
-            out = _mm(ctx.reshape(n, L, H * D), wo, self.dtype)
+            ctx = attend(q, k, v)
+            out = mm(ctx.reshape(n, L, H * D), wo, self.dtype)
         self.sow("intermediates", "ssd_stats", sown_attend_pairs(n, L, 2))
         return out
 
@@ -267,10 +266,10 @@ class ReLU2(nn.Module):
     @nn.compact
     def __call__(self, x):
         W = x.shape[-1]
-        w1 = self.param("w1", _dense_init, (W, self.mlp_dim), jnp.float32)
-        w2 = self.param("w2", _dense_init, (self.mlp_dim, W), jnp.float32)
-        a = jax.nn.relu(_mm(x, w1, self.dtype))
-        return _mm(a * a, w2, self.dtype)
+        w1 = self.param("w1", dense_init, (W, self.mlp_dim), jnp.float32)
+        w2 = self.param("w2", dense_init, (self.mlp_dim, W), jnp.float32)
+        a = jax.nn.relu(mm(x, w1, self.dtype))
+        return mm(a * a, w2, self.dtype)
 
 
 class Layer(nn.Module):
@@ -281,7 +280,7 @@ class Layer(nn.Module):
     What a layer leaves for the backward pass when nothing computes it again
     (:class:`NemotronH` decides by ``kind``; bfloat16 unless said): ``"*"``
     q, k, v and the context, 17 KB a token (the scores never:
-    ``lfm2._attend`` computes them again, a block of queries against the
+    ``decoder_parts.attend`` computes them again, a block of queries against the
     keys up to its end); ``"E"`` the per-assignment arrays,
     ``experts_per_token`` rows a token (the gathered inputs, both grouped
     products' results, the float32 combine) and the shared expert's hidden
@@ -342,7 +341,7 @@ class NemotronH(nn.Module):
     layer's letter**, not by a knob, and since PR 48 the choice is none: no
     layer is wrapped in ``nn.remat``; every layer keeps its residuals
     (:class:`Layer` has their sizes) and the backward pass computes again
-    only what the parts' own checkpoints cover, ``lfm2._attend``'s scores
+    only what the parts' own checkpoints cover, ``decoder_parts.attend``'s scores
     and an ``"E"`` layer's windows (``models/moe.py``). Sized by compiling
     the benchmark cell's round program (``MEMEM*E``, 4,096 tokens a step, 8
     held experts, 21 B a parameter of state around it) for a v5e with
@@ -424,7 +423,7 @@ class NemotronH(nn.Module):
                 routed_scaling_factor=self.routed_scaling_factor,
                 dtype=self.dtype, name=f"layers_{i}")(h)
         h = RMSNorm(self.norm_eps, name="final_norm")(h)
-        head = self.param("head", _dense_init,
+        head = self.param("head", dense_init,
                           (self.width, self.vocab_size), jnp.float32)
         return jnp.dot(h.astype(self.dtype), head.astype(self.dtype),
                        preferred_element_type=jnp.float32)
@@ -441,7 +440,7 @@ register_model(
         # DroplessMoE's jax.lax.ragged_dot has no batching rule for
         # per-client expert weights.
         vmap_clients=False,
-        work_counts=moe.work_counts_beside("ssd_stats", STATS),
+        work_counts=work_counts_beside("ssd_stats", STATS),
         defaults={
             "vocab_size": 131072, "max_len": 262144, "width": 2688,
             "pattern": "MEMEM*E", "mamba_heads": 64, "mamba_head_dim": 64,

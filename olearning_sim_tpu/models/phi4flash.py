@@ -9,7 +9,7 @@ residual layers,
     logits = ln(h) @ embed.T                  (head tied to the embedding)
 
 ``ln`` a LayerNorm with scale and bias, ``mlp`` the gated SiLU MLP without
-bias (:class:`~olearning_sim_tpu.models.lfm2.SwiGLU`), and ``mixer`` one of
+bias (:class:`~olearning_sim_tpu.models.decoder_parts.SwiGLU`), and ``mixer`` one of
 six things by the layer's PUBLISHED index ``i`` (:func:`layer_kind`):
 
 - **M**, Mamba-1 (``d_inner`` = ``expand`` x width channels, ``N`` =
@@ -41,7 +41,7 @@ six things by the layer's PUBLISHED index ``i`` (:func:`layer_kind`):
   pair), then ``a W_o + b_o``; the mask lets query ``t`` see keys ``t -
   window + 1 .. t`` and the scores are computed only for the key blocks the
   window reaches (:func:`window_attend`);
-- **F**: the same with the whole causal prefix (``lfm2._attend``: a block of
+- **F**: the same with the whole causal prefix (``decoder_parts.attend``: a block of
   queries against the keys up to its end), and it hands on its ``k`` and
   ``v``;
 - **C**, cross-attention: ``q = u W_q + b`` only; keys and values are the F
@@ -76,18 +76,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from olearning_sim_tpu.models import moe
-from olearning_sim_tpu.models.kimi_linear import (
-    _a_log_init, _causal_taps, _dt_bias_init)
-from olearning_sim_tpu.models.lfm2 import (
-    SwiGLU, _attend, _dense_init, _mm, attend_pairs, sown_attend_pairs)
+from olearning_sim_tpu.models.decoder_parts import (
+    SwiGLU, a_log_init, attend, attend_pairs, causal_taps, dense_init,
+    dt_bias_init, mm, sown_attend_pairs, work_counts_beside)
 from olearning_sim_tpu.models.registry import ModelSpec, register_model
 
 # What a layer with a scan or attention sows as ``phi4flash_stats`` on every
 # call, one int32 vector: the tokens and chunks its scan took, the
 # (query, key) pairs of a sequence's window a head needs and the scores a
 # head formed for them (masked ones among them), and the same two of an F or
-# C layer's whole causal prefix (``lfm2.attend_pairs``).
+# C layer's whole causal prefix (``decoder_parts.attend_pairs``).
 STATS = ("sscan_tokens", "sscan_chunks", "window_attn_pairs_needed",
          "window_attn_pairs_computed", "attend_pairs_needed",
          "attend_pairs_computed")
@@ -161,7 +159,7 @@ def window_pairs(L: int, window: int) -> Tuple[int, int]:
     """(the (query, key) pairs a head's window needs over a sequence of
     ``L`` tokens, the scores :func:`window_attend` forms for them): every
     query block against itself and, but for the first, the one before;
-    inside one window, what ``lfm2._attend`` forms."""
+    inside one window, what ``decoder_parts.attend`` forms."""
     reach = min(L, window)
     blocks = -(-L // window)
     return (L * reach - reach * (reach - 1) // 2,
@@ -180,7 +178,7 @@ def window_attend(q, k, v, window: int):
     tail shorter than a block is padded with keys no real query sees."""
     n, L, G, R, D = q.shape
     if L <= window:
-        return _attend(q, k, v)
+        return attend(q, k, v)
     pad = -L % window
     b = (L + pad) // window
 
@@ -225,32 +223,32 @@ class Mamba(nn.Module):
         n, L, W = u.shape
         Di, N, R = self.d_inner, self.d_state, self.dt_rank
         f32 = jnp.float32
-        in_proj = self.param("in_proj", _dense_init, (W, 2 * Di), f32)
+        in_proj = self.param("in_proj", dense_init, (W, 2 * Di), f32)
         conv = self.param("conv", nn.initializers.lecun_normal(),
                           (self.d_conv, Di), f32)
         conv_bias = self.param("conv_bias", nn.initializers.zeros, (Di,), f32)
-        x_proj = self.param("x_proj", _dense_init, (Di, R + 2 * N), f32)
-        dt_proj = self.param("dt_proj", _dense_init, (R, Di), f32)
+        x_proj = self.param("x_proj", dense_init, (Di, R + 2 * N), f32)
+        dt_proj = self.param("dt_proj", dense_init, (R, Di), f32)
         # A step log-uniform in [0.001, 0.1], a channel.
-        dt_bias = self.param("dt_bias", _dt_bias_init, (Di,), f32)
-        A_log = self.param("A_log", _a_log_init, (Di, N), f32)
+        dt_bias = self.param("dt_bias", dt_bias_init, (Di,), f32)
+        A_log = self.param("A_log", a_log_init, (Di, N), f32)
         D = self.param("D", nn.initializers.ones, (Di,), f32)
-        out_proj = self.param("out_proj", _dense_init, (Di, W), f32)
+        out_proj = self.param("out_proj", dense_init, (Di, W), f32)
 
         def fed(a, kernel):     # operands in ``dtype``, the result float32
             return jnp.dot(a.astype(self.dtype), kernel.astype(self.dtype),
                            preferred_element_type=f32)
 
         with jax.named_scope("phi4flash.mamba_projections"):
-            x, z = jnp.split(_mm(u, in_proj, self.dtype), 2, axis=-1)
-            x = jax.nn.silu(_causal_taps(x.astype(f32), conv) + conv_bias)
+            x, z = jnp.split(mm(u, in_proj, self.dtype), 2, axis=-1)
+            x = jax.nn.silu(causal_taps(x.astype(f32), conv) + conv_bias)
             d, B, C = jnp.split(fed(x, x_proj), [R, R + N], axis=-1)
             dt = jax.nn.softplus(fed(d, dt_proj) + dt_bias)
         with jax.named_scope("phi4flash.selective_scan"):
             y = selective_scan(x, dt, -jnp.exp(A_log), B, C)
         with jax.named_scope("phi4flash.mamba_projections"):
             y = y + D * x
-            out = _mm(jax.nn.silu(z.astype(f32)) * y, out_proj, self.dtype)
+            out = mm(jax.nn.silu(z.astype(f32)) * y, out_proj, self.dtype)
         self.sow("intermediates", "phi4flash_stats", jnp.asarray(
             [n * L, n * -(-L // CHUNK), 0, 0, 0, 0], jnp.int32))
         return out, y
@@ -265,11 +263,11 @@ class GMU(nn.Module):
     @nn.compact
     def __call__(self, u, m):
         W, Di = u.shape[-1], m.shape[-1]
-        in_proj = self.param("in_proj", _dense_init, (W, Di), jnp.float32)
-        out_proj = self.param("out_proj", _dense_init, (Di, W), jnp.float32)
+        in_proj = self.param("in_proj", dense_init, (W, Di), jnp.float32)
+        out_proj = self.param("out_proj", dense_init, (Di, W), jnp.float32)
         with jax.named_scope("phi4flash.gmu"):
-            gate = jax.nn.silu(_mm(u, in_proj, self.dtype).astype(jnp.float32))
-            return _mm(gate * m, out_proj, self.dtype)
+            gate = jax.nn.silu(mm(u, in_proj, self.dtype).astype(jnp.float32))
+            return mm(gate * m, out_proj, self.dtype)
 
 
 class DiffAttention(nn.Module):
@@ -293,17 +291,17 @@ class DiffAttention(nn.Module):
         f32 = jnp.float32
 
         def projected(name, width):     # u W + b, the bias added in float32
-            kernel = self.param(name + "_proj", _dense_init, (W, width), f32)
+            kernel = self.param(name + "_proj", dense_init, (W, width), f32)
             bias = self.param(name + "_bias", nn.initializers.zeros,
                               (width,), f32)
-            return (_mm(u, kernel, self.dtype).astype(f32)
+            return (mm(u, kernel, self.dtype).astype(f32)
                     + bias).astype(self.dtype)
 
         lam = {name: self.param(name, nn.initializers.normal(0.1), (D,), f32)
                for name in ("lambda_q1", "lambda_k1", "lambda_q2",
                             "lambda_k2")}
         subln = self.param("subln", nn.initializers.ones, (2 * D,), f32)
-        wo = self.param("out_proj", _dense_init, (H * D, W), f32)
+        wo = self.param("out_proj", dense_init, (H * D, W), f32)
         bo = self.param("out_bias", nn.initializers.zeros, (W,), f32)
         lam0 = lambda_init(self.index)
         # The S mixer's score and context products have a scope of their
@@ -326,7 +324,7 @@ class DiffAttention(nn.Module):
                 "phi4flash.window_products" if self.window else scope):
             a1, a2 = (
                 window_attend(q[..., j, :], k[..., j, :], v, self.window)
-                if self.window else _attend(q[..., j, :], k[..., j, :], v)
+                if self.window else attend(q[..., j, :], k[..., j, :], v)
                 for j in (0, 1))
         with jax.named_scope(scope):
             lam_full = (jnp.exp(jnp.sum(lam["lambda_q1"] * lam["lambda_k1"]))
@@ -336,7 +334,7 @@ class DiffAttention(nn.Module):
             a = a * jax.lax.rsqrt(
                 jnp.mean(a * a, axis=-1, keepdims=True) + self.eps)
             a = a * subln * (1.0 - lam0)
-            out = _mm(a.reshape(n, L, H * D), wo, self.dtype).astype(f32) + bo
+            out = mm(a.reshape(n, L, H * D), wo, self.dtype).astype(f32) + bo
         if self.window:
             needed, computed = window_pairs(L, self.window)
             self.sow("intermediates", "phi4flash_stats", jnp.asarray(
@@ -406,7 +404,7 @@ class Phi4Flash(nn.Module):
     every layer's residuals kept (``scripts/compile_cell.py``; PERF.md
     section 4 has the compiler's row), and a wrapped layer is computed
     twice. What the backward pass computes again: the attention scores
-    (``lfm2._attend``'s and :func:`window_attend`'s own checkpoints) and the
+    (``decoder_parts.attend``'s and :func:`window_attend`'s own checkpoints) and the
     scan's chunks."""
 
     vocab_size: int = 200064
@@ -476,7 +474,7 @@ register_model(
         # One client at a time, as the other long-context decoders: a
         # client's float32 carry and gradient fill most of a chip.
         vmap_clients=False,
-        work_counts=moe.work_counts_beside("phi4flash_stats", STATS),
+        work_counts=work_counts_beside("phi4flash_stats", STATS),
         defaults={
             "vocab_size": 200064, "max_len": 262144, "width": 2560,
             "num_hidden_layers": 32, "layer_slice": [0, 31], "heads": 40,

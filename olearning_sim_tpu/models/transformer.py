@@ -30,11 +30,6 @@ class TransformerBlock(nn.Module):
     mlp_dim: int
     dtype: jnp.dtype = jnp.bfloat16
     attention_impl: str = "dense"
-    # Ring-only: compute each ring step with the fused Pallas kernel
-    # (trainable via its custom VJP) instead of plain XLA ops — the
-    # per-chunk A/B switch of ops/flash_attention.py, exposed at model
-    # level so configs can flip it without code.
-    ring_use_flash: bool = False
 
     @nn.compact
     def __call__(self, x, pad_mask):
@@ -54,30 +49,8 @@ class TransformerBlock(nn.Module):
             # eval of a model trained with attention_impl="dense").
             y = RingSelfAttention(
                 num_heads=self.heads, dtype=self.dtype,
-                use_flash=self.ring_use_flash,
                 name="MultiHeadDotProductAttention_0",
             )(x, pad_mask)
-        elif self.attention_impl == "flash":
-            # Fused Pallas kernel: no HBM score tensor. Slower than XLA's
-            # fused dense path on current chips (see ops/flash_attention.py);
-            # exists as the ring per-step primitive and for variants XLA
-            # can't fuse. Not for FedCore client models as it stands: the
-            # round program leaves mp to the auto partitioner, which Mosaic
-            # refuses, and this call does not yet go through
-            # ops.lowering.manual_over_auto_axes, which lifts that (the
-            # kernel still loses to dense on every shape measured).
-            from olearning_sim_tpu.ops import flash_attention
-
-            B, L, W = x.shape
-            head_dim = W // self.heads
-            qkv = nn.DenseGeneral(
-                features=(3, self.heads, head_dim), axis=-1, dtype=self.dtype,
-                name="qkv",
-            )(x)
-            q, k, v = (jnp.moveaxis(qkv[:, :, i], 2, 1) for i in range(3))
-            o = flash_attention(q, k, v, kv_mask=pad_mask)
-            o = jnp.moveaxis(o, 1, 2).reshape(B, L, W)
-            y = nn.Dense(W, dtype=self.dtype, name="attn_out")(o)
         else:
             attn_mask = nn.make_attention_mask(pad_mask, pad_mask, dtype=self.dtype)
             y = nn.MultiHeadDotProductAttention(
@@ -101,7 +74,15 @@ class TextTransformer(nn.Module):
     pad_id: int = 0
     dtype: jnp.dtype = jnp.bfloat16
     attention_impl: str = "dense"
-    ring_use_flash: bool = False  # see TransformerBlock.ring_use_flash
+
+    def __post_init__(self):
+        # Refused when the model is built: any other string used to mean
+        # dense, and a stored task may name an implementation that is gone.
+        if self.attention_impl not in ("dense", "ring"):
+            raise ValueError(
+                f"attention_impl={self.attention_impl!r}: the text "
+                "transformer has 'dense' and 'ring'")
+        super().__post_init__()
 
     @nn.compact
     def __call__(self, tokens):
@@ -140,7 +121,7 @@ class TextTransformer(nn.Module):
         for _ in range(self.depth):
             x = TransformerBlock(
                 self.width, self.heads, self.mlp_dim, self.dtype,
-                self.attention_impl, self.ring_use_flash,
+                self.attention_impl,
             )(x, pad_mask)
         # Mean-pool over real tokens (robust when no CLS convention exists in
         # the synthetic/Sent140 tokenization).

@@ -357,7 +357,8 @@ class PerformanceManager:
         return render_prometheus(self.registry)
 
     def metrics_snapshot(self) -> Dict[str, Any]:
-        """Dict form of the registry (bench.py artifacts)."""
+        """Dict form of the registry (what ``render_metrics("json")``
+        serializes)."""
         from olearning_sim_tpu.telemetry import snapshot
 
         return snapshot(self.registry)

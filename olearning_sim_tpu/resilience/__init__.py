@@ -12,7 +12,7 @@ TPU engine (docs/resilience.md):
 - :mod:`policy` — operator-level failure policies (fail_task / skip_round /
   retry) and the runner's resilience configuration;
 - :mod:`events` — counters + structured events surfaced through the
-  performance manager, the task status API, and bench.py.
+  performance manager and the task status API.
 """
 
 from olearning_sim_tpu.resilience.events import (

@@ -172,5 +172,6 @@ _GLOBAL = ResilienceLog()
 
 
 def global_log() -> ResilienceLog:
-    """The process-wide default sink (bench.py reads its counters)."""
+    """The process-wide default sink: what a component writes to when it
+    is handed no log of its own."""
     return _GLOBAL

@@ -214,8 +214,8 @@ CATALOG = {
     "ols_engine_rounds_to_target": (
         GAUGE,
         "Train rounds until eval accuracy first reached the configured "
-        "convergence target (the rounds-denominated time-to-accuracy "
-        "figure BENCH_convergence.json banks). Unset until reached",
+        "convergence target (time-to-accuracy in rounds, the figure "
+        "the convergence gate compares). Unset until reached",
         ("task_id",),
     ),
     "ols_engine_compile_cache_hits_total": (

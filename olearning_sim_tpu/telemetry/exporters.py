@@ -91,7 +91,8 @@ def render_prometheus(registry: Optional[MetricsRegistry] = None) -> str:
 
 
 def snapshot(registry: Optional[MetricsRegistry] = None) -> Dict[str, Any]:
-    """JSON-ready dump of every instrument (bench.py artifact form)."""
+    """JSON-ready dump of every instrument (the ``getMetrics`` RPC's JSON
+    form)."""
     registry = registry if registry is not None else default_registry()
     out: Dict[str, Any] = {}
     for metric in registry.metrics():

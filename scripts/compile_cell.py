@@ -81,8 +81,10 @@ def lower_round_step(workload: str, seed: int):
         seed=seed, num_clients=int(cell.traffic["clients"]),
         n_local=int(syn["n_local"]), seq_len=int(model["input_shape"][0]),
         num_classes=int(syn["num_classes"]),
-        vocab_size=int(syn["vocab_size"]),
         dirichlet_alpha=syn.get("dirichlet_alpha"),
+        # Only the decoder cells size their vocabulary; shapes alone matter.
+        **({"vocab_size": int(syn["vocab_size"])} if "vocab_size" in syn
+           else {}),
     ).pad_for(plan, cfg.block_clients)
     clients = (ds.num_clients,)
 

@@ -265,6 +265,82 @@ def test_the_client_block_stage_is_single_and_builders_import_stages():
     assert fedcore_imports == [], fedcore_imports
 
 
+MODELS_DIR = os.path.join(REPO, "olearning_sim_tpu", "models")
+MODEL_MODULES = sorted(
+    name[:-3] for name in os.listdir(MODELS_DIR)
+    if name.endswith(".py") and name not in ("__init__.py", "registry.py"))
+DECODERS = ("lfm2", "kimi_linear", "nemotron_h", "phi4flash")
+
+
+@pytest.mark.parametrize("module", MODEL_MODULES)
+def test_a_model_module_imports_no_siblings_private_name(module):
+    """What two model families share has a public name and an owner: no
+    file under ``models/`` takes an underscored name from another file
+    there (imported, or read off an imported module), and no decoder family
+    imports another's module, so an edit to a family's file is an edit to
+    its own benchmark cells (the shared parts are
+    ``models/decoder_parts.py``'s and ``models/moe.py``'s)."""
+    import ast
+
+    pkg = "olearning_sim_tpu.models"
+    tree = ast.parse(open(os.path.join(MODELS_DIR, module + ".py"),
+                          encoding="utf-8").read())
+    siblings, private = {}, []              # local name -> sibling module
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            for a in node.names:
+                if a.name.startswith(pkg + "."):
+                    siblings[a.asname or a.name] = a.name[len(pkg) + 1:]
+        elif isinstance(node, ast.ImportFrom):
+            source = node.module or ""
+            if node.level:                  # from . import x / from .x import
+                source = pkg + ("." + source if source else "")
+            if source == pkg:
+                for a in node.names:
+                    if os.path.exists(os.path.join(MODELS_DIR,
+                                                   a.name + ".py")):
+                        siblings[a.asname or a.name] = a.name
+            elif source.startswith(pkg + "."):
+                siblings[source] = source[len(pkg) + 1:]
+                private += [f"{source}.{a.name}" for a in node.names
+                            if a.name.startswith("_")]
+    private += [
+        f"{siblings[node.value.id]}.{node.attr}" for node in ast.walk(tree)
+        if isinstance(node, ast.Attribute) and node.attr.startswith("_")
+        and isinstance(node.value, ast.Name) and node.value.id in siblings]
+    assert private == [], private
+    if module in DECODERS:
+        others = sorted(set(siblings.values()) & set(DECODERS) - {module})
+        assert others == [], others
+
+
+def _documents():
+    docs = ["README.md", "PARITY.md", "examples/README.md",
+            ".claude/skills/verify/SKILL.md"]
+    docs += sorted("docs/" + name for name in os.listdir(
+        os.path.join(REPO, "docs")) if name.endswith(".md"))
+    return [d for d in docs if os.path.exists(os.path.join(REPO, d))]
+
+
+@pytest.mark.parametrize("doc", _documents())
+def test_documents_name_only_files_that_exist(doc):
+    """A document that names a script, an example, a config, another
+    document or a record in the root names one that is there: a command the
+    reader is sent to run, or a file quoted as evidence, does not outlive
+    the file."""
+    import re
+
+    text = open(os.path.join(REPO, doc), encoding="utf-8").read()
+    names = set(re.findall(
+        r"(?<![\w/.-])(?:scripts|examples|configs|docs)/[\w./-]+"
+        r"\.(?:py|json|md|sh)\b", text))
+    names |= set(re.findall(
+        r"(?<![\w/.*-])(?:[A-Z][A-Z_]+[\w.]*\.json|bench\.py)\b", text))
+    missing = sorted(n for n in names
+                     if not os.path.exists(os.path.join(REPO, n)))
+    assert missing == [], missing
+
+
 def test_ast_rules_wall_clock_rule():
     hits = ast_rules.lint_source(
         "import time\nnow = time.time()\n", "olearning_sim_tpu/x.py")

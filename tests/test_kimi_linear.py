@@ -23,7 +23,7 @@ from olearning_sim_tpu.engine.client_data import (
 from olearning_sim_tpu.engine.fedcore import FedCoreConfig, build_fedcore
 from olearning_sim_tpu.models import get_model
 from olearning_sim_tpu.models import kimi_linear as km
-from olearning_sim_tpu.models.lfm2 import SwiGLU
+from olearning_sim_tpu.models.decoder_parts import SwiGLU
 from olearning_sim_tpu.models.moe import DroplessMoE
 from olearning_sim_tpu.parallel.mesh import make_mesh_plan
 
@@ -174,7 +174,7 @@ def test_the_backward_pass_computes_no_block_again(layer_types, dense):
     (the scan's, which the backward function of ``ops/kda_scan.py``'s custom
     VJP makes, with the two chunk bodies inside it, again checkpoints; the
     two around it with the L2 and per-head norms and no scan), the chunk
-    bodies of the forward scans and ``lfm2._attend``'s around an MLA mixer's
+    bodies of the forward scans and ``decoder_parts.attend``'s around an MLA mixer's
     scores (no norm inside it); a dense MLP none, and an expert layer none
     since PR 47 (its backward pass is a loop of ``models/moe.py``'s own over
     the row windows, which computes a window's hidden products again inside

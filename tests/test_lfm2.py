@@ -20,8 +20,10 @@ from olearning_sim_tpu.engine.client_data import (
     make_central_text_eval_set, make_synthetic_text_dataset)
 from olearning_sim_tpu.engine.fedcore import FedCoreConfig, build_fedcore
 from olearning_sim_tpu.models import get_model
+from olearning_sim_tpu.models import decoder_parts
 from olearning_sim_tpu.models import lfm2 as lfm2_model
-from olearning_sim_tpu.models.moe import DroplessMoE, describe_stats
+from olearning_sim_tpu.models.decoder_parts import describe_stats
+from olearning_sim_tpu.models.moe import DroplessMoE
 from olearning_sim_tpu.parallel.expert_parallel import ep_param_specs
 from olearning_sim_tpu.parallel.mesh import make_mesh_plan
 
@@ -59,7 +61,7 @@ def test_each_dense_block_kind_matches_the_reference(kind):
                  lambda p, x: ref.short_conv(p, "", x)),
         "attention": (lfm2_model.CausalGQA(4, 2, dtype=F32),
                       lambda p, x: ref.attention(p, "", x)),
-        "mlp": (lfm2_model.SwiGLU(96, F32),
+        "mlp": (decoder_parts.SwiGLU(96, F32),
                 lambda p, x: ref.swiglu(x, p["w1"], p["w3"], p["w2"])),
     }[kind]
     x = _x(1)
@@ -81,7 +83,7 @@ def test_each_dense_block_kind_matches_the_reference(kind):
 
 
 def _plain_attend(q, k, v):
-    """The form ``lfm2._attend`` replaced (PR 45): all L x L scores, the
+    """The form ``decoder_parts.attend`` replaced (PR 45): all L x L scores, the
     upper triangle masked, one float32 softmax a row."""
     n_keys, D = k.shape[1], q.shape[-1]
     scores = jnp.einsum("nqgrd,nkgd->ngrqk", q, k,
@@ -116,7 +118,7 @@ def _loss_and_grads(attend, over_clients=False):
 
 
 def _blocked(q, k, v):
-    return lfm2_model._attend(q, k, v, B)
+    return decoder_parts.attend(q, k, v, B)
 
 
 @pytest.mark.parametrize("heads", sorted(HEADS))
@@ -166,7 +168,7 @@ def test_the_blocked_program_holds_no_l_by_l_array_and_one_checkpoint():
     scores = [e.outvars[0].aval.shape for e in forward
               if e.primitive.name == "exp"]
     assert len(scores) == 4 and {np.prod(s[:-2]) for s in scores} == {heads}
-    assert sum(s[-2] * s[-1] for s in scores) == lfm2_model.attend_pairs(
+    assert sum(s[-2] * s[-1] for s in scores) == decoder_parts.attend_pairs(
         length, B)[1] == B * B * (1 + 2 + 3 + 4)
     backward = list(_equations(jax.make_jaxpr(jax.grad(
         lambda *a: (_blocked(*a) ** 2).sum(), (0, 1, 2)))(q, k, v).jaxpr))
@@ -185,10 +187,10 @@ def test_attend_pairs_against_a_brute_count(length, block):
     rows = np.arange(length)
     # Row i is in the block that ends at key ``end[i]`` (exclusive).
     end = np.minimum((rows // block + 1) * block, length)
-    assert lfm2_model.attend_pairs(length, block) == (
+    assert decoder_parts.attend_pairs(length, block) == (
         int((rows + 1).sum()), int(end.sum()))
     if length <= block:
-        assert lfm2_model.attend_pairs(length, block)[1] == length * length
+        assert decoder_parts.attend_pairs(length, block)[1] == length * length
 
 
 def test_an_attention_layer_sows_the_pairs_of_its_sequences():

@@ -190,3 +190,12 @@ def test_moe_aux_loss_threaded_into_fl_path():
 
     dense = build_fedcore("mlp2", fedavg(0.05), plan, cfg)
     assert dense.apply_aux_fn is None
+
+
+@pytest.mark.parametrize("impl", ["flash", "dence"])
+def test_text_transformer_refuses_an_unknown_attention_impl(impl):
+    """A value the family does not have is refused when the model is built,
+    with the two it has: a stored task that names a removed implementation
+    (or misspells one) does not quietly train the dense program."""
+    with pytest.raises(ValueError, match="'dense' and 'ring'"):
+        get_model("distilbert").build(attention_impl=impl)

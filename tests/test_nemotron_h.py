@@ -506,7 +506,7 @@ def _checkpoints(jaxpr):
 def test_the_backward_pass_computes_no_layer_again():
     """The intent, pinned (until PR 48 it was "only the Mamba-2 layers",
     and the test was named so): an attention layer's backward pass holds one
-    ``jax.checkpoint`` equation, ``lfm2._attend``'s own around the scores
+    ``jax.checkpoint`` equation, ``decoder_parts.attend``'s own around the scores
     (no norm inside it); an expert layer's holds none (its windows are
     computed again by its own backward loop, not by a checkpoint); a
     Mamba-2 layer's holds none, so its scan and its projections run once a
@@ -533,7 +533,7 @@ def test_the_backward_pass_computes_no_layer_again():
 ])
 def test_the_other_sparse_decoders_round_programs_did_not_move(
         config, digest):
-    """The two families that share ``models/moe.py`` and ``lfm2._attend``
+    """The two families that share ``models/moe.py`` and ``decoder_parts.attend``
     with this one lower their small presets' ``round_step`` to the text
     they lowered to before this family chose its layers to compute again
     (PR 40: the digests are the parent commit's), so their compilation
@@ -547,7 +547,7 @@ def test_the_other_sparse_decoders_round_programs_did_not_move(
     ``DroplessMoE`` works its sorted rows in windows inside a
     ``lax.while_loop`` with a backward loop of its own and sows
     ``moe_window_trips`` (one more row at the end of ``model_stats``). PR 45's before them: the attention layers'
-    ``attend_pairs_*`` counts and ``lfm2._attend``'s mask from two
+    ``attend_pairs_*`` counts and ``decoder_parts.attend``'s mask from two
     ``iota``s."""
     def read(*path):
         with open(os.path.join(*path, config + ".json")) as f:
